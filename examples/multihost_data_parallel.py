@@ -20,11 +20,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-from kungfu_tpu.utils.platform import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import jax.numpy as jnp
 import numpy as np
 import optax

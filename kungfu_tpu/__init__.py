@@ -24,10 +24,6 @@ from .utils import knobs as _knobs
 _SIM_LITE = bool(_knobs.get("KFT_SIM_LITE"))
 
 if not _SIM_LITE:
-    from .utils.jax_compat import ensure_compat as _ensure_jax_compat
-
-    _ensure_jax_compat()  # alias moved jax surfaces (jax.shard_map on 0.4.x)
-
     from . import comm, plan
     from .comm import Session
     from .training import (broadcast_variables, build_train_step,
@@ -103,6 +99,7 @@ def init_distributed(local_device_ids=None) -> bool:
         return True
     if local_device_ids is None and we.chip_ids is not None:
         local_device_ids = we.chip_ids
+    D.require_own_chips(list(we.peers), we.rank())
     D.initialize(list(we.peers), we.rank(), we.cluster_version,
                  local_device_ids=local_device_ids)
     return True

@@ -84,7 +84,9 @@ def _bench_xla(args, sizes):
 
     from ..comm.mesh import (CHIP_AXIS, HOST_AXIS, PEER_AXIS, flat_mesh,
                              hierarchical_mesh)
+    from ..utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ndev = args.devices or len(jax.devices())
     if args.method == "HIER":
         mesh = hierarchical_mesh(args.hosts, jax.devices()[:ndev])
@@ -128,9 +130,6 @@ def _bench_native(args, sizes):
 
 def main(argv=None):
     args = parse_args(argv)
-    if args.method in ("XLA", "HIER"):
-        from ..utils.platform import pin_cpu_if_requested
-        pin_cpu_if_requested()
     sizes = _sizes_for(args)
     tot_size = sum(sizes) * 4  # f32 bytes
 

@@ -121,10 +121,6 @@ def param_count(params) -> int:
 def main(argv=None) -> int:
     args = parse_args(argv)
 
-    from kungfu_tpu.utils.platform import pin_cpu_if_requested
-
-    pin_cpu_if_requested()
-
     import jax
     import jax.numpy as jnp
     import optax
@@ -134,7 +130,9 @@ def main(argv=None) -> int:
     from kungfu_tpu.models.gpt import GPTConfig, forward_local, init_params
     from kungfu_tpu.training import (build_train_step, init_opt_state,
                                      replicate)
+    from kungfu_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = GPTConfig(vocab_size=args.vocab, d_model=args.d_model,
                     n_heads=args.n_heads, n_layers=args.n_layers,
                     d_ff=args.d_ff, max_seq=args.seq,
@@ -197,7 +195,7 @@ def main(argv=None) -> int:
     for _ in range(args.warmup_steps):
         sp, st, loss = step(sp, st, (toks, tgts))
     if args.warmup_steps:
-        float(np.asarray(loss)[0])  # host fetch = reliable sync (see bench.py)
+        float(np.asarray(loss)[0])  # host fetch waits for the device
 
     t0 = time.perf_counter()
     for _ in range(args.steps):

@@ -28,11 +28,6 @@ import json
 import time
 
 import jax
-
-from ..utils.platform import pin_cpu_if_requested
-
-pin_cpu_if_requested()
-
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -205,6 +200,8 @@ def main(argv=None):
                     "report the per-tick fixed cost + v crossover")
     ap.add_argument("--json", default=None, help="also write JSON here")
     args = ap.parse_args(argv)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.fit:
         docs = [run_sweep(dp=args.dp, pp=args.pp, remat=args.remat,
                           virtual_stages=v) for v in (1, 2)]

@@ -42,10 +42,6 @@ import time
 
 def _worker(args) -> None:
     import jax
-
-    from ..utils.platform import pin_cpu_if_requested
-    pin_cpu_if_requested()
-
     import jax.numpy as jnp
     import numpy as np
     import optax
@@ -142,13 +138,12 @@ def main(argv=None):
 
     # orchestrator: two passes sharing one persistent cache dir
     with tempfile.TemporaryDirectory(prefix="kft_xla_cache_") as cache:
-        env = dict(os.environ, KFT_COMPILE_CACHE=cache)
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache)
         n = args.size
         if not n:
             probe = subprocess.run(
                 [sys.executable, "-c",
-                 "import kungfu_tpu.utils.platform as p; import jax; "
-                 "p.pin_cpu_if_requested(); print(len(jax.devices()))"],
+                 "import jax; print(len(jax.devices()))"],
                 capture_output=True, text=True, env=env, timeout=300)
             if probe.returncode != 0 or not probe.stdout.strip():
                 print(probe.stderr[-2000:], file=sys.stderr)

@@ -52,7 +52,7 @@ __all__ = ["run_serving_scenario"]
 _SERVER_ARGS = ["--vocab", "256", "--d-model", "32", "--n-heads", "2",
                 "--n-layers", "2", "--d-ff", "64", "--max-seq", "128",
                 "--slots", "4", "--block", "16", "--blocks", "64",
-                "--chunk", "4", "--buckets", "16"]
+                "--chunk", "4", "--buckets", "16", "--dtype", "float32"]
 _PROMPT_LEN = 8      # <= the single 16-token prefill bucket
 _MAX_NEW = 8
 _WARMUP = 2          # serial: pays the prefill + decode compiles

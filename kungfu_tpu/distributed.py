@@ -82,6 +82,37 @@ def is_initialized() -> bool:
     return _live is not None
 
 
+def require_own_chips(peers: Sequence, rank: int) -> None:
+    """Fail with a message when this worker shares its host with another
+    worker of the job and the backend is a TPU.
+
+    libtpu gives a host's chips to ONE process: ``KFT_VISIBLE_CHIPS``
+    reaches jax only as ``local_device_ids``, which libtpu does not use
+    to divide chips, so every colocated worker opens every chip and all
+    but the first die inside backend start-up with a lockfile error
+    that names no cause (measured on a four-chip v5e host, PR 21).
+    One worker per host (``-np 1``, or one per pod host) drives all of
+    the host's chips.  Call before the first backend use; on other
+    backends (the CPU rig's colocated workers) this returns."""
+    hosts = [h for h, _ in _norm_peers(peers)]
+    colocated = hosts.count(hosts[rank])
+    if colocated < 2:
+        return
+    import jax
+    msg = (f"{colocated} workers of this job run on host "
+           f"{hosts[rank]!r} and the backend is a TPU: a host's TPU chips "
+           f"belong to one process, and KFT_VISIBLE_CHIPS does not divide "
+           f"them between workers. Launch one worker per host (-np 1 "
+           f"drives every local chip); a multi-process data plane on one "
+           f"TPU host is not supported (ROADMAP R2/S6)")
+    try:
+        backend = jax.default_backend()
+    except RuntimeError as e:   # the loser of libtpu's lockfile
+        raise RuntimeError(msg) from e
+    if backend == "tpu":
+        raise RuntimeError(msg)
+
+
 def _clear_backends() -> None:
     import jax
     import jax.extend.backend as _eb
@@ -121,31 +152,20 @@ def initialize(peers: Sequence, rank: int, cluster_version: int = 0,
         # a backend built before initialize() would pin the single-process
         # device set; drop it so the distributed one is built instead
         _clear_backends()
-    from .utils.jax_compat import config_flag_supported
-    if config_flag_supported("jax_enable_recoverability"):
-        jax.config.update("jax_enable_recoverability", True)
+    jax.config.update("jax_enable_recoverability", True)
     # jax's preemption sync manager traps SIGTERM to defer the death to a
     # sync point — but THIS framework's preemption story is the runner's
     # (SIGTERM death -> shrink proposal -> survivors absorb it,
     # launcher/watch.py); a trapped SIGTERM would leave the worker
-    # half-alive and turn the eviction into a late SIGABRT.  On a jax
-    # without these flags peer death still surfaces as a RuntimeError
-    # from the failed collective, which the recovery path catches.
-    if config_flag_supported("jax_enable_preemption_service"):
-        jax.config.update("jax_enable_preemption_service", False)
-    kwargs = dict(
+    # half-alive and turn the eviction into a late SIGABRT.
+    jax.config.update("jax_enable_preemption_service", False)
+    jax.distributed.initialize(
         coordinator_address=coord,
         num_processes=n,
         process_id=rank,
         local_device_ids=local_device_ids,
         heartbeat_timeout_seconds=knobs.get("KFT_DATA_PLANE_HEARTBEAT_S"),
         shutdown_timeout_seconds=knobs.get("KFT_DATA_PLANE_SHUTDOWN_S"))
-    import inspect as _inspect
-    supported = _inspect.signature(jax.distributed.initialize).parameters
-    # elastic-tuned heartbeat/shutdown timeouts exist only on jax builds
-    # with the recoverable runtime; older ones use their fixed defaults
-    jax.distributed.initialize(
-        **{k: v for k, v in kwargs.items() if k in supported})
     _live = (cluster_version, coord, n, rank)
     global _atexit_armed
     if not _atexit_armed:

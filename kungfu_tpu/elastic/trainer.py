@@ -20,7 +20,6 @@ import time
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -41,8 +40,9 @@ def _restack(host_tree, n_new: int, mesh):
     newcomers clone lane 0 (the reference's broadcast-from-rank-0 sync).
     The grow case stages through the kffast buffer pool: repeated
     resizes recycle one host staging buffer per (dtype, nbytes) class
-    instead of fresh-allocating the full host tree each time
-    (``device_put`` copies out before the pool slot can be reused)."""
+    instead of fresh-allocating the full host tree each time (the pool
+    reuses a slot only once nothing references it, device_put
+    included)."""
     from ..store.pool import default_pool
     spec = P(mesh.axis_names)
 
@@ -55,7 +55,9 @@ def _restack(host_tree, n_new: int, mesh):
             out = default_pool().take(t.dtype, (n_new,) + t.shape[1:])
             out[:n_old] = t
             out[n_old:] = t[0:1]
-        return jax.device_put(jnp.asarray(out), NamedSharding(mesh, spec))
+        # host array + sharding: each lane's slice goes straight to its
+        # device (jnp.asarray first would stage all n lanes on device 0)
+        return jax.device_put(out, NamedSharding(mesh, spec))
     return jax.tree_util.tree_map(re, host_tree)
 
 
@@ -95,8 +97,7 @@ class ElasticTrainer:
         self.last_resize_seconds: Optional[float] = None
         self.last_resize_compiled = False  # True: new step fn was built
         # persistent XLA cache: a respawned/grown worker pays a disk
-        # deserialisation instead of a recompile (KFT_COMPILE_CACHE=off
-        # to disable)
+        # deserialisation instead of a recompile
         from ..utils.compile_cache import enable_compile_cache
         enable_compile_cache()
         stack = lambda tree: jax.tree_util.tree_map(

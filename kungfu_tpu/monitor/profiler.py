@@ -3,7 +3,7 @@
 The paper's monitoring plane exists so the system can *act* on live
 performance signals (srcs/go/monitor/, session/monitoring.go feeding
 adaptiveStrategies.go), but until this module the repo's signal plane
-stopped at host-side wall clocks: BENCH_r01..r05 is flat and nobody can
+stopped at host-side wall clocks: BENCH_r03..r05 is flat and nobody can
 say whether the step is compute-, collective-, input- or host-bound
 (ROADMAP items 3 and 5).  kfprof fuses the existing pieces — the
 ``jax.profiler`` wrapper (utils/trace.py), the measured ceilings
@@ -22,9 +22,9 @@ shows phase rows per rank.  Wired into the elastic trainers
 
 **(b) Compiled cost & roofline gauges** — at (re)compile time the
 trainers hand their jitted step to :func:`publish_compiled_cost`, which
-runs ``fn.lower(...).compile().cost_analysis()`` (version-shimmed via
-``utils.jax_compat.compiled_cost_analysis``; gracefully absent on old
-jaxlibs) and publishes ``kungfu_tpu_step_flops`` /
+runs ``fn.lower(...).compile().cost_analysis()`` (via
+``utils.jax_compat.compiled_cost_analysis``; absent where the backend
+has no cost model) and publishes ``kungfu_tpu_step_flops`` /
 ``kungfu_tpu_step_hbm_bytes`` gauges.  Each step,
 :func:`publish_roofline` combines those with the measured compute phase
 into ``kungfu_tpu_roofline_fraction{bound=mxu|hbm|best}`` against the

@@ -543,10 +543,7 @@ def _big_tile_ok() -> bool:
     env = knobs.get("KFT_FLASH_BIG_TILE")
     if env is not None:
         return env
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return False
+    kind = jax.devices()[0].device_kind.lower()
     return "v5 lite" in kind or "v5e" in kind
 
 

@@ -14,15 +14,12 @@ import signal
 import sys
 import threading
 
-from ..utils.platform import pin_cpu_if_requested
-
-pin_cpu_if_requested()   # honor JAX_PLATFORMS=cpu over the TPU plugin
-
 import jax
 import jax.numpy as jnp
 
 from ..checkpoint import restore_npz_like
 from ..models import gpt as G
+from ..utils.compile_cache import enable_compile_cache
 from .engine import DecodeEngine
 from .server import ServingServer
 
@@ -38,6 +35,10 @@ def main(argv=None):
     ap.add_argument("--max-seq", type=int, default=1024)
     ap.add_argument("--rope", action="store_true")
     ap.add_argument("--swiglu", action="store_true")
+    ap.add_argument("--dtype", choices=("bfloat16", "float32"),
+                    default="bfloat16",
+                    help="model/activation dtype (the same on every "
+                         "platform; float32 for token-exact checks)")
     ap.add_argument("--npz", default=None,
                     help="weights from checkpoint.save_npz (else: "
                          "seed-initialized demo weights)")
@@ -78,8 +79,8 @@ def main(argv=None):
                          "greedy; see docs/serving.md for when it pays)")
     args = ap.parse_args(argv)
 
-    dtype = (jnp.bfloat16 if jax.devices()[0].platform == "tpu"
-             else jnp.float32)
+    enable_compile_cache()
+    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[args.dtype]
     cfg = G.GPTConfig(vocab_size=args.vocab, d_model=args.d_model,
                       n_heads=args.n_heads, n_kv_heads=args.n_kv_heads,
                       n_layers=args.n_layers, d_ff=args.d_ff,

@@ -250,7 +250,7 @@ class ServingServer:
 
     def _scheduler(self):
         """Sole owner of the engine after start().  Any engine exception
-        (device error, tunnel failure) is fatal: record it and release
+        (device error, runtime failure) is fatal: record it and release
         every waiting client with an error instead of a silent wedge."""
         try:
             while not self._stop.is_set():

@@ -32,12 +32,15 @@ def replicate(params, mesh: Optional[Mesh] = None):
     """Stack one replica per lane and shard over the mesh."""
     mesh = mesh or flat_mesh()
     n = int(np.prod(mesh.devices.shape))
-    spec = _stack_spec(mesh)
+    sharding = NamedSharding(mesh, _stack_spec(mesh))
 
     def rep(t):
-        t = jnp.asarray(t)
-        stacked = jnp.broadcast_to(t[None], (n,) + t.shape)
-        return jax.device_put(stacked, NamedSharding(mesh, spec))
+        # a host broadcast view + the sharding: device_put sends each
+        # lane its own slice (building the stack with jnp first would
+        # hold all n replicas on device 0 before resharding)
+        t = np.asarray(t)
+        return jax.device_put(np.broadcast_to(t[None], (n,) + t.shape),
+                              sharding)
     return jax.tree_util.tree_map(rep, params)
 
 
@@ -225,6 +228,9 @@ def build_train_step(loss_fn: Callable,
     def step(stacked_params, stacked_state, global_batch):
         p, s, losses = jitted(stacked_params, stacked_state, global_batch)
         return p, s, losses
+    # AOT access to the program behind the wrapper: inspect what it
+    # lowers to, or compile once and call the executable
+    step.lower = jitted.lower
     return step
 
 

@@ -3,121 +3,91 @@
 SURVEY §7 names resize-triggers-recompile as the dominant engineering
 risk of elastic training on XLA: the reference's resize costs ~1 barrier
 (srcs/go/kungfu/peer/peer.go:144-166 rebuilds a session, no compilation),
-ours costs a recompile at every previously-unseen cluster size.  Two
+ours costs a recompile at every previously-unseen cluster size — and
+every fresh process compiles its whole program set from nothing.  Two
 mitigations stack:
 
 1. in-process: ElasticTrainer caches compiled steps per size, so
    oscillating schedules (4→8→4…) recompile once per distinct size;
 2. across processes/restarts (this module): jax's persistent
-   compilation cache makes the recompile a disk hit — a respawned or
-   grown worker pays deserialisation, not XLA compilation.
+   compilation cache makes the recompile a disk hit.
 
-Call :func:`enable_compile_cache` once per process before the first jit
-(idempotent).  Default-on for accelerator backends; on CPU it requires
-an explicit opt-in (the ``path`` argument or ``KFT_COMPILE_CACHE``)
-because XLA:CPU AOT blobs log a harmless-but-alarming loader error on
-every cached load.  ``KFT_COMPILE_CACHE`` overrides the location;
-``0``/``off`` disables the wiring entirely.
+Where the cache lives is decided OUTSIDE the code:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax itself reads it at import and
+  the cache is there, on every backend.  This module sets no directory.
+- unset, accelerator backend: ``<checkout>/.jax_cache`` — derived from
+  this package's location, so every process of one checkout shares it
+  and two runs of one command find each other's programs.
+- unset, CPU backend: off.  XLA:CPU AOT blobs log a harmless-but-
+  alarming cpu_aot_loader "SIGILL" error on every cached load, and the
+  tests compile little enough not to need it.
+
+Call :func:`enable_compile_cache` once per process, after the backend is
+chosen (after ``jax.distributed.initialize`` in multi-process workers)
+and before the first jit.  Idempotent.
 """
 from __future__ import annotations
 
 import os
 from typing import Optional
 
-from . import knobs
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
-CACHE_ENV = "KFT_COMPILE_CACHE"
-_DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                            "kungfu_tpu", "xla")
 
+def enable_compile_cache() -> Optional[str]:
+    """Turn jax's persistent compilation cache on and make it keep every
+    program.  Returns the directory in use, or None when the cache is
+    off (CPU backend and no ``JAX_COMPILATION_CACHE_DIR``).
 
-def _host_fingerprint() -> str:
-    """Short digest of the host's ISA surface + jax version.
-
-    XLA:CPU AOT blobs bake in the *compiling* host's machine features; a
-    cache shared across heterogeneous machines loads blobs the current
-    CPU may not support (cpu_aot_loader warns "could lead to SIGILL").
-    The jax cache key does not fully cover this, so the cache directory
-    is partitioned per host type instead."""
-    import hashlib
-    import platform
+    jax's default thresholds skip programs that compiled in under a
+    second; a resize or a respawn pays for those too, so they are
+    lowered to zero unless the matching ``JAX_PERSISTENT_CACHE_*``
+    variable says otherwise."""
     import jax
-    bits = [platform.machine(), platform.processor(), jax.__version__]
-    try:
-        with open("/proc/cpuinfo") as f:
-            for ln in f:
-                # x86 lists ISA extensions under "flags", aarch64 under
-                # "Features"; take whichever appears first
-                if ln.startswith(("flags", "Features")):
-                    bits.append(" ".join(sorted(set(
-                        ln.split(":", 1)[-1].split()))))
-                    break
-    except OSError:
-        pass
-    return hashlib.sha256("|".join(bits).encode()).hexdigest()[:12]
-
-
-def enable_compile_cache(path: Optional[str] = None,
-                         min_compile_time_secs: Optional[float] = None
-                         ) -> Optional[str]:
-    """Point jax's persistent compilation cache at a ``host-<digest>``
-    subdirectory of ``path`` (default: ``$KFT_COMPILE_CACHE`` or
-    ``~/.cache/kungfu_tpu/xla``) — blobs are partitioned per host type
-    because XLA:CPU AOT code baked for one machine's ISA can SIGILL on
-    another.  Returns the directory in use (the subdirectory, not the
-    base), or None when disabled — via the env toggle, or because the
-    backend is CPU and neither ``path`` nor ``KFT_COMPILE_CACHE`` asked
-    for it explicitly (see the module docstring).
-
-    The default threshold (0: cache every program) is right for elastic
-    training, where even sub-second step compiles add up across a fleet
-    of respawned workers.  A ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``
-    env var takes precedence over the default, but an EXPLICIT
-    ``min_compile_time_secs`` argument wins over both."""
-    env = (knobs.raw(CACHE_ENV) or "").strip().lower()
-    if env in ("0", "off", "none", "disable"):
-        return None
-    import jax
-    # respect a cache the user already configured (jax env var or
-    # jax.config) — this helper provides a default, never an override
-    existing = (jax.config.jax_compilation_cache_dir
-                or os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-    if path is None and not knobs.is_set(CACHE_ENV) and existing:
-        return existing
-    # Default the cache to accelerator backends only.  XLA:CPU AOT blobs
-    # record pseudo machine features (+prefer-no-scatter/gather) that the
-    # loader's host-feature probe never reports, so EVERY cached-program
-    # load on CPU logs a scary (harmless) cpu_aot_loader "SIGILL" error —
-    # even on the very host that wrote the blob.  On TPU (where a resize
-    # recompile costs seconds and the loader is quiet) the cache stays
-    # on by default; on CPU it needs an explicit opt-in via the argument
-    # or KFT_COMPILE_CACHE.
-    explicit = path is not None or knobs.is_set(CACHE_ENV)
-    if not explicit and jax.default_backend() == "cpu":
-        # one-line notice so CPU deployments that previously benefited
-        # from cached recompiles know caching is now opt-in here
-        import logging
-        logging.getLogger(__name__).info(
-            "compile cache: off by default on CPU (set KFT_COMPILE_CACHE "
-            "or pass path= to opt in)")
-        return None
-    base_dir = path or knobs.raw(CACHE_ENV) or _DEFAULT_DIR
-    cache_dir = os.path.join(base_dir, "host-" + _host_fingerprint())
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # precedence: explicit argument > user env var > our default (0)
-    if min_compile_time_secs is not None:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_time_secs)
-    elif "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+    cache_dir = os.environ.get(CACHE_DIR_ENV)
+    if not cache_dir:
+        if jax.default_backend() == "cpu":
+            return None
+        cache_dir = DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
     if "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" not in os.environ:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if "JAX_COMPILATION_CACHE_MAX_SIZE" not in os.environ:
-        # bound the on-disk cache (LRU eviction) so caching every
-        # program can't grow ~/.cache without limit
-        jax.config.update("jax_compilation_cache_max_size",
-                          4 * 1024 * 1024 * 1024)
     return cache_dir
+
+
+class CompileCounter:
+    """Counts, from construction on, the programs XLA compiled in this
+    process and the ones the persistent cache supplied instead (jax's
+    own monitoring events).  A warm run of an unchanged command should
+    read ``compiled == 0``."""
+
+    # recorded once per program jax asks the backend for, cached or not
+    _REQUEST = "/jax/core/compile/backend_compile_duration"
+    _HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        import jax
+        self._requests = 0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    @property
+    def compiled(self) -> int:
+        return self._requests - self.cache_hits
+
+    def _on_duration(self, name, _secs, **_kw):
+        if name == self._REQUEST:
+            self._requests += 1
+
+    def _on_event(self, name, **_kw):
+        if name == self._HIT:
+            self.cache_hits += 1

@@ -1,60 +1,14 @@
-"""Forward-compat shims over the jax API surfaces this framework uses.
+"""Helpers over the jax surfaces the kfprof cost gauges use.
 
-The repo targets current jax (``jax.shard_map``, ``jax.typeof``, the
-recoverable-distributed config flags — see tests/test_jax_compat.py),
-but must still import and train on the jax pinned in older images
-(0.4.x), where those names live under ``jax.experimental`` or do not
-exist.  :func:`ensure_compat` installs the aliases once, at package
-import, so every call site can use the current spelling unconditionally.
-
-Only *renames* are shimmed.  Behavioral gaps (e.g. a jax without
-``jax_enable_recoverability`` cannot promise peer death surfaces as a
-catchable error) are handled at the call site by feature-testing
-``jax.config.values`` — see ``distributed.initialize``.
+The repo runs on the one jax the image installs (0.9.0:
+``jax.shard_map``, ``jax.typeof``, ``lax.axis_size``, ``lax.pcast``,
+the recoverable-distributed config flags, a dict from
+``cost_analysis()`` — pinned by tests/test_jax_compat.py), so nothing
+here aliases or feature-tests a jax name.  What remains is behaviour:
+lowering a donating step without its donation, and a cost analysis that
+answers None where a backend has no cost model.
 """
 from __future__ import annotations
-
-
-def ensure_compat() -> None:
-    """Idempotently alias moved/renamed jax surfaces onto the current
-    names.  Safe to call any number of times, from any thread that runs
-    before the first use (kungfu_tpu/__init__ calls it at import)."""
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        # jax < 0.5: jax.experimental.shard_map.shard_map
-        from jax.experimental.shard_map import shard_map
-        jax.shard_map = shard_map
-    if not hasattr(jax.lax, "axis_size"):
-        # jax < 0.6: no lax.axis_size; the static mesh-axis size is in
-        # the trace-time axis env.  Call sites use it for loop bounds
-        # and shapes, so this MUST return a Python int (a psum(1, ...)
-        # would be traced) — axis_frame gives exactly that on 0.4.x.
-        from jax._src import core as _core
-
-        def axis_size(axis_name):
-            frame = _core.axis_frame(axis_name)
-            return int(getattr(frame, "size", frame))
-
-        jax.lax.axis_size = axis_size
-    if not hasattr(jax, "typeof"):
-        # jax < 0.6: the aval accessor is jax.core.get_aval; callers here
-        # only probe optional attrs on the result (e.g. `.vma`) via
-        # getattr-with-default, so the older aval type suffices
-        from jax.core import get_aval
-
-        def typeof(x):
-            return get_aval(x)
-
-        jax.typeof = typeof
-
-
-def config_flag_supported(flag: str) -> bool:
-    """True when this jax build knows the given config option (e.g.
-    ``jax_enable_recoverability``); ``jax.config.update`` on an unknown
-    flag raises instead of ignoring it."""
-    import jax
-    return flag in jax.config.values
 
 
 def lower_for_cost_analysis(fn, *args, **kwargs):
@@ -66,9 +20,9 @@ def lower_for_cost_analysis(fn, *args, **kwargs):
     and the throwaway AOT compile emits donation warnings (or, on some
     jaxlib builds, refuses) for buffers that are never actually
     executed.  When the lowering declares donated arguments (probed
-    through ``Lowered.args_info``, present since 0.4.x; absent means
-    not donating), re-jit the wrapped function with donation off and
-    lower that twin instead.  Falls back to the original lowering when
+    through ``Lowered.args_info``; absent means not donating), re-jit
+    the wrapped function with donation off and lower that twin
+    instead.  Falls back to the original lowering when
     the twin cannot be built (no ``__wrapped__``, e.g. a fake in
     tests), so the gauges never regress for non-donating callers."""
     import jax
@@ -91,15 +45,11 @@ def lower_for_cost_analysis(fn, *args, **kwargs):
 
 
 def compiled_cost_analysis(compiled) -> "dict | None":
-    """XLA cost analysis of an AOT-compiled step, normalized across jax
-    versions (the kfprof flops/HBM gauges, monitor/profiler.py).
-
-    ``Compiled.cost_analysis()`` returns a plain dict on current jax, a
-    one-element **list** of dicts on 0.4.x, and does not exist (or
-    raises ``NotImplementedError``) on older jaxlibs / backends without
-    a cost model.  Callers get one flat ``{"flops": ..., "bytes
-    accessed": ..., ...}`` dict, or None when this build cannot say —
-    absence of the gauges, never a crash (tests/test_jax_compat.py)."""
+    """XLA cost analysis of an AOT-compiled step (the kfprof flops/HBM
+    gauges, monitor/profiler.py): one flat ``{"flops": ..., "bytes
+    accessed": ..., ...}`` dict, or None when the object has no
+    ``cost_analysis`` or the backend has no cost model — absence of the
+    gauges, never a crash (tests/test_jax_compat.py)."""
     fn = getattr(compiled, "cost_analysis", None)
     if fn is None:
         return None
@@ -110,8 +60,6 @@ def compiled_cost_analysis(compiled) -> "dict | None":
         # (NotImplementedError, XlaRuntimeError, ...): "unknown" is an
         # expected answer here, not a failure to surface
         return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     if not isinstance(cost, dict):
         return None
     return dict(cost)

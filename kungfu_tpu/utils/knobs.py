@@ -33,7 +33,7 @@ import os
 import sys
 from typing import Dict, List, Mapping, Optional
 
-__all__ = ["Knob", "KNOBS", "get", "raw", "is_set", "generate_docs"]
+__all__ = ["Knob", "KNOBS", "get", "raw", "generate_docs"]
 
 _FALSEY = ("", "0", "false", "off", "no")
 
@@ -94,14 +94,6 @@ def raw(name: str, env: Optional[Mapping[str, str]] = None) -> Optional[str]:
     source = os.environ if env is None else env
     value = source.get(name)
     return value if value else None
-
-
-def is_set(name: str, env: Optional[Mapping[str, str]] = None) -> bool:
-    """True when the knob is present in the environment (even if empty —
-    some knobs, e.g. KFT_COMPILE_CACHE, treat bare presence as intent)."""
-    KNOBS[name]
-    source = os.environ if env is None else env
-    return name in source
 
 
 def get(name: str, env: Optional[Mapping[str, str]] = None,
@@ -247,9 +239,6 @@ _def("KFT_SNAPSHOT_BUDGET", "float", 0.05,
 _def("KFT_SNAP_CHUNK_MB", "float", 64.0,
      "Store leaves larger than this are chunked into zero-copy views.",
      group=_ELASTIC)
-_def("KFT_COMPILE_CACHE", "str", None,
-     "Compiled-executable cache directory; `0/off/none/disable` "
-     "disables, bare presence opts in on CPU.", group=_ELASTIC)
 _def("KFT_RPC_BREAKER_FAILS", "float", 3.0,
      "Consecutive transport failures before the rpc circuit breaker "
      "opens.", group=_ELASTIC)

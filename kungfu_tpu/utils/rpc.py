@@ -90,7 +90,7 @@ def _netloc(url: str) -> str:
 
 
 def classify(exc: BaseException) -> str:
-    """Map an exception from :func:`call` onto the outage taxonomy."""
+    """Map an exception from :func:`call` onto the outage classes."""
     if isinstance(exc, urllib.error.HTTPError):
         if exc.code == 404:
             return "unseeded"

@@ -75,3 +75,26 @@ def test_broadcast_host_tree_singleton_passthrough():
     out = D.broadcast_host_tree(tree, peer=None)
     assert np.array_equal(out["a"], tree["a"])
     assert np.array_equal(out["b"]["c"], tree["b"]["c"])
+
+
+def test_colocated_workers_on_a_tpu_fail_with_a_message(monkeypatch):
+    """A host's TPU chips belong to one process (libtpu): two workers on
+    one host must fail with OUR message — both the one that got the
+    chips and the one libtpu's lockfile turned away — while the CPU
+    rig's colocated workers and one-worker-per-host pods pass."""
+    import jax
+    same = ["127.0.0.1:31100", "127.0.0.1:31101"]
+    apart = [PeerID("10.0.0.1", 30000), PeerID("10.0.0.2", 30000)]
+    D.require_own_chips(same, 0)                     # cpu backend: fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    D.require_own_chips(apart, 1)                    # own host: fine
+    with pytest.raises(RuntimeError, match="one worker per host"):
+        D.require_own_chips(same, 1)
+
+    def lockfile():
+        raise RuntimeError("Unable to initialize backend 'tpu': ABORTED: "
+                           "libtpu multi-process lockfile")
+    monkeypatch.setattr(jax, "default_backend", lockfile)
+    with pytest.raises(RuntimeError, match="ROADMAP R2/S6") as ei:
+        D.require_own_chips(same, 0)
+    assert "lockfile" in str(ei.value.__cause__)

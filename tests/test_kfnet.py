@@ -66,8 +66,8 @@ def test_rate_counter_partial_first_window_reports():
     assert rc.rate(1.0) == pytest.approx(1000.0)
 
 
-# ---------------------------------------------------- target taxonomy
-def test_target_taxonomy():
+# ----------------------------------------------------- target classes
+def test_target_classes():
     assert net.control_target("h:1") == "ctrl:h:1"
     assert net.control_target("ctrl:h:1") == "ctrl:h:1"   # idempotent
     assert net.is_peer_target("10.0.0.1:7001")

@@ -88,12 +88,6 @@ def test_unregistered_name_is_a_keyerror():
         knobs.raw("KFT_NO_SUCH_KNOB", env={})
 
 
-def test_is_set_detects_presence_even_when_empty():
-    # compile_cache treats bare presence ("" included) as opt-in
-    assert knobs.is_set("KFT_COMPILE_CACHE", env={"KFT_COMPILE_CACHE": ""})
-    assert not knobs.is_set("KFT_COMPILE_CACHE", env={})
-
-
 def test_duplicate_registration_rejected():
     with pytest.raises(ValueError):
         knobs._def("KFT_BASE_PORT", "int", 1, "dup", group="Launcher")

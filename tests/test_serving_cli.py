@@ -26,7 +26,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG_FLAGS = ["--vocab", "61", "--d-model", "16", "--n-heads", "4",
              "--n-layers", "2", "--d-ff", "32", "--max-seq", "64",
              "--slots", "2", "--block", "4", "--blocks", "32",
-             "--chunk", "2", "--buckets", "8,16", "--port", "0"]
+             "--chunk", "2", "--buckets", "8,16", "--port", "0",
+             "--dtype", "float32"]
 CFG = G.GPTConfig(vocab_size=61, d_model=16, n_heads=4, n_layers=2,
                   d_ff=32, max_seq=64, dtype=jnp.float32)
 

@@ -20,7 +20,7 @@ that changed; ``--no-cache`` bypasses it.
 
 Heuristic honesty: extraction is AST-shaped, not a points-to analysis.
 Env-var names are resolved through same-file module-level string
-constants only (``CACHE_ENV = "KFT_COMPILE_CACHE"``); a name imported
+constants only (``TOKEN_ENV = "KFT_CONTROL_TOKEN"``); a name imported
 from another module is recorded unresolved and skipped by the passes.
 """
 from __future__ import annotations

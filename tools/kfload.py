@@ -68,7 +68,8 @@ from kungfu_tpu.utils import knobs  # noqa: E402
 _SERVER_ARGS = ["--vocab", "256", "--d-model", "32", "--n-heads", "2",
                 "--n-layers", "2", "--d-ff", "64", "--max-seq", "128",
                 "--slots", "4", "--block", "16", "--blocks", "64",
-                "--chunk", "4", "--buckets", "16", "--prefix-cache"]
+                "--chunk", "4", "--buckets", "16", "--prefix-cache",
+                "--dtype", "float32"]
 _READY_S = 180.0
 
 
