@@ -1,0 +1,22 @@
+"""A kernel's share of its roofline: the least time the chip could take for
+the work the algorithm needs in one step (a function of perf/work.py, from
+shapes alone) over the device time the kernel's events took per step in the
+traced stretch (`perf.trace.kernel_ns`). Nothing to read (no event that
+`pattern` finds, or no whole step in the trace) gives nothing, never 0."""
+import re
+
+from perf import trace as tracing, work
+
+
+def read(ctx, pattern: str, min_seconds: str, step_pattern: str):
+    t = ctx["trace"]
+    if t is None or not any(t.ops):
+        return None
+    step_rx = re.compile(step_pattern)
+    steps = sum(1 for name, _, _ in t.modules[0] if step_rx.search(name))
+    kernel_ns = tracing.kernel_ns(t, pattern)
+    if not kernel_ns or not steps:
+        return None
+    least = getattr(work, min_seconds)(ctx["config"], ctx["traffic"],
+                                       ctx["peaks"])
+    return 100.0 * least / (kernel_ns / steps / 1e9)
