@@ -12,9 +12,13 @@ steps — the communication schedule is compiled, not interpreted.
 """
 from __future__ import annotations
 
-import os as _os
+import time as _time
 
-from .utils import knobs as _knobs
+_import_began = _time.perf_counter()
+
+import os as _os  # noqa: E402
+
+from .utils import knobs as _knobs  # noqa: E402
 
 # kfsim lite mode: the fake trainers of kungfu_tpu/sim/ run hundreds of
 # control-plane-only processes on one box and must not pay the jax import
@@ -259,3 +263,8 @@ __all__ = [
     "build_train_step_with_state", "init_opt_state", "lane", "lane_mean",
     "replicate",
 ]
+
+# how long this import took, jax and the rest it pulls included when this
+# is the process's first use of them: the program's own part of a set-up
+# (the benchmark's `setup_import_s`)
+import_seconds = _time.perf_counter() - _import_began

@@ -180,10 +180,11 @@ def validate_tp(cfg: GPTConfig, ntp: int) -> None:
 def embed(params, tokens, pos, cfg: GPTConfig):
     """Token (+ learned position, unless RoPE) embedding.
     ``tokens`` [...,]; ``pos`` broadcastable positions."""
-    x = params["wte"][tokens]
-    if not cfg.rope:
-        x = x + params["wpe"][pos]
-    return x.astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens]
+        if not cfg.rope:
+            x = x + params["wpe"][pos]
+        return x.astype(cfg.dtype)
 
 
 @jax.checkpoint
@@ -228,15 +229,16 @@ def _layer_qkv(layer, x, cfg: GPTConfig, pos=None):
     Under GQA, k/v come out with ``kv_heads`` heads (the cache shape);
     use :func:`_expand_kv` before a full-width attend.  With RoPE, q/k
     are rotated here by the global positions ``pos``."""
-    h = rms_norm(x, layer["ln1"])
-    q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cfg.dtype))
-    kk = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cfg.dtype))
-    v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cfg.dtype))
-    if cfg.rope:
-        if pos is None:
-            raise ValueError("RoPE model needs positions in _layer_qkv")
-        q = _rope_rotate(q, pos, cfg)
-        kk = _rope_rotate(kk, pos, cfg)
+    if cfg.rope and pos is None:
+        raise ValueError("RoPE model needs positions in _layer_qkv")
+    with jax.named_scope("attn"):
+        h = rms_norm(x, layer["ln1"])
+        q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cfg.dtype))
+        kk = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cfg.dtype))
+        v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cfg.dtype))
+        if cfg.rope:
+            q = _rope_rotate(q, pos, cfg)
+            kk = _rope_rotate(kk, pos, cfg)
     return q, kk, v
 
 
@@ -278,10 +280,11 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
     ``remat_ffn`` checkpoints the norm+FFN sub-block: its internal
     activations (the [B, T, 2F] up-projection above all) are recomputed
     in the backward from ``x`` — the attention residuals stay saved."""
-    o = jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cfg.dtype))
-    if tp_axis:
-        o = lax.psum(o, tp_axis)
-    x = x + o
+    with jax.named_scope("attn"):
+        o = jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cfg.dtype))
+        if tp_axis:
+            o = lax.psum(o, tp_axis)
+        x = x + o
 
     def norm_ffn(layer, x):
         h = rms_norm(x, layer["ln2"])
@@ -291,7 +294,8 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
 
     if remat_ffn:
         norm_ffn = jax.checkpoint(norm_ffn)
-    return x + norm_ffn(layer, x)
+    with jax.named_scope("ffn"):
+        return x + norm_ffn(layer, x)
 
 
 def _attend(q, kk, v, attn: str, sp_axis: Optional[str],
@@ -353,7 +357,8 @@ def apply_layer(layer, x, cfg: GPTConfig, *,
     if remat_around_attn:
         qkv_fn = jax.checkpoint(qkv_fn)
     q, kk, v = qkv_fn(layer, x)
-    o = _attend(q, kk, v, attn, sp_axis, kv_groups=cfg.kv_groups)
+    with jax.named_scope("attn"):
+        o = _attend(q, kk, v, attn, sp_axis, kv_groups=cfg.kv_groups)
 
     finish = functools.partial(_layer_finish, cfg=cfg, tp_axis=tp_axis,
                                ffn=ffn, remat_ffn=remat_ffn)
@@ -419,7 +424,8 @@ def forward_features(params, tokens, cfg: GPTConfig, *,
     for layer in params["layers"]:
         x = layer_fn(layer, x)
 
-    return rms_norm(x, params["lnf"])
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["lnf"])
 
 
 def forward_local(params, tokens, cfg: GPTConfig, *,
@@ -528,9 +534,12 @@ def _decode_hidden(params, cfg: GPTConfig, cache, pos, token,
         kc = lax.dynamic_update_slice(kv["k"], kk, (0, pos, 0, 0))
         vc = lax.dynamic_update_slice(kv["v"], v, (0, pos, 0, 0))
         new_cache.append({"k": kc, "v": vc})
-        o = _decode_attend(q, _expand_kv(kc, cfg), _expand_kv(vc, cfg), pos)
+        with jax.named_scope("attn"):
+            o = _decode_attend(q, _expand_kv(kc, cfg), _expand_kv(vc, cfg),
+                               pos)
         x = _layer_finish(layer, x, o, cfg, tp_axis)
-    return rms_norm(x, params["lnf"]), new_cache
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["lnf"]), new_cache
 
 
 def _head(params, x):

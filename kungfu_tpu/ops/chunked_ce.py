@@ -89,12 +89,16 @@ def _online_lse(x, w, chunk):
     return m + jnp.log(s)
 
 
+@jax.named_scope("ce_head")
 def _fwd(x, w, targets, chunk):
     lse = _online_lse(x, w, chunk)
     loss = lse - _target_logit(x, w, targets)
     return loss, (x, w, targets, lse)
 
 
+# a custom_vjp's backward is traced apart from its forward, so the scope
+# is opened a second time
+@jax.named_scope("ce_head")
 def _bwd(chunk, res, g):
     x, w, targets, lse = res
     B, T, D = x.shape

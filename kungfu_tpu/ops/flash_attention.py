@@ -269,6 +269,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     out = res[0]
     lse = res[1] if with_lse else None
@@ -413,6 +414,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                                lambda b, h, iq: (b, h, iq, 0)),
         out_shape=_sds((B, H, T, _LANES), jnp.float32, q),
         interpret=interpret,
+        name="flash_bwd_delta",
     )(ot, gt)
     if dlse is not None:
         # ds = p * (dp - delta + dlse) * scale — fold dlse into the row term
@@ -428,6 +430,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=_sds(qt.shape, qt.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, gt, lse, delta)
 
     # q innermost for dk/dv: k/v block indexed by grid axis 2
@@ -448,6 +451,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, gt, lse, delta)
     back = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     return back(dq), back(dk), back(dv)

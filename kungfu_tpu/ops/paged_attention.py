@@ -179,6 +179,7 @@ def _run_kernel(qg, k_pool, v_pool, tables, pos, k_scale, v_scale,
         grid_spec=grid_spec,
         out_shape=_sds((S, KVH, R, Dh), qg.dtype, qg),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)
 
 

@@ -100,10 +100,12 @@ def _accum_grads_fn(loss_fn: Callable, axis: str, accum_steps: int,
                 (loss, aux), grads = vg(params, aux, mb)
             else:
                 loss, grads = vg(params, mb)
-            return (loss_acc + loss,
-                    jax.tree_util.tree_map(
-                        lambda a, g: a + g.astype(a.dtype), grad_acc, grads),
-                    aux), None
+            with jax.named_scope("accumulate"):
+                return (loss_acc + loss,
+                        jax.tree_util.tree_map(
+                            lambda a, g: a + g.astype(a.dtype), grad_acc,
+                            grads),
+                        aux), None
 
         # carries must carry the mesh-varying axis the per-microbatch
         # loss/grads have inside shard_map (see shard_map#scan-vma):
@@ -212,12 +214,20 @@ def build_train_step(loss_fn: Callable,
     def body(stacked_params, stacked_state, batch):
         params = jax.tree_util.tree_map(lambda t: t[0], stacked_params)
         state = jax.tree_util.tree_map(lambda t: t[0], stacked_state)
-        loss, grads = grads_of(params, batch)
-        updates, state = optimizer.update(grads, state, params)
-        params = optax.apply_updates(params, updates)
-        mean_loss = jax.lax.pmean(loss, axis)
+        # the scopes are how a device trace is read (docs/monitoring.md,
+        # "Scope names"): metadata of the program, nothing at run time.
+        # The restacking stands inside `optimizer` because XLA names a
+        # fusion after its root, and each update's is the `x[None]`
         restack = lambda t: jax.tree_util.tree_map(lambda x: x[None], t)
-        return restack(params), restack(state), mean_loss.reshape(1)
+        with jax.named_scope("grads"):
+            loss, grads = grads_of(params, batch)
+        with jax.named_scope("optimizer"):
+            updates, state = optimizer.update(grads, state, params)
+            params = optax.apply_updates(params, updates)
+            params, state = restack(params), restack(state)
+        with jax.named_scope("sync"):
+            mean_loss = jax.lax.pmean(loss, axis)
+        return params, state, mean_loss.reshape(1)
 
     sm = jax.shard_map(body, mesh=mesh,
                        in_specs=(spec, spec, spec),
@@ -265,15 +275,19 @@ def build_train_step_with_state(loss_fn: Callable,
         params = jax.tree_util.tree_map(lambda t: t[0], stacked_params)
         state = jax.tree_util.tree_map(lambda t: t[0], stacked_state)
         mstate = jax.tree_util.tree_map(lambda t: t[0], stacked_mstate)
-        (loss, new_mstate), grads = grads_of(params, mstate, batch)
-        updates, state = optimizer.update(grads, state, params)
-        params = optax.apply_updates(params, updates)
-        if sync_model_state:
-            new_mstate = C.all_reduce(new_mstate, axis, "MEAN")
-        mean_loss = jax.lax.pmean(loss, axis)
+        # the same scopes as in build_train_step
         restack = lambda t: jax.tree_util.tree_map(lambda x: x[None], t)
-        return (restack(params), restack(state), restack(new_mstate),
-                mean_loss.reshape(1))
+        with jax.named_scope("grads"):
+            (loss, new_mstate), grads = grads_of(params, mstate, batch)
+        with jax.named_scope("optimizer"):
+            updates, state = optimizer.update(grads, state, params)
+            params = optax.apply_updates(params, updates)
+            params, state = restack(params), restack(state)
+        with jax.named_scope("sync"):
+            if sync_model_state:
+                new_mstate = C.all_reduce(new_mstate, axis, "MEAN")
+            mean_loss = jax.lax.pmean(loss, axis)
+        return params, state, restack(new_mstate), mean_loss.reshape(1)
 
     sm = jax.shard_map(body, mesh=mesh,
                        in_specs=(spec, spec, spec, spec),

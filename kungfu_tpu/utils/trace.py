@@ -140,8 +140,22 @@ def capturing() -> Optional[str]:
         return _capture_dir
 
 
+def _device_only():
+    """Profiler options with the host and Python tracers off, as the
+    benchmark's traced runs have them (perf/loop.py).  With the host
+    tracer on, the runtime's transfer threads wrote 30 million events
+    for 30 ResNet-50 steps: 0.94 GB, over a minute in ``stop_trace`` and
+    a device that stalled while they drained (PERF.md, PR 25 (a))."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0
+    return options
+
+
 def start_capture(logdir: str) -> Optional[str]:
-    """Begin an XLA device trace (view in XProf/TensorBoard).
+    """Begin an XLA device trace (view in XProf/TensorBoard): device
+    events only, see :func:`_device_only`.
 
     Idempotent and exception-safe: returns the logdir on success, None
     when a capture is already running or jax.profiler refused (counted
@@ -154,7 +168,8 @@ def start_capture(logdir: str) -> Optional[str]:
             _count_capture_failure("start-busy")
             return None
         try:
-            jax.profiler.start_trace(logdir)
+            jax.profiler.start_trace(logdir,
+                                     profiler_options=_device_only())
         except Exception:
             _count_capture_failure("start")
             return None

@@ -14,6 +14,7 @@ import jax
 import pytest
 
 from kungfu_tpu.utils import compile_cache as cc
+from kungfu_tpu.utils.compile_cache import CompileCounter
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -23,7 +24,8 @@ def cache_config():
     """Restore the jax cache options a test changed."""
     names = ("jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes")
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_include_metadata_in_key")
     saved = {n: getattr(jax.config, n) for n in names}
     yield
     for n, v in saved.items():
@@ -85,7 +87,13 @@ path = enable_compile_cache()
 counter = CompileCounter()
 jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((8, 8))).block_until_ready()
 print(json.dumps({"path": path, "config": jax.config.jax_compilation_cache_dir,
-                  "compiled": counter.compiled, "hits": counter.cache_hits}))
+                  "compiled": counter.compiled, "hits": counter.cache_hits,
+                  "trace": counter.seconds(counter.TRACE),
+                  "lower": counter.seconds(counter.LOWER),
+                  "request": counter.seconds(counter.REQUEST),
+                  "retrieval": counter.seconds(counter.RETRIEVAL),
+                  "compile": counter.compile_seconds(),
+                  "events": [name for name, _, _ in counter.records]}))
 """
 
 
@@ -107,6 +115,100 @@ def test_two_processes_share_the_env_directory(tmp_path):
     assert cold["compiled"] >= 1 and cold["hits"] == 0
     assert warm["compiled"] == 0 and warm["hits"] == cold["compiled"]
     assert os.listdir(tmp_path)
+
+
+def test_the_counter_says_what_each_stage_took(tmp_path):
+    """Cold, the backend's seconds are compiling; warm, they are the
+    cache's retrieval and compiling reads 0: the benchmark's
+    `setup_compile_s` and `setup_cache_load_s`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    cold, warm = _child(env), _child(env)
+    for run in (cold, warm):
+        assert run["trace"] > 0 and run["lower"] > 0 and run["request"] > 0
+        assert set(run["events"]) <= set(CompileCounter.STAGE_EVENTS)
+    assert cold["retrieval"] == 0 and cold["compile"] == cold["request"]
+    assert warm["compiled"] == 0 and warm["compile"] == 0
+    assert 0 < warm["retrieval"] <= warm["request"]
+    # a hit's retrieval arrives just before the request that holds it
+    at = warm["events"].index(CompileCounter.RETRIEVAL)
+    assert warm["events"][at + 1] == CompileCounter.REQUEST
+
+
+def _feed(event, seconds, at_ns, monkeypatch):
+    monkeypatch.setattr(cc.time, "perf_counter_ns", lambda: at_ns)
+    jax.monitoring.record_event_duration_secs(event, seconds)
+
+
+def test_the_counter_sums_spans_up_to_a_moment(monkeypatch):
+    counter = CompileCounter()
+    assert cc.current_counter() is counter
+    S = 10 ** 9
+    # an inner jit's tracing inside its caller's, then one apart
+    _feed(counter.TRACE, 1.0, 4 * S, monkeypatch)
+    _feed(counter.TRACE, 3.0, 5 * S, monkeypatch)
+    _feed(counter.TRACE, 0.5, 9 * S, monkeypatch)
+    _feed(counter.LOWER, 0.25, 10 * S, monkeypatch)
+    _feed("/jax/some/other_duration", 7.0, 10 * S, monkeypatch)
+    assert [name for name, _, _ in counter.records] == [
+        counter.TRACE] * 3 + [counter.LOWER]
+    assert counter.seconds(counter.TRACE, counter.LOWER) == \
+        pytest.approx(3.75)
+    assert counter.seconds(counter.TRACE) == pytest.approx(3.5)
+    assert counter.seconds(counter.TRACE, until_ns=5 * S) == \
+        pytest.approx(3.0)
+    assert counter.seconds(counter.TRACE, until_ns=3 * S) == 0
+    assert counter.seconds(counter.LOWER) == pytest.approx(0.25)
+    # jax traces again while it lowers: over both events, counted once
+    _feed(counter.TRACE, 0.125, 10 * S - S // 16, monkeypatch)
+    assert counter.seconds(counter.TRACE, counter.LOWER) == \
+        pytest.approx(3.75)
+    # one program from the cache, one compiled, one compiled later
+    _feed(counter.RETRIEVAL, 0.5, 11 * S, monkeypatch)
+    _feed(counter.REQUEST, 0.75, 11 * S, monkeypatch)
+    _feed(counter.REQUEST, 20.0, 40 * S, monkeypatch)
+    _feed(counter.REQUEST, 2.0, 60 * S, monkeypatch)
+    assert counter.seconds(counter.RETRIEVAL) == pytest.approx(0.5)
+    assert counter.compile_seconds() == pytest.approx(22.0)
+    assert counter.compile_seconds(until_ns=50 * S) == pytest.approx(20.0)
+    assert counter.compile_seconds(until_ns=11 * S) == 0
+
+
+def test_the_counters_records_are_bounded(monkeypatch):
+    monkeypatch.setattr(CompileCounter, "MAX_RECORDS", 3)
+    counter = CompileCounter()
+    for i in range(5):
+        _feed(counter.LOWER, 1.0, (i + 1) * 10 ** 10, monkeypatch)
+    assert len(counter.records) == 3
+    assert counter.seconds(counter.LOWER) == pytest.approx(3.0)
+
+
+def test_the_newest_counter_is_the_current_one():
+    first, second = CompileCounter(), CompileCounter()
+    assert cc.current_counter() is second is not first
+
+
+def test_names_are_in_the_caches_key_unless_the_environment_says(
+        monkeypatch, cache_config, tmp_path):
+    """A program whose scopes were renamed must not load the executable
+    compiled under the old names (docs/monitoring.md)."""
+    option = "jax_compilation_cache_include_metadata_in_key"
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path))
+    monkeypatch.delenv(option.upper(), raising=False)
+    jax.config.update(option, False)
+    cc.enable_compile_cache()
+    assert getattr(jax.config, option) is True
+    monkeypatch.setenv(option.upper(), "0")
+    jax.config.update(option, False)
+    cc.enable_compile_cache()
+    assert getattr(jax.config, option) is False
+
+
+def test_the_package_says_how_long_its_import_took():
+    """0.4 s alone on this box once jax is loaded (conftest.py has), 2.9 s
+    with it: the benchmark's `setup_import_s` reads the attribute."""
+    import kungfu_tpu
+    assert 0 < kungfu_tpu.import_seconds < 5
 
 
 def test_no_knob_and_no_home_directory_default():

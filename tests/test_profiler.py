@@ -107,6 +107,24 @@ def test_capture_idempotent_and_counted(tmp_path):
     assert utrace.stop_capture() is None          # idempotent
 
 
+def test_capture_takes_device_events_only(tmp_path, monkeypatch):
+    """An operator's /profile gets the trace the benchmark gets: with the
+    host tracer on, 30 ResNet-50 steps wrote 0.94 GB (PERF.md)."""
+    import jax
+    from kungfu_tpu.utils import trace as utrace
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda logdir, **kw: seen.update(logdir=logdir, **kw))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    logdir = str(tmp_path / "t")
+    assert utrace.start_capture(logdir) == logdir
+    assert utrace.stop_capture() == logdir
+    options = seen["profiler_options"]
+    assert seen["logdir"] == logdir
+    assert (options.host_tracer_level, options.python_tracer_level) == (0, 0)
+
+
 def test_capture_context_does_not_stop_foreign_capture(tmp_path):
     from kungfu_tpu.utils import trace as utrace
     own = str(tmp_path / "own")
