@@ -173,6 +173,25 @@ def _mixed_precision(grads_of: Callable, compute_dtype, has_aux: bool):
     return wrapped
 
 
+def _compiler_options(mesh: Mesh) -> dict:
+    """XLA options for the accumulating step's program, by the mesh's
+    platform: on TPU the memory scheduler is pinned to its list order.
+
+    Left to itself XLA orders the program three ways (list, DFS,
+    post-order) and keeps the order whose *estimated* peak is lowest.  For
+    a step that sums gradients over microbatches the estimates tie (each
+    order ends holding every gradient sum and the last gradient), a few
+    MB decide, and what buffer assignment then needs differs by over a
+    gigabyte: for one and the same Mistral step 8.36 GB of temporaries in
+    DFS order against 6.93 GB in list order, which is the order built to
+    keep memory low (PERF.md section 6, PR 28).  Pinning it keeps the
+    step's memory from moving with an edit somewhere else in the model.
+    Other backends do not know the option."""
+    if mesh.devices.flat[0].platform != "tpu":
+        return {}
+    return {"xla_memory_scheduler": "list"}
+
+
 def build_train_step(loss_fn: Callable,
                      optimizer: optax.GradientTransformation,
                      mesh: Optional[Mesh] = None,
@@ -233,7 +252,8 @@ def build_train_step(loss_fn: Callable,
                        in_specs=(spec, spec, spec),
                        out_specs=(spec, spec, spec))
     jit_kwargs = {"donate_argnums": (0, 1)} if donate else {}
-    jitted = jax.jit(sm, **jit_kwargs)
+    jitted = jax.jit(sm, compiler_options=_compiler_options(mesh),
+                     **jit_kwargs)
 
     def step(stacked_params, stacked_state, global_batch):
         p, s, losses = jitted(stacked_params, stacked_state, global_batch)

@@ -196,3 +196,18 @@ def test_resnet_accumulation_matches_sequential_microbatches():
     tree_allclose(jax.tree_util.tree_map(lambda t: np.asarray(t)[0], sms2),
                   ms)
     assert np.isfinite(float(np.asarray(loss2)[0]))
+
+
+@pytest.mark.parametrize("platform, options", [
+    ("cpu", {}), ("tpu", {"xla_memory_scheduler": "list"})])
+def test_the_accumulating_steps_compiler_options(platform, options):
+    """Only a TPU mesh pins XLA's memory scheduler; the CPU backend does
+    not know the option and must be handed none (every other test here
+    compiles through it)."""
+    import types
+
+    from kungfu_tpu.training import _compiler_options
+    device = types.SimpleNamespace(platform=platform)
+    mesh = types.SimpleNamespace(devices=np.array([device], dtype=object))
+    assert _compiler_options(mesh) == options
+    assert _compiler_options(flat_mesh(jax.devices()[:1])) == {}

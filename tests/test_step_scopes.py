@@ -29,7 +29,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GPT = GPTConfig(vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_ff=128,
                 max_seq=128, n_kv_heads=2, rope=True, mlp="swiglu")
 GPT_METRICS = ["forward_ms.gpt", "backward_ms.gpt", "recompute_ms.gpt",
-               "optimizer_ms.gpt", "accumulate_ms.gpt", "ce_head_ms.gpt"]
+               "optimizer_ms.gpt", "accumulate_ms.gpt", "ce_head_ms.gpt",
+               "ce_head_other_ms.gpt"]
 FLASH_METRICS = ["flash_fwd_ms.gpt", "flash_bwd_ms.gpt"]
 RESNET_METRICS = ["forward_ms.resnet", "backward_ms.resnet"]
 
