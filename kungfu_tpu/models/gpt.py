@@ -68,6 +68,18 @@ class GPTConfig:
     # than a [D, d_ff, 2] layout whose minor dim is 2 on v5e) and tensor
     # parallelism shards d_ff with gate/up pairs kept together)
     mlp: str = "gelu"
+    # what a published configuration states beside its widths: the eps
+    # inside every RMS norm and the base of the rotary frequencies
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    # a second RMS norm on each sub-block's OUTPUT, before the residual
+    # add (leaves ``ln1_out``, ``ln2_out``): x + N(Attn(N(x))), then
+    # a + N(FFN(N(a)))
+    out_norms: bool = False
+    # how many times the whole stack runs under its one set of weights,
+    # each round starting from the last one's normed output
+    # (models/looped.py trains it; nothing here decodes it)
+    n_rounds: int = 1
 
     def __post_init__(self):
         if self.d_model % self.n_heads != 0:
@@ -85,6 +97,8 @@ class GPTConfig:
         if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(f"mlp must be 'gelu' or 'swiglu', "
                              f"got {self.mlp!r}")
+        if self.n_rounds < 1:
+            raise ValueError(f"n_rounds must be >= 1, got {self.n_rounds}")
 
     @property
     def head_dim(self) -> int:
@@ -114,9 +128,13 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
         return (jax.random.normal(key, shape, jnp.float32)
                 / np.sqrt(fan_in))
 
+    out_norms = ({"ln1_out": jnp.ones((D,), jnp.float32),
+                  "ln2_out": jnp.ones((D,), jnp.float32)}
+                 if cfg.out_norms else {})
     layers: List[Dict] = []
     for _ in range(cfg.n_layers):
         layers.append({
+            **out_norms,
             "ln1": jnp.ones((D,), jnp.float32),
             "wq": dense(next(k), (D, H, Dh), D),
             "wk": dense(next(k), (D, Hkv, Dh), D),
@@ -144,8 +162,11 @@ def param_specs(cfg: GPTConfig, tp: Optional[str] = "tp") -> Dict:
     ``tp=None`` replicates everything (pure dp/sp)."""
     t = tp
 
+    out_norms = {"ln1_out": P(), "ln2_out": P()} if cfg.out_norms else {}
+
     def layer_specs():
         return {
+            **out_norms,
             "ln1": P(),
             "wq": P(None, t, None),
             "wk": P(None, t, None),
@@ -187,15 +208,20 @@ def embed(params, tokens, pos, cfg: GPTConfig):
         return x.astype(cfg.dtype)
 
 
-@jax.checkpoint
 def rms_norm(x, scale, eps=1e-5):
-    """RMS layernorm in f32 (bias-free).
+    """RMS layernorm in f32 (bias-free); ``eps`` is ``GPTConfig.norm_eps``
+    wherever a configuration is at hand.
 
     jax.checkpoint because the autodiff of the f32 upcast otherwise saves
     TWO f32 copies of the activation per call (the upcast and the
     normalized product — print_saved_residuals showed them dominating
     layer memory); recomputing the norm from ``x`` in the backward is two
     cheap bandwidth passes."""
+    return _rms_norm(x, scale, eps)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(2,))
+def _rms_norm(x, scale, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
@@ -209,7 +235,7 @@ def _rope_rotate(t, pos, cfg: GPTConfig):
     positions — the continuous-batching decode path, where every slot
     sits at a different depth)."""
     half = cfg.head_dim // 2
-    freqs = 10000.0 ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[..., None] * freqs  # [(B,) T, half]
     # angles/cos/sin in f32 (position precision); the big tensor math
     # runs in rope_dtype — default the activation dtype (an f32
@@ -232,7 +258,7 @@ def _layer_qkv(layer, x, cfg: GPTConfig, pos=None):
     if cfg.rope and pos is None:
         raise ValueError("RoPE model needs positions in _layer_qkv")
     with jax.named_scope("attn"):
-        h = rms_norm(x, layer["ln1"])
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cfg.dtype))
         kk = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cfg.dtype))
         v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cfg.dtype))
@@ -284,13 +310,17 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
         o = jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cfg.dtype))
         if tp_axis:
             o = lax.psum(o, tp_axis)
+        if cfg.out_norms:
+            o = rms_norm(o, layer["ln1_out"], cfg.norm_eps)
         x = x + o
 
     def norm_ffn(layer, x):
-        h = rms_norm(x, layer["ln2"])
-        if ffn is not None:
-            return ffn(layer, h)
-        return _dense_ffn(layer, h, cfg, tp_axis)
+        h = rms_norm(x, layer["ln2"], cfg.norm_eps)
+        m = (ffn(layer, h) if ffn is not None
+             else _dense_ffn(layer, h, cfg, tp_axis))
+        if cfg.out_norms:
+            m = rms_norm(m, layer["ln2_out"], cfg.norm_eps)
+        return m
 
     if remat_ffn:
         norm_ffn = jax.checkpoint(norm_ffn)
@@ -367,29 +397,15 @@ def apply_layer(layer, x, cfg: GPTConfig, *,
     return finish(layer, x, o)
 
 
-def forward_features(params, tokens, cfg: GPTConfig, *,
-                     tp_axis: Optional[str] = None,
-                     sp_axis: Optional[str] = None,
-                     attn: str = "auto",
-                     remat: bool = False):
-    """Transformer stack on this device's shard → post-norm features
-    [B_local, T_local, D] (everything except the LM head).  With an
-    UNSHARDED head (no ``tp_axis``), feed these to
-    ``ops.chunked_ce.chunked_cross_entropy`` to train without ever
-    materializing [B, T, V] logits; under tensor parallelism use
-    ``parallel_cross_entropy`` on the vocab-sharded logits instead.
-
-    ``tokens``: [B_local, T_local] int32.  With ``sp_axis`` the global
-    sequence is the rank-order concatenation of shards; with ``tp_axis``
-    the head/feature dims hold the local slice and (in forward_local) the
-    returned logits are vocab-sharded ``[B_local, T_local, V/tp]``.
-
-    ``attn``: "ring" | "ring_flash" | "ulysses" (these need ``sp_axis``) |
-    "flash" (Pallas kernel) | "dense"; "auto" = ring (flash-chunked on
-    TPU) when sequence-parallel, else the flash kernel on TPU when the
-    sequence tiles into its blocks (~1.5x dense throughput and no [T, T]
-    materialization), else dense.
-    """
+def layer_stack(params, tokens, cfg: GPTConfig, *,
+                tp_axis: Optional[str] = None,
+                sp_axis: Optional[str] = None,
+                attn: str = "auto",
+                remat: bool = False):
+    """``(x, run)``: the embedded tokens [B_local, T_local, D] and
+    ``run(x) -> x``, one pass through every layer of ``params`` — what
+    :func:`forward_features` does once and ``models/looped.py`` once a
+    round.  Arguments as in :func:`forward_features`."""
     T = tokens.shape[1]
     if attn == "auto":
         def _flash_ok():
@@ -421,11 +437,50 @@ def forward_features(params, tokens, cfg: GPTConfig, *,
         layer_fn = jax.checkpoint(layer_fn)
     elif remat not in (False, None, "", "none", "ffn", "attn"):
         raise ValueError(f"unknown remat mode {remat!r}")
-    for layer in params["layers"]:
-        x = layer_fn(layer, x)
 
+    def run(x):
+        for layer in params["layers"]:
+            x = layer_fn(layer, x)
+        return x
+    return x, run
+
+
+def forward_features(params, tokens, cfg: GPTConfig, *,
+                     tp_axis: Optional[str] = None,
+                     sp_axis: Optional[str] = None,
+                     attn: str = "auto",
+                     remat: bool = False):
+    """Transformer stack on this device's shard → post-norm features
+    [B_local, T_local, D] (everything except the LM head).  With an
+    UNSHARDED head (no ``tp_axis``), feed these to
+    ``ops.chunked_ce.chunked_cross_entropy`` to train without ever
+    materializing [B, T, V] logits; under tensor parallelism use
+    ``parallel_cross_entropy`` on the vocab-sharded logits instead.
+
+    ``tokens``: [B_local, T_local] int32.  With ``sp_axis`` the global
+    sequence is the rank-order concatenation of shards; with ``tp_axis``
+    the head/feature dims hold the local slice and (in forward_local) the
+    returned logits are vocab-sharded ``[B_local, T_local, V/tp]``.
+
+    ``attn``: "ring" | "ring_flash" | "ulysses" (these need ``sp_axis``) |
+    "flash" (Pallas kernel) | "dense"; "auto" = ring (flash-chunked on
+    TPU) when sequence-parallel, else the flash kernel on TPU when the
+    sequence tiles into its blocks (~1.5x dense throughput and no [T, T]
+    materialization), else dense.
+    """
+    _one_round_only(cfg, "forward_features")
+    x, run = layer_stack(params, tokens, cfg, tp_axis=tp_axis,
+                         sp_axis=sp_axis, attn=attn, remat=remat)
+    x = run(x)
     with jax.named_scope("final_norm"):
-        return rms_norm(x, params["lnf"])
+        return rms_norm(x, params["lnf"], cfg.norm_eps)
+
+
+def _one_round_only(cfg: GPTConfig, what: str) -> None:
+    if cfg.n_rounds != 1:
+        raise ValueError(f"{what} runs the layers once; n_rounds="
+                         f"{cfg.n_rounds} is trained by models/looped.py "
+                         f"and has no decode path")
 
 
 def forward_local(params, tokens, cfg: GPTConfig, *,
@@ -526,6 +581,7 @@ def _decode_hidden(params, cfg: GPTConfig, cache, pos, token,
     Under ``tp_axis`` the cache and q/k/v hold the local head shard and
     the per-layer psums restore replicated activations — the same
     Megatron sharding as training."""
+    _one_round_only(cfg, "_decode_hidden")
     x = embed(params, token[:, None], pos, cfg)               # [B, 1, D]
     pos1 = jnp.reshape(pos, (1,))
     new_cache = []
@@ -539,7 +595,7 @@ def _decode_hidden(params, cfg: GPTConfig, cache, pos, token,
                                pos)
         x = _layer_finish(layer, x, o, cfg, tp_axis)
     with jax.named_scope("final_norm"):
-        return rms_norm(x, params["lnf"]), new_cache
+        return rms_norm(x, params["lnf"], cfg.norm_eps), new_cache
 
 
 def _head(params, x):

@@ -83,6 +83,7 @@ def forward_local(params, tokens, cfg: MoEGPTConfig,
     """Local forward → (logits [B, T, V], mean aux loss).  Without
     ``ep_axis`` each rank holds all experts (the oracle)."""
     g = cfg.gpt
+    G._one_round_only(g, "moe_gpt.forward_local")
     T = tokens.shape[1]
     pos = jnp.arange(T)
     x = G.embed(params, tokens, pos[None], g)
@@ -100,7 +101,7 @@ def forward_local(params, tokens, cfg: MoEGPTConfig,
     for layer in params["layers"]:
         ffn = moe_ffn_cb if "moe" in layer else None
         x = G.apply_layer(layer, x, g, attn=attn, ffn=ffn, pos=pos)
-    x = G.rms_norm(x, params["lnf"])
+    x = G.rms_norm(x, params["lnf"], g.norm_eps)
     logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
                         params["lm_head"])
     aux_total = (sum(aux_acc) / len(aux_acc)) if aux_acc else jnp.float32(0.)

@@ -184,6 +184,7 @@ def make_gpt_pp_train_step(cfg: G.GPTConfig,
     either way); for M < S it is (M-1) + S*v ticks, still below
     GPipe's overhead but short of the asymptotic formula.
     """
+    G._one_round_only(cfg, "make_gpt_pp_train_step")
     tp = TP_AXIS if TP_AXIS in mesh.axis_names else None
     specs = pp_param_specs(cfg, tp=tp)
     data_spec = P(DP_AXIS, None)
@@ -279,7 +280,7 @@ def make_gpt_pp_train_step(cfg: G.GPTConfig,
 
             def head_nll(y_tgt):
                 y, tgt = y_tgt
-                xf = G.rms_norm(y, p["lnf"])
+                xf = G.rms_norm(y, p["lnf"], cfg.norm_eps)
                 logits = jnp.einsum("btd,dv->btv", xf.astype(jnp.float32),
                                     p["lm_head"])
                 return G.parallel_cross_entropy(logits, tgt,
@@ -332,7 +333,7 @@ def make_gpt_pp_train_step(cfg: G.GPTConfig,
 
             def head_nll(y_tgt):
                 y, tgt = y_tgt
-                xf = G.rms_norm(y, p["lnf"])
+                xf = G.rms_norm(y, p["lnf"], cfg.norm_eps)
                 logits = jnp.einsum("btd,dv->btv", xf.astype(jnp.float32),
                                     p["lm_head"])  # vocab-sharded under tp
                 return G.parallel_cross_entropy(logits, tgt,

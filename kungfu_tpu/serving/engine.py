@@ -184,7 +184,7 @@ def _decode_core(params, cfg: GPTConfig, block_size: int, pools, tables,
         new_pools.append(pool)
         o = pool_attend(q, pool, tables, pos, mode=attend_mode)
         x = G._layer_finish(layer, x, o, cfg, tp_axis)
-    x = G.rms_norm(x, params["lnf"])
+    x = G.rms_norm(x, params["lnf"], cfg.norm_eps)
     return G.tp_head(params, x, tp_axis), new_pools    # [S, V] f32
 
 
@@ -349,7 +349,7 @@ def _make_verify(cfg: GPTConfig, block_size: int, K: int,
             o = pool_attend_queries(q, pool, tables, qpos,
                                     mode=attend_mode)     # [S, Q, H, Dh]
             x = G._layer_finish(layer, x, o, cfg, tp_axis_)
-        x = G.rms_norm(x, params["lnf"])
+        x = G.rms_norm(x, params["lnf"], cfg.norm_eps)
         S = x.shape[0]
         # G.tp_head is the ONE tp-logits implementation (vocab-gather
         # convention lives there); fold Q into the batch to reuse it
@@ -431,7 +431,7 @@ def _make_prefill(cfg: GPTConfig, block_size: int, group: int,
             # the psum in _layer_finish restores replicated activations
             o = G._attend(q, kk, v, "dense", None, kv_groups=cfg.kv_groups)
             x = G._layer_finish(layer, x, o, cfg, tp_axis_)
-        x = G.rms_norm(x, params["lnf"])
+        x = G.rms_norm(x, params["lnf"], cfg.norm_eps)
         h_last = jnp.take_along_axis(
             x, jnp.maximum(t_real - 1, 0)[:, None, None], axis=1)
         logits = G.tp_head(params, h_last, tp_axis_)     # [G, V]
@@ -494,7 +494,7 @@ def _make_prefill_cached(cfg: GPTConfig, block_size: int, group: int,
             o = pool_attend_queries(q, pool, table_rows, qpos,
                                     mode="gather")
             x = G._layer_finish(layer, x, o, cfg, tp_axis_)
-        x = G.rms_norm(x, params["lnf"])
+        x = G.rms_norm(x, params["lnf"], cfg.norm_eps)
         h_last = jnp.take_along_axis(
             x, jnp.maximum(t_real - 1, 0)[:, None, None], axis=1)
         logits = G.tp_head(params, h_last, tp_axis_)     # [G, V]
@@ -574,6 +574,7 @@ class DecodeEngine:
         if attend not in ("auto", "fused", "gather"):
             raise ValueError(f"attend must be auto|fused|gather, "
                              f"got {attend!r}")
+        G._one_round_only(cfg, "DecodeEngine")
         quant = kv_dtype == jnp.int8
         if kv_dtype is not None and not quant:
             raise ValueError("kv_dtype must be None (model dtype) or "

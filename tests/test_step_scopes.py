@@ -5,6 +5,7 @@ benchmark's per-layer metrics look for, the program is still called
 no output. All on the CPU: a scope is metadata of the program, the same
 whatever compiles it."""
 import contextlib
+import dataclasses
 import glob
 import json
 import os
@@ -18,6 +19,7 @@ import pytest
 
 import kungfu_tpu.optimizers as kfopt
 from kungfu_tpu.comm.mesh import flat_mesh
+from kungfu_tpu.models import looped
 from kungfu_tpu.models.gpt import GPTConfig, forward_features, init_params
 from kungfu_tpu.models.resnet import ResNet
 from kungfu_tpu.ops.chunked_ce import chunked_cross_entropy
@@ -31,7 +33,15 @@ GPT = GPTConfig(vocab_size=256, d_model=64, n_heads=4, n_layers=2, d_ff=128,
 GPT_METRICS = ["forward_ms.gpt", "backward_ms.gpt", "recompute_ms.gpt",
                "optimizer_ms.gpt", "accumulate_ms.gpt", "ce_head_ms.gpt",
                "ce_head_other_ms.gpt"]
-FLASH_METRICS = ["flash_fwd_ms.gpt", "flash_bwd_ms.gpt"]
+FLASH_METRICS = ["flash_fwd_ms.gpt", "flash_bwd_ms.gpt",
+                 "flash_fwd_ms.ouro", "flash_bwd_ms.ouro"]
+# the looped decoder: the same layers, four rounds under one set of weights
+OURO = dataclasses.replace(GPT, n_kv_heads=4, norm_eps=1e-6, rope_theta=1e6,
+                           out_norms=True, n_rounds=4)
+OURO_METRICS = ["forward_ms.ouro", "backward_ms.ouro", "recompute_ms.ouro",
+                "optimizer_ms.ouro", "accumulate_ms.ouro", "ce_head_ms.ouro",
+                "ce_head_other_ms.ouro", "exit_ms.ouro",
+                "loop_other_ms.ouro"]
 RESNET_METRICS = ["forward_ms.resnet", "backward_ms.resnet"]
 
 
@@ -77,6 +87,21 @@ def make_gpt_step(mesh):
                   (tokens, jnp.roll(tokens, -1, axis=1)))
 
 
+def make_ouro_step(mesh):
+    def loss_fn(p, batch):
+        tokens, targets = batch
+        return looped.loss_fn(p, tokens, targets, OURO, beta=0.1,
+                              ce_chunk=128, attn="flash", remat="full")
+
+    opt = kfopt.synchronous_sgd(optax.adamw(1e-3))
+    step = build_train_step(loss_fn, opt, mesh, donate=False, accum_steps=2,
+                            compute_dtype=jnp.bfloat16)
+    params = replicate(looped.init_params(jax.random.PRNGKey(0), OURO), mesh)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
+    return step, (params, init_opt_state(opt, params, mesh),
+                  (tokens, jnp.roll(tokens, -1, axis=1)))
+
+
 def make_resnet_step(mesh):
     model = ResNet(stage_sizes=[1, 1], num_classes=10, num_filters=8)
 
@@ -98,7 +123,8 @@ def make_resnet_step(mesh):
                   (images, jnp.arange(4, dtype=jnp.int32)))
 
 
-MAKERS = {"gpt": make_gpt_step, "resnet": make_resnet_step}
+MAKERS = {"gpt": make_gpt_step, "resnet": make_resnet_step,
+          "ouro": make_ouro_step}
 
 
 @pytest.fixture(scope="module")
@@ -116,7 +142,8 @@ def names(steps):
 
 # the flash kernels run only on the chip inside shard_map (the CPU takes the
 # jnp path there): their names are checked on the lowered kernels below
-@pytest.mark.parametrize("metric", GPT_METRICS + RESNET_METRICS)
+@pytest.mark.parametrize("metric", GPT_METRICS + RESNET_METRICS
+                         + OURO_METRICS)
 def test_the_step_has_what_each_metric_reads(names, metric):
     family = metric.rsplit(".", 1)[1]
     assert found(scope_metrics()[metric], names[family])
@@ -124,10 +151,10 @@ def test_the_step_has_what_each_metric_reads(names, metric):
 
 def test_every_scope_metric_of_the_manifest_is_covered_here():
     assert set(scope_metrics()) == set(GPT_METRICS + FLASH_METRICS
-                                       + RESNET_METRICS)
+                                       + RESNET_METRICS + OURO_METRICS)
 
 
-@pytest.mark.parametrize("which", ["gpt", "resnet"])
+@pytest.mark.parametrize("which", ["gpt", "resnet", "ouro"])
 def test_the_builders_scopes(names, which):
     has = lambda rx: any(re.search(rx, n) for n in names[which])
     for scope in ("grads", "optimizer", "sync"):
@@ -151,13 +178,36 @@ def test_gpt_scopes_by_pass(names):
                 if "transpose(" in n or "rematted_computation" in n]
 
 
+def test_ouro_scopes_stand_inside_the_round_loop(names):
+    """`ut_loop` stands around the scan of rounds, so jax's marks of the
+    pass stand on it and the layers' scopes inside the loop's body: the
+    pass metrics read the rounds as they read a plain stack, and the
+    loop's own operations carry the program's name, not only `while`."""
+    has = lambda rx: any(re.search(rx, n) for n in names["ouro"])
+    for scope in ("attn", "ffn", "final_norm"):
+        assert has(rf"jvp\(ut_loop\)/while/body/.*/{scope}/"), scope
+    assert has(r"transpose\(jvp\(ut_loop\)\)/while/body/.*"
+               r"rematted_computation/ffn/")
+    assert has(r"jvp\(exit_gate\)") and has(r"jvp\(exit_mix\)")
+    # the heads' map is a loop too, under the head's name
+    assert has(r"jvp\(ce_head\)/while/body/.*/ce_head/")
+    assert has(r"transpose\(jvp\(ce_head\)\)/while/body/dynamic_")
+    other = found(scope_metrics()["loop_other_ms.ouro"], names["ouro"])
+    assert [n for n in other if n.endswith("/while/body/dynamic_slice")]
+    assert [n for n in other if n.endswith("closed_call/add_any")]
+    assert not [n for n in other
+                if re.search("attn|ffn|final_norm|ce_head", n)]
+    # no loop of the model's is left without a name of the program's
+    assert not has(r"jvp\(\)\)?/while")
+
+
 def test_resnet_passes_are_told_by_jaxs_own_marks(names):
     has = lambda rx: any(re.search(rx, n) for n in names["resnet"])
     assert has(r"/grads/jvp\(ResNet\)/BottleneckBlock_0/Conv_\d+/")
     assert has(r"/grads/transpose\(jvp\(ResNet\)\)/.*/BatchNorm_\d+/")
 
 
-@pytest.mark.parametrize("which", ["gpt", "resnet"])
+@pytest.mark.parametrize("which", ["gpt", "resnet", "ouro"])
 def test_the_program_is_still_jit_body(steps, which):
     # the accepted step_device_ms.* and flash_roofline find the step's
     # executions in the trace by this name
@@ -214,10 +264,13 @@ def test_the_flash_kernels_carry_their_names(flash_grad, kernel):
 @pytest.mark.parametrize("metric", FLASH_METRICS)
 def test_the_flash_metrics_find_the_kernels_by_name(metric):
     # what the trace's label of a kernel looks like on the chip
-    labels = [f"jit(body)/grads/while/body/closed_call/jvp(attn)/{k}/"
+    # (inside the looped decoder's scan the mark stands on the loop)
+    under = ("jvp(attn)" if metric.endswith(".gpt")
+             else "jvp(ut_loop)/while/body/closed_call/attn")
+    labels = [f"jit(body)/grads/while/body/closed_call/{under}/{k}/"
               "pallas_call" for k in FLASH_KERNELS]
     kept = found(scope_metrics()[metric], labels)
-    assert kept == ([labels[0]] if metric == "flash_fwd_ms.gpt"
+    assert kept == ([labels[0]] if metric.startswith("flash_fwd_ms")
                     else labels[1:])
 
 
