@@ -19,10 +19,18 @@ and accumulates
     dv += p^T dO,   ds = p * (dO v^T - delta),   dk += ds^T q * s,
     dq += ds k * s,        with  delta = rowsum(dO * O)
 
-in two kernels (dq with k innermost; dk/dv with q innermost); ``delta``
-is precomputed once per row by a tiny third kernel (lane-replicated like
-lse), so training memory stays O(T * D) — no [T, T] materialization
-anywhere.
+in ONE kernel (q innermost; dk/dv in block accumulators, dq in a [T, D]
+f32 scratch that holds the whole sequence's dq of one (batch, head)) where
+that scratch fits its VMEM budget (:func:`_fused_backward`: T <= 8192 at
+heads of 128), so each tile's p and ds are made once: five matmuls a tile.
+Longer sequences run two kernels (dq with k innermost beside dk/dv with q
+innermost: seven matmuls a tile).  ``delta`` is precomputed once per row
+by a tiny kernel of its own (lane-replicated like lse), so training
+memory stays O(T * D) — no [T, T] materialization anywhere.
+
+Causal grids step over the tiles above the diagonal without running or
+fetching them: the index maps (:func:`_k_block_index`,
+:func:`_q_block_index`) name the neighbouring visible block again.
 
 On CPU (tests, CI) the kernels run with ``interpret=True``.
 """
@@ -68,7 +76,7 @@ def _mask_skip() -> bool:
 
 def _causal_tile_classes(iq, ik, block_q, block_k):
     """Classify tile (iq, ik) against the causal diagonal — the single
-    source of truth for all three kernels (fwd, bwd-dq, bwd-dkv).
+    source of truth for all the kernels (fwd, bwd-dq, bwd-dkv).
     Returns (below, on_diag, visible): ``below`` = every key position in
     the tile visible to every query (no mask needed), ``on_diag`` =
     straddles the diagonal (mask required), ``visible`` = any pair
@@ -104,6 +112,28 @@ def _causal_dispatch(body, causal, iq, ik, block_q, block_k):
         @pl.when(visible)
         def _():
             body(masked=True)
+
+
+def _k_block_index(causal, block_q, block_k):
+    """The k-side block a grid step (iq, ik) asks for.  Causal: never a
+    block above the diagonal — a step that runs nothing names the last
+    visible k block of its q row again, and a repeated index is not
+    fetched again (the dead step costs a grid step and no DMA)."""
+    if not causal:
+        return lambda iq, ik: ik
+    return lambda iq, ik: jnp.minimum(
+        ik, (iq * block_q + block_q - 1) // block_k)
+
+
+def _q_block_index(causal, block_q, block_k, n_q):
+    """The q-side block (q, dO, lse, delta) a grid step (iq, ik) asks
+    for; causal: the first visible q block of the k column in place of
+    one above the diagonal (clamped: with T_k > T_q a k column may see
+    no q row at all)."""
+    if not causal:
+        return lambda iq, ik: iq
+    return lambda iq, ik: jnp.minimum(
+        jnp.maximum(iq, ik * block_k // block_q), n_q - 1)
 
 
 # ------------------------------------------------------------------ forward
@@ -244,6 +274,9 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
                                with_lse=with_lse)
     o_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, iq, ik: (b, h, iq, 0))
+    k_at = _k_block_index(causal, block_q, block_k)
+    k_spec = pl.BlockSpec((1, 1, block_k, D),
+                          lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
     out_specs = [o_spec]
     out_shape = [_sds(qt.shape, qt.dtype, q)]
     if with_lse:
@@ -253,14 +286,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     res = pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, iq, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, iq, ik: (b, h, ik, 0)),
-        ],
+        in_specs=[o_spec, k_spec, k_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -343,8 +369,17 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, causal, scale,
-                       block_q, block_k, n_q):
+                       *rest, causal, scale, block_q, block_k, n_q, n_k,
+                       with_dq):
+    """dk and dv of one k block, q innermost.  ``with_dq``: the fused
+    backward — the tile's ``ds`` also adds its q rows' ``ds k`` into a
+    [T, D] f32 scratch that holds the whole sequence's dq of this
+    (batch, head), so p and ds are made once a tile (five matmuls, not
+    the seven of a dq kernel beside this one)."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = rest
     ik = pl.program_id(2)
     iq = pl.program_id(3)  # q innermost: accumulators carry across q-blocks
 
@@ -353,18 +388,29 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    if with_dq:
+        @pl.when((ik == 0) & (iq == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def _accum(masked: bool):
         p, ds, q, do = _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, masked=masked, scale=scale,
                                    block_q=block_q, block_k=block_k,
                                    iq=iq, ik=ik)
+        ds = ds.astype(q.dtype)
         # dv += p^T dO ; dk += ds^T q
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k_ref[0, 0, :, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _causal_dispatch(_accum, causal, iq, ik, block_q, block_k)
 
@@ -372,6 +418,27 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _finish():
         dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when((ik == n_k - 1) & (iq == n_q - 1))
+        def _finish_dq():
+            dq_ref[0, 0, :, :] = dq_acc[...].astype(dq_ref.dtype)
+
+
+# The fused backward keeps one (batch, head)'s whole dq in VMEM as f32,
+# [T, D] with D padded to the lane width, beside the tiles.  Within this
+# budget dq, dk and dv come from ONE kernel (T <= 8192 at D <= 128, 4096
+# at D = 256); past it the dq kernel runs beside the dk/dv kernel, as for
+# every shape before.  The fused call needs more scoped VMEM than the
+# 16 MiB default (refused on v5e by 56 KB at T 4096, D 128): 34.9 MiB
+# at the budget's edge with f32 operands (T 4096, D 256), so it asks for
+# 48 of the v5e's 128; 24 to 100 MiB time alike (PERF.md section 6).
+_FUSED_DQ_BYTES = 4 * 1024 * 1024
+_FUSED_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _fused_backward(T: int, D: int) -> bool:
+    return T * -(-D // _LANES) * _LANES * 4 <= _FUSED_DQ_BYTES
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
@@ -397,12 +464,6 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     vt = jnp.transpose(v, (0, 2, 1, 3))
     ot = jnp.transpose(out, (0, 2, 1, 3))
     gt = jnp.transpose(g, (0, 2, 1, 3))
-    q_spec = pl.BlockSpec((1, 1, block_q, D),
-                          lambda b, h, iq, ik: (b, h, iq, 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, D),
-                          lambda b, h, iq, ik: (b, h, ik, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                            lambda b, h, iq, ik: (b, h, iq, 0))
 
     # delta preprocess: one rowsum per q row (vs per block pair)
     dspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq: (b, h, iq, 0))
@@ -421,38 +482,65 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
         delta = delta - jnp.broadcast_to(
             dlse.astype(jnp.float32)[..., None], delta.shape)
 
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
-        grid=(B, H, n_q, n_k),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=_sds(qt.shape, qt.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qt, kt, vt, gt, lse, delta)
-
     # q innermost for dk/dv: k/v block indexed by grid axis 2
+    q_at = _q_block_index(causal, block_q, block_k, n_q)
     kq_spec = pl.BlockSpec((1, 1, block_q, D),
-                           lambda b, h, ik, iq: (b, h, iq, 0))
+                           lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
                            lambda b, h, ik, iq: (b, h, ik, 0))
     krow_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                             lambda b, h, ik, iq: (b, h, iq, 0))
-    dk, dv = pl.pallas_call(
+                             lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
+    out_specs = [kk_spec, kk_spec]
+    out_shape = [_sds(kt.shape, kt.dtype, k), _sds(vt.shape, vt.dtype, v)]
+    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
+               pltpu.VMEM((block_k, D), jnp.float32)]
+    fused = _fused_backward(T, D)
+    if fused:
+        # dq rides in the dk/dv kernel: one block for the whole sequence,
+        # resident across the (ik, iq) steps of a (batch, head)
+        out_specs.insert(0, pl.BlockSpec(
+            (1, 1, T, D), lambda b, h, ik, iq: (b, h, 0, 0)))
+        out_shape.insert(0, _sds(qt.shape, qt.dtype, q))
+        scratch.insert(0, pltpu.VMEM((T, D), jnp.float32))
+    # the fused kernel keeps the dk/dv kernel's name: the benchmark's
+    # flash_bwd_ms.* read /flash_bwd_(delta|dq|dkv)/ (docs/monitoring.md,
+    # "Scope names")
+    outs = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, n_q=n_q),
+                          block_q=block_q, block_k=block_k, n_q=n_q,
+                          n_k=n_k, with_dq=fused),
         grid=(B, H, n_k, n_q),
         in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, krow_spec, krow_spec],
-        out_specs=[kk_spec, kk_spec],
-        out_shape=[_sds(kt.shape, kt.dtype, k),
-                   _sds(vt.shape, vt.dtype, v)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT) if fused else None),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qt, kt, vt, gt, lse, delta)
+    dk, dv = outs[-2:]
+    if fused:
+        dq = outs[0]
+    else:
+        k_at = _k_block_index(causal, block_q, block_k)
+        q_spec = pl.BlockSpec((1, 1, block_q, D),
+                              lambda b, h, iq, ik: (b, h, iq, 0))
+        k_spec = pl.BlockSpec((1, 1, block_k, D),
+                              lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
+        row_spec = pl.BlockSpec((1, 1, block_q, _LANES),
+                                lambda b, h, iq, ik: (b, h, iq, 0))
+        dq = pl.pallas_call(
+            functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
+                              block_q=block_q, block_k=block_k, n_k=n_k),
+            grid=(B, H, n_q, n_k),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=_sds(qt.shape, qt.dtype, q),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(qt, kt, vt, gt, lse, delta)
     back = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     return back(dq), back(dk), back(dv)
 
@@ -561,7 +649,11 @@ def default_blocks(head_dim: int, seq_len: int):
       ONE tile fits VMEM and measures fwd 51.6 vs 40.8 TFLOP/s,
       lifting the fwd+bwd composite 56.9 -> 74.3 TFLOP/s (+31%) with
       the backward held at 1024 (its budget — two f32 tiles + two
-      accumulators — overflows VMEM at 2048).  At longer sequences the
+      accumulators, and in the fused kernel the sequence's dq — overflows
+      the 16 MiB default at 2048; with the fused call's 48 MiB a 2048
+      tile compiles and is slower: 4.1 ms a call of 32 heads at 4096
+      with (2048, 1024) and 6.7 with (2048, 2048) against 3.6 with
+      (1024, 1024), and (512, *) 3.7).  At longer sequences the
       multi-k-block 2048-tile lse-saving forward overflows VMEM
       (measured 24.0M vs the 16M budget at seq 8192), so 1024 stands.
       Gated on targets where the 16 MiB tile is measured to fit
@@ -587,7 +679,7 @@ def flash_attention(q, k, v, causal: bool = False,
 
     ``block_q``/``block_k`` default by head_dim (:func:`default_blocks`);
     ``bwd_blocks``: optional (block_q, block_k) for the backward
-    kernels, whose VMEM budget (two f32 tiles + two accumulators) is
+    kernels, whose VMEM budget (two f32 tiles + the accumulators) is
     tighter — it defaults to the forward blocks capped at 1024.
     """
     if block_q is None or block_k is None:

@@ -241,17 +241,26 @@ FLASH_KERNELS = ("flash_fwd", "flash_bwd_delta", "flash_bwd_dq",
                  "flash_bwd_dkv")
 
 
-@pytest.fixture(scope="module")
-def flash_grad():
+def lowered_flash_grad(T, heads, kv_heads):
+    """(the lowered module, the jaxpr) of the causal flash gradient at
+    [1, T, heads, 128] in bf16: lowered, never run."""
     from kungfu_tpu.ops.flash_attention import flash_attention
-    q = jnp.zeros((1, 128, 4, 32), jnp.float32)
-    k = jnp.zeros((1, 128, 2, 32), jnp.float32)
+    q = jax.ShapeDtypeStruct((1, T, heads, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, T, kv_heads, 128), jnp.bfloat16)
     grad = jax.jit(jax.grad(
-        lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                        kv_groups=2).sum(),
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True,
+            kv_groups=heads // kv_heads).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)))
     return (grad.lower(q, k, k).as_text(debug_info=True),
             str(jax.make_jaxpr(grad)(q, k, k)))
+
+
+@pytest.fixture(scope="module")
+def flash_grad():
+    # a sequence over the fused backward's budget (8192 at heads of 128):
+    # the dq kernel runs beside the dk/dv kernel, all four names are there
+    return lowered_flash_grad(9216, 2, 1)
 
 
 @pytest.mark.parametrize("kernel", FLASH_KERNELS)
@@ -259,6 +268,15 @@ def test_the_flash_kernels_carry_their_names(flash_grad, kernel):
     lowered, jaxpr = flash_grad
     assert kernel in lowered
     assert f"name={kernel}" in jaxpr
+
+
+def test_at_the_cells_shape_the_backward_is_delta_and_dkv():
+    # 4096 x 128, both token cells: one kernel makes dq, dk and dv under
+    # the name the benchmark's flash_bwd_ms.* already read
+    lowered, jaxpr = lowered_flash_grad(4096, 4, 1)
+    assert set(re.findall(r"name=(flash_\w+)", jaxpr)) == {
+        "flash_fwd", "flash_bwd_delta", "flash_bwd_dkv"}
+    assert "flash_bwd_dkv" in lowered and "flash_bwd_dq" not in lowered
 
 
 @pytest.mark.parametrize("metric", FLASH_METRICS)
