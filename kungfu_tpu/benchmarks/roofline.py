@@ -240,12 +240,9 @@ def bench_hbm(mib: int, reps: int) -> dict:
 
 _HD64_VARIANTS = {
     # one measured attempt at the D64 fwd softmax gap (30.4 vs its 38.9
-    # no-softmax causal ceiling, round-4 verdict #9): fold the score
-    # scale into the q block (16x fewer multiply elements at D=64), and
-    # D64-specific block shapes (fewer online-softmax rescale rounds /
-    # whole-row tiles)
+    # no-softmax causal ceiling, round-4 verdict #9): D64-specific block
+    # shapes (fewer online-softmax rescale rounds / whole-row tiles)
     "base": {},
-    "prescale_q": {"env": {"KFT_FLASH_PRESCALE_Q": "1"}},
     "bq512_bk2048": {"blocks": (512, 2048)},
     "bq1024_bk2048": {"blocks": (1024, 2048)},
 }
@@ -253,7 +250,7 @@ _HD64_VARIANTS = {
 
 def hd64_worker(variant: str, reps: int = 512) -> dict:
     """One fresh-process measurement of flash fwd D64 causal under a
-    variant (trace-time env flags require process isolation)."""
+    variant."""
     from ..ops.flash_attention import flash_attention
     spec = _HD64_VARIANTS[variant]
     B, T, H, D = 4, 2048, 12, 64
@@ -284,18 +281,11 @@ def run_hd64_probe(out_path: str, rounds: int = 3) -> dict:
 
     best = {}
     for _ in range(rounds):
-        for variant, spec in _HD64_VARIANTS.items():
-            # arms must not inherit experiment flags from the caller's
-            # shell: a stray KFT_FLASH_PRESCALE_Q=1 would contaminate
-            # the base arm and the conclusion would compare a variant
-            # against itself
-            env = dict(os.environ)
-            env["KFT_FLASH_PRESCALE_Q"] = "0"
-            env.update(spec.get("env", {}))
+        for variant in _HD64_VARIANTS:
             r = subprocess.run(
                 [sys.executable, "-m", "kungfu_tpu.benchmarks.roofline",
                  "--hd64-worker", variant],
-                env=env, capture_output=True, text=True, timeout=600)
+                capture_output=True, text=True, timeout=600)
             assert r.returncode == 0, r.stderr[-2000:]
             row = _json.loads(r.stdout.strip().splitlines()[-1])
             if (variant not in best
@@ -318,9 +308,7 @@ def run_hd64_probe(out_path: str, rounds: int = 3) -> dict:
                "irreducible row max/sum + exp2 + cast VPU work, not the "
                "scale multiply or block shape"
                if winner["tflops"] <= base * 1.02 else
-               "— a real win; before adopting as default, make the "
-               "BACKWARD kernel consistent (prescale_q is fwd-only, "
-               "see _prescale_q docstring)")),
+               "— a real win")),
     }
     with open(out_path, "w") as f:
         _json.dump(doc, f, indent=2)

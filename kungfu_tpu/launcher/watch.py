@@ -33,6 +33,10 @@ from .proc import Proc
 # kills) delivers; SIGKILL follows when the VM is torn down hard.
 _PREEMPT_CODES = {-15, -9, 143, 137}
 
+# how long a worker that a stage removes has to finish the resize and
+# leave before the watcher ends it (Watcher.update, reap)
+LEAVE_S = 3.0
+
 
 class Watcher:
     """Per-host process reconciler."""
@@ -62,6 +66,9 @@ class Watcher:
         # removes a healthy worker after survivors began finalizing
         # (observed as split final membership in 100-worker sim sweeps)
         self._condemned: set = set()
+        # workers a stage removed that are still on their way out:
+        # (proc, its chip, when the watcher ends it)
+        self._leaving: list = []
         # applied Stage history for the debug endpoint (reference: the
         # runner's -debug-port dump, handler.go:117-122)
         self.history: List[Dict] = []
@@ -78,12 +85,20 @@ class Watcher:
             _chaos_point("launcher.watch.update", version=version)
             want = set(self.local_workers(cluster))
             have = set(self.current)
+            # A worker that the new stage removes is inside the resize:
+            # it commits, hands its shards over and takes the old plane
+            # down together with the members that stay, and then leaves
+            # by itself.  It gets LEAVE_S to do so; SIGTERM is for the
+            # one that does not (reap() looks, so the loop never waits).
+            leave_by = time.monotonic() + LEAVE_S
             for peer in have - want:
                 _chaos_point("launcher.watch.kill", version=version)
-                self.current.pop(peer).kill()
-                chip = self._chip_of.pop(peer, None)
-                if chip is not None and self.pool:
-                    self.pool.put(chip)
+                self._leaving.append((self.current.pop(peer),
+                                      self._chip_of.pop(peer, None),
+                                      leave_by))
+            if want - have:
+                # what the stage spawns may need their ports and chips
+                self._end_leavers(float("inf"))
             self._done.clear()  # new membership version: everyone works again
             # exclusions that landed leave want; keep condemning only
             # peers still awaiting theirs (a later grow that re-adds an
@@ -146,6 +161,7 @@ class Watcher:
         ANY worker death; the BASELINE north star asks preemption to be
         absorbed elastically instead)."""
         with self._lock:
+            self._end_leavers(time.monotonic())
             for peer, proc in list(self.current.items()):
                 code = proc.poll()
                 if code is None:
@@ -162,8 +178,22 @@ class Watcher:
                 elif self.failed is None:
                     self.failed = code
 
+    def _end_leavers(self, now: float) -> None:
+        """Let go of the removed workers that have left, and end those
+        whose time is up (lock held)."""
+        staying = []
+        for proc, chip, leave_by in self._leaving:
+            if proc.poll() is None and now < leave_by:
+                staying.append((proc, chip, leave_by))
+                continue
+            proc.kill()
+            if chip is not None and self.pool:
+                self.pool.put(chip)
+        self._leaving = staying
+
     def drain(self) -> None:
         with self._lock:
+            self._end_leavers(float("inf"))
             for proc in self.current.values():
                 proc.kill()
             self.current.clear()
@@ -171,6 +201,11 @@ class Watcher:
     def alive(self) -> int:
         with self._lock:
             return len(self.current)
+
+    def settled(self) -> bool:
+        """No worker left, member or on its way out."""
+        with self._lock:
+            return not self.current and not self._leaving
 
 
 def propose_exclusion(config_url: str, dead: set, retries: int = 8
@@ -675,7 +710,7 @@ def watch_run(job: Job, host: str, parent: PeerID, initial: Cluster,
                 if now - doctor_last >= doctor_scrape_s:
                     doctor_last = now
                     _doctor_tick(w, doctor, policy, executor)
-            if stop_when_empty and w.alive() == 0 and (
+            if stop_when_empty and w.settled() and (
                     not config_url or global_size == 0
                     or w.all_local_done()):
                 return 0

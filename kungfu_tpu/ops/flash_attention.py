@@ -58,22 +58,6 @@ _LOG2E = 1.4426950408889634
 _INV_LOG2E = 1.0 / _LOG2E
 
 
-def _mask_skip() -> bool:
-    """Causal mask strategy: True = dual-branch kernels where
-    fully-visible blocks skip the mask iota/compare/select (only
-    diagonal-straddling tiles pay it); False = single branch, mask on
-    every visible block.  Measured on idle v5e (B4 T2048 D64, 1024
-    blocks): neutral in the forward and +1.9% fwd+bwd (36.7 vs 36.1
-    TFLOP/s) — kept as default because it never loses and the margin
-    widens under host load.  ``KFT_FLASH_MASK_SKIP=0/1`` overrides for
-    experiments — in a FRESH process: the flag is read at trace time
-    and compiled kernels are cached, so flipping it mid-process has no
-    effect."""
-    from ..utils import knobs
-    env = knobs.get("KFT_FLASH_MASK_SKIP")
-    return True if env is None else env
-
-
 def _causal_tile_classes(iq, ik, block_q, block_k):
     """Classify tile (iq, ik) against the causal diagonal — the single
     source of truth for all the kernels (fwd, bwd-dq, bwd-dkv).
@@ -92,26 +76,22 @@ def _causal_tile_classes(iq, ik, block_q, block_k):
 
 
 def _causal_dispatch(body, causal, iq, ik, block_q, block_k):
-    """Run ``body(masked=...)`` once per visible tile under the causal
-    masking strategy (:func:`_mask_skip`).  Blocks strictly above the
-    diagonal run nothing — their grid steps are predicated off."""
+    """Run ``body(masked=...)`` once per visible tile: fully-visible
+    tiles skip the mask iota/compare/select, only the tiles that straddle
+    the diagonal pay it.  Blocks strictly above the diagonal run nothing
+    — their grid steps are predicated off."""
     if not causal:
         body(masked=False)
         return
-    below, on_diag, visible = _causal_tile_classes(iq, ik, block_q,
-                                                   block_k)
-    if _mask_skip():
-        @pl.when(below)
-        def _():
-            body(masked=False)
+    below, on_diag, _ = _causal_tile_classes(iq, ik, block_q, block_k)
 
-        @pl.when(on_diag)
-        def _():
-            body(masked=True)
-    else:
-        @pl.when(visible)
-        def _():
-            body(masked=True)
+    @pl.when(below)
+    def _():
+        body(masked=False)
+
+    @pl.when(on_diag)
+    def _():
+        body(masked=True)
 
 
 def _k_block_index(causal, block_q, block_k):
@@ -137,29 +117,6 @@ def _q_block_index(causal, block_q, block_k, n_q):
 
 
 # ------------------------------------------------------------------ forward
-def _prescale_q() -> bool:
-    """hd64 softmax-gap probe (round-4 verdict #9): fold the score
-    scale into the Q BLOCK ([bq, D] multiply) instead of the score tile
-    ([bq, bk] multiply — bk/D times more elements; 16x at D=64).
-    Measured on idle v5e (B4 T2048 D64 causal, fresh process per arm,
-    alternated, best-of-3 — ROOFLINE.json ``hd64_probe``): 30.5 vs
-    base 30.37 TFLOP/s — NEUTRAL (Mosaic already fuses the scalar
-    multiply into the elementwise chain), and whole-row block shapes
-    (bk=2048) LOSE ~18%.  The D64 gap to the 38.9 no-softmax ceiling
-    is the irreducible row max/sum + exp2 + cast VPU work.
-    (Regenerate: ``python -m kungfu_tpu.benchmarks.roofline
-    --hd64-probe``.)
-    FORWARD-ONLY experiment flag: the backward kernel still scales the
-    score tile, so with the flag on, fwd and bwd probabilities differ
-    by the bf16 rounding of the prescaled q — fine for a fwd
-    microbenchmark, NOT a shippable default until the backward is
-    changed to match.  Default off; ``KFT_FLASH_PRESCALE_Q=1``
-    enables — in a FRESH process (trace-time flag, like
-    ``KFT_FLASH_MASK_SKIP``)."""
-    from ..utils import knobs
-    return bool(knobs.get("KFT_FLASH_PRESCALE_Q"))
-
-
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q,
                block_k, n_k, with_lse):
     if with_lse:
@@ -185,14 +142,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q,
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
-        if _prescale_q():
-            q = (q.astype(jnp.float32) * (scale * _LOG2E)).astype(q.dtype)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        else:
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32
-                                    ) * (scale * _LOG2E)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32
+                                ) * (scale * _LOG2E)
         if masked:
             qpos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -629,12 +581,7 @@ def _big_tile_ok() -> bool:
     """Whether the 16 MiB f32 2048x2048 probability tile is known to fit
     this target's VMEM.  Measured-good on v5e ("TPU v5 lite") ONLY;
     every other generation falls back to 1024 until measured (a too-big
-    default would turn a working config into a compile failure —
-    ADVICE r3).  ``KFT_FLASH_BIG_TILE=1/0`` overrides either way."""
-    from ..utils import knobs
-    env = knobs.get("KFT_FLASH_BIG_TILE")
-    if env is not None:
-        return env
+    default would turn a working config into a compile failure)."""
     kind = jax.devices()[0].device_kind.lower()
     return "v5 lite" in kind or "v5e" in kind
 
@@ -657,7 +604,7 @@ def default_blocks(head_dim: int, seq_len: int):
       multi-k-block 2048-tile lse-saving forward overflows VMEM
       (measured 24.0M vs the 16M budget at seq 8192), so 1024 stands.
       Gated on targets where the 16 MiB tile is measured to fit
-      (:func:`_big_tile_ok`; ``KFT_FLASH_BIG_TILE`` overrides).
+      (:func:`_big_tile_ok`).
 
     Shorter sequences fall back via fit_block either way."""
     if head_dim >= 128 and seq_len <= 2048 and _big_tile_ok():

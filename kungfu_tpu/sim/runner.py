@@ -38,6 +38,8 @@ from ..chaos.runner import (Scenario, ScenarioResult,
                             _free_port, _PolicySampler,
                             doctor_violations, floor_violations,
                             policy_violations)
+from ..monitor import MONITOR_PORT_OFFSET
+from ..plan.hostspec import DEFAULT_WORKER_PORT
 
 # The spawned payload: sets lite mode BEFORE any kungfu_tpu import (a
 # belt to the env var's braces), then runs the fake trainer.  The
@@ -59,21 +61,39 @@ SIM_SERVE_WORKER = (
     "sys.exit(main())\n"
 )
 
-# Worker base port chosen so that BOTH the worker range and the metrics
-# range (port + MONITOR_PORT_OFFSET) sit below the kernel's default
-# ephemeral floor (net.ipv4.ip_local_port_range starts at 32768): a
-# 100-process fleet makes thousands of outgoing heartbeat/config
-# connections, and any of them could otherwise squat a metrics port as
-# its ephemeral source port (observed as EADDRINUSE at n=100).
-SIM_BASE_PORT = 21100
+# The sim fleets' workers sit a fixed offset under the process's worker
+# window (plan/hostspec: DEFAULT_WORKER_PORT, moved by KFT_BASE_PORT), so
+# that processes given distinct windows run distinct fleets.  The offset
+# is chosen so that at the default base BOTH the worker range (21300..)
+# and the metrics range (port + MONITOR_PORT_OFFSET, 31300..) sit below
+# the kernel's default ephemeral floor (net.ipv4.ip_local_port_range
+# starts at 32768): a 100-process fleet makes thousands of outgoing
+# heartbeat/config connections, and any of them could otherwise squat a
+# metrics port as its ephemeral source port (observed as EADDRINUSE at
+# n=100).  It also puts the metrics range 200 above the window's base,
+# clear of the real workers there.  A base too low to have room beneath
+# it keeps its fleets above its workers' metrics range.
+SIM_PORT_OFFSET = -9800
+SIM_PORTS = 600
+
+
+def _sim_base_port(worker_base: int) -> int:
+    below = worker_base + SIM_PORT_OFFSET
+    if below >= 1124:
+        return below
+    return worker_base + MONITOR_PORT_OFFSET + 200
+
+
+SIM_BASE_PORT = _sim_base_port(DEFAULT_WORKER_PORT)
 
 # Concurrent runs in one process (pytest running two scenarios in
 # threads) each need a disjoint worker range, or their metrics servers
 # fight over port+offset and their /state adoption probes cross fleets.
-# A cursor hands out [base, base+nprocs) slices, wrapping before the
-# metrics range would cross the ephemeral floor.  Cross-PROCESS
-# concurrency is covered separately: the fake trainer degrades to
-# serving no /metrics when its bind loses a race.
+# A cursor hands out [base, base+nprocs) slices of the process's
+# SIM_PORTS, wrapping at their end and before the metrics range would
+# cross the ephemeral floor.  Concurrent PROCESSES are kept apart by
+# their windows; the fake trainer still degrades to serving no /metrics
+# when its bind loses a race.
 _BASE_LOCK = threading.Lock()
 _BASE_CURSOR = [SIM_BASE_PORT]
 
@@ -149,10 +169,10 @@ class _ServeLoadDriver(threading.Thread):
 
 
 def _alloc_base_port(nprocs: int) -> int:
-    from ..monitor import MONITOR_PORT_OFFSET
     with _BASE_LOCK:
         base = _BASE_CURSOR[0]
-        if base + nprocs + MONITOR_PORT_OFFSET >= 32768:
+        if (base + nprocs > SIM_BASE_PORT + SIM_PORTS
+                or base + nprocs + MONITOR_PORT_OFFSET >= 32768):
             base = SIM_BASE_PORT
         _BASE_CURSOR[0] = base + nprocs
         return base

@@ -398,17 +398,6 @@ _def("KFT_POLICY_ACT_WAL", "str", None,
      "Action WAL path override; default derives from KFT_TRACE_DIR "
      "(unset and no trace dir: in-memory only).", group=_POLICY)
 
-_OPS = "Kernels (ops)"
-_def("KFT_FLASH_MASK_SKIP", "bool", None,
-     "Flash attention: skip fully-masked KV tiles. Tri-state — unset "
-     "lets the autotune probe decide.", group=_OPS)
-_def("KFT_FLASH_PRESCALE_Q", "bool", False,
-     "Flash attention: pre-scale Q once instead of per-tile.",
-     group=_OPS)
-_def("KFT_FLASH_BIG_TILE", "bool", None,
-     "Flash attention: force the large KV tile on/off. Tri-state — "
-     "unset lets the device probe decide.", group=_OPS)
-
 _CHAOS = "Chaos (kfchaos)"
 _def("KFT_CHAOS_PLAN", "str", None,
      "Fault-plan JSON path, armed once at import.", group=_CHAOS)

@@ -13,6 +13,10 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+from testutil import claim_port_window  # noqa: E402
+
+claim_port_window(os.environ)
+
 import jax  # noqa: E402
 import pytest  # noqa: E402
 

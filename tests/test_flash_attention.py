@@ -162,6 +162,26 @@ def test_the_backward_is_chosen_from_the_shape(T, fused):
     assert {"flash_fwd", "flash_bwd_delta", "flash_bwd_dkv"} <= names
 
 
+def test_the_kernels_read_no_environment(monkeypatch):
+    """The program is chosen from the shapes alone: a variable left in the
+    shell cannot give a training run a forward its backward disagrees
+    with."""
+    q = jax.ShapeDtypeStruct((1, 64, 2, 16), jnp.float32)
+
+    def lowered():
+        # a new function each time, so that jit traces again
+        grad = jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, True, 32, 16).sum(), argnums=(0, 1, 2))
+        return jax.jit(grad).lower(q, q, q).as_text()
+
+    plain = lowered()
+    # two of the switches that are gone, at the values that changed the
+    # program (spelt apart: they are no knobs, and kfcheck would look them up)
+    for gone, value in (("FLASH_PRESCALE_Q", "1"), ("FLASH_MASK_SKIP", "0")):
+        monkeypatch.setenv(f"KFT_{gone}", value)
+    assert lowered() == plain
+
+
 @pytest.mark.parametrize("bq,bk", [(128, 128), (128, 64), (64, 128)])
 def test_a_causal_index_map_names_only_tiles_that_run(bq, bk):
     T = 512

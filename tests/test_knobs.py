@@ -57,10 +57,10 @@ def test_bool_falsey_set(text, expect):
 
 def test_tristate_bool_default_none():
     # unset -> None, so callers can distinguish "unset" from "forced
-    # off" (flash_attention._mask_skip, chaos data-plane force)
-    assert knobs.get("KFT_FLASH_MASK_SKIP", env={}) is None
-    assert knobs.get("KFT_FLASH_MASK_SKIP",
-                     env={"KFT_FLASH_MASK_SKIP": "0"}) is False
+    # off" (the chaos runner's data-plane probe override)
+    assert knobs.get("KFT_TESTS_DATA_PLANE", env={}) is None
+    assert knobs.get("KFT_TESTS_DATA_PLANE",
+                     env={"KFT_TESTS_DATA_PLANE": "0"}) is False
 
 
 def test_malformed_warns_and_falls_back(capsys):

@@ -20,6 +20,12 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from kungfu_tpu import native  # noqa: E402
+from kungfu_tpu.plan import DEFAULT_WORKER_PORT  # noqa: E402
+
+# this file's two clusters take the top of the process's worker ports
+# (tests/testutil.py), twenty each
+BASE_A = DEFAULT_WORKER_PORT + 60
+BASE_B = DEFAULT_WORKER_PORT + 80
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -62,7 +68,7 @@ def test_two_host_cluster_over_loopback_aliases(tmp_path):
 
     hosts = "127.0.0.2:2,127.0.0.3:2"
     cluster = Cluster.from_hostlist(HostList.parse(hosts), 4,
-                                    base_port=31400)
+                                    base_port=BASE_A)
     srv = ConfigServer(host="127.0.0.1").start()
     put_config(srv.url, cluster)
 
@@ -75,7 +81,7 @@ def test_two_host_cluster_over_loopback_aliases(tmp_path):
             launchers.append(subprocess.Popen(
                 [sys.executable, "-m", "kungfu_tpu.launcher",
                  "-np", "4", "-H", hosts, "-self", self_host,
-                 "-port-range", "31400-31499",
+                 "-port-range", f"{BASE_A}-{BASE_A + 19}",
                  "-config-server", srv.url, "--",
                  sys.executable, str(script)],
                 env=env, cwd=REPO, stdout=subprocess.PIPE,
@@ -164,7 +170,7 @@ with open(os.path.join(os.environ["TEST_OUT"],
 
     hosts = "127.0.0.2:2,127.0.0.3:2"
     cluster = Cluster.from_hostlist(HostList.parse(hosts), 4,
-                                    base_port=31500)
+                                    base_port=BASE_B)
     srv = ConfigServer(host="127.0.0.1").start()
     put_config(srv.url, cluster)
 
@@ -177,7 +183,7 @@ with open(os.path.join(os.environ["TEST_OUT"],
             launchers[self_host] = subprocess.Popen(
                 [sys.executable, "-m", "kungfu_tpu.launcher",
                  "-np", "4", "-H", hosts, "-self", self_host, "-w",
-                 "-port-range", "31500-31599",
+                 "-port-range", f"{BASE_B}-{BASE_B + 19}",
                  "-config-server", srv.url, "--",
                  sys.executable, str(worker)],
                 env=env, cwd=REPO, stdout=subprocess.PIPE,

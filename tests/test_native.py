@@ -283,13 +283,24 @@ def _w_async_pair_avg(rank, peers, q, selection):
             avg = AsyncPairAverager(p, selection=selection)
             avg.save(params)
             p.barrier(name="init")  # reference: step-0 store init barrier
-            for step in range(60):
-                params = avg.mix(params)
-                grad = {"w": 2.0 * (params["w"] - target)}
-                params = {"w": params["w"] - 0.1 * grad["w"]}
-                avg.save(params)
-            p.barrier(name="trained")
-            err = float(jnp.abs(params["w"] - target).max())
+            # What a rank mixes in is whatever its peer last saved, and
+            # how old that is was up to the machine: a rank that took its
+            # sixty steps while a peer had not yet taken one ended on that
+            # peer's initial model.  AD-PSGD converges under a BOUNDED
+            # delay, so the ranks meet every ten steps (exchanges inside a
+            # window stay unsynchronised) and train until all of them are
+            # there: six windows as before, more only while one is behind.
+            for window in range(30):
+                for _ in range(10):
+                    params = avg.mix(params)
+                    grad = {"w": 2.0 * (params["w"] - target)}
+                    params = {"w": params["w"] - 0.1 * grad["w"]}
+                    avg.save(params)
+                err = float(jnp.abs(params["w"] - target).max())
+                worst = p.all_reduce(np.asarray([err], np.float32),
+                                     op="MAX", name=f"err{window}")
+                if window >= 5 and worst[0] < 0.5:
+                    break
             assert err < 0.5, f"rank {rank} err {err}"
             q.put((rank, "ok"))
     except Exception as e:  # pragma: no cover

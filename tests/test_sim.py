@@ -160,10 +160,17 @@ def test_sim_fleet_absorbs_preemption(tmp_path):
     """One kill at a step fence: the watcher must reap it, CAS-shrink
     the membership, and the survivors must converge on the smaller
     cluster — the no-fresh-start/progress invariants hold throughout."""
+    target_steps = 8
     plan = Plan(seed=None).add("elastic.step.fence", "kill", rank=1,
                                step=list(range(2, 50)))
+    # The fleet trains for a quarter of a second, and a rank that comes up
+    # later than that adopts its peers' finished state and passes no fence
+    # at all.  It then dies at the commit that opens its drain, before its
+    # lease can show the target: whatever the machine's timing the kill
+    # fires, and the others cannot finish without the shrink.
+    plan.add("store.save", "kill", rank=1, step=[target_steps])
     sc = Scenario(name="t1-sim-kill", desc="", plan=plan,
-                  tier="sim", nprocs=5, target_steps=8,
+                  tier="sim", nprocs=5, target_steps=target_steps,
                   sim_step_s=0.03, min_fired=1, min_config_versions=2,
                   timeout_s=120.0)
     res = run_sim_scenario(sc, out_root=str(tmp_path), verbose=False)

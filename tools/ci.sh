@@ -175,10 +175,13 @@ else
     for ((i = s; i < ${#FILES[@]}; i += JOBS)); do
       shard+=("${FILES[$i]}")
     done
-    # per-shard worker-port window, starting OFF the library default
-    # (31100) so shards collide neither with each other nor with a
-    # concurrent manual run using defaults
-    ( KFT_BASE_PORT=$((31400 + s * 300)) \
+    # per-shard port window, OFF the library default (31100) so shards
+    # collide neither with each other nor with a concurrent manual run
+    # using defaults: the windows of tests/testutil.py, which the xdist
+    # workers of a plain pytest run get too (tests/conftest.py)
+    ( KFT_BASE_PORT=$(python -c "import sys; sys.path.insert(0, 'tests')
+from testutil import window_base_port
+print(window_base_port('gw$s'))") \
         python -m pytest "${shard[@]}" -q \
         > "/tmp/kft-ci-shard-$s.log" 2>&1 ) &
     pids+=($!)
