@@ -91,11 +91,14 @@ def parse_args(argv=None):
     p.add_argument("--swiglu", action="store_true")
     p.add_argument("--remat", nargs="?", const="full", default="",
                    choices=["", "none", "full", "attn", "ffn"],
-                   help="per-layer rematerialization: 'full' saves only "
-                        "each block's input; 'attn' additionally saves "
-                        "the attention output so the backward never "
-                        "re-runs the flash kernel; 'ffn' recomputes only "
-                        "the norm+FFN sub-block")
+                   help="per-layer rematerialization: 'full' saves each "
+                        "block's input, the flash kernel's output and lse "
+                        "(the backward never re-runs the kernel) and, "
+                        "under output norms, the FFN's output projection; "
+                        "'attn' saves all of the attention's residuals "
+                        "(q, k, v too) and recomputes the projections, "
+                        "norms and FFN; 'ffn' recomputes only the "
+                        "norm+FFN sub-block")
     p.add_argument("--attn", default="auto",
                    help="auto | flash | dense")
     p.add_argument("--f32", action="store_true",

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.ring_attention import (reference_attention, ring_attention,
@@ -289,7 +290,8 @@ def _dense_ffn(layer, h, cfg: GPTConfig, tp_axis: Optional[str] = None):
     m = u @ layer["wm"].astype(cfg.dtype)
     if tp_axis:
         m = lax.psum(m, tp_axis)
-    return m
+    # named after the psum, so that a kept value is the reduced one
+    return checkpoint_name(m, "ffn_proj")
 
 
 def _layer_finish(layer, x, o, cfg: GPTConfig,
@@ -397,6 +399,12 @@ def apply_layer(layer, x, cfg: GPTConfig, *,
     return finish(layer, x, o)
 
 
+# what remat="full" keeps of a layer beside its input: the flash kernel's
+# output and lse (ops/flash_attention._fa_fwd) and wm's output (_dense_ffn)
+_FULL_REMAT_KEEPS = jax.checkpoint_policies.save_only_these_names(
+    "ffn_proj", "flash_out", "flash_lse")
+
+
 def layer_stack(params, tokens, cfg: GPTConfig, *,
                 tp_axis: Optional[str] = None,
                 sp_axis: Optional[str] = None,
@@ -405,7 +413,23 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
     """``(x, run)``: the embedded tokens [B_local, T_local, D] and
     ``run(x) -> x``, one pass through every layer of ``params`` — what
     :func:`forward_features` does once and ``models/looped.py`` once a
-    round.  Arguments as in :func:`forward_features`."""
+    round.  Arguments as in :func:`forward_features`.
+
+    ``remat``: ``True`` or ``"full"`` puts one ``jax.checkpoint`` around
+    each layer, which keeps the block's input and what a kernel or the
+    FFN's output projection made (``_FULL_REMAT_KEEPS``) and makes the
+    rest again in the backward.  What is kept follows what the traced
+    block holds, with no switch: the flash output and its ``lse`` only
+    where ``attn`` is the local flash kernel (the backward then runs no
+    second ``flash_fwd``; dense, ring and Ulysses attention keep nothing
+    new), ``wm``'s output only where the backward reads it
+    (``cfg.out_norms``).  That is at most two ``[B, T, D]`` a layer visit
+    beside the block's input, each ``B*T / (72*D)`` of the bytes of the
+    layer's f32 weights with their Adam state (1.4% at D = 4096 and 4,096
+    tokens a microbatch); most where weights are shared across rounds
+    (``models/looped.py``).  A capacity knob for models that do not fit
+    otherwise: a step pays for it with the layers' forward a second time.
+    ``"ffn"`` and ``"attn"``: see :func:`apply_layer`."""
     T = tokens.shape[1]
     if attn == "auto":
         def _flash_ok():
@@ -429,12 +453,7 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
                                  remat_ffn=(remat == "ffn"),
                                  remat_around_attn=(remat == "attn"))
     if remat in (True, "full"):
-        # trade FLOPs for HBM: save only each block's input; recompute
-        # activations in the backward (jax.checkpoint per layer).  With
-        # the flash kernel, activations are already O(T*D), so this is a
-        # capacity knob for larger d_model/n_layers than fit otherwise —
-        # measured ~20% step-time cost when it isn't needed.
-        layer_fn = jax.checkpoint(layer_fn)
+        layer_fn = jax.checkpoint(layer_fn, policy=_FULL_REMAT_KEEPS)
     elif remat not in (False, None, "", "none", "ffn", "attn"):
         raise ValueError(f"unknown remat mode {remat!r}")
 

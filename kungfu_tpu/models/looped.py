@@ -59,8 +59,11 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
 def forward_rounds(params, tokens, cfg: GPTConfig, *, attn: str = "auto",
                    remat: bool = False):
     """Every round's normed state, ``[n_rounds, B, T, D]``.  ``attn`` and
-    ``remat`` as in ``gpt.forward_features``; ``remat="full"`` keeps the
-    ``n_rounds x n_layers`` layer inputs and recomputes inside each."""
+    ``remat`` as in ``gpt.layer_stack``; ``remat="full"`` keeps, for each
+    of the ``n_rounds x n_layers`` layer visits, the layer's input and what
+    ``gpt.layer_stack``'s policy names (the flash kernel's output and
+    ``lse``; ``wm``'s output under ``cfg.out_norms``), stacked over the
+    rounds by the scan, and makes the rest of each visit again."""
     x, run = G.layer_stack(params, tokens, cfg, attn=attn, remat=remat)
 
     def one_round(x, _):

@@ -42,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -557,10 +558,17 @@ def _fa_fwd(q, k, v, causal, block_q, block_k, kv_groups, bwd_blocks):
                               _expand_kv_heads(v, kv_groups), causal,
                               block_q, block_k, _auto_interpret(),
                               with_lse=True)
+    # what the kernel made carries a name, so that a checkpoint policy
+    # around the caller (gpt.layer_stack's under remat="full") can keep it
+    # and spare the backward a second flash_fwd; the primal output and the
+    # residual are the same named value, and outside a checkpoint region a
+    # name is the identity
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     # residuals keep k/v COMPACT under GQA — the expand re-runs in the
     # backward (a cheap repeat) instead of storing kv_groups-times the
     # KV activations across the whole fwd->bwd window
-    return out, (q, k, v, out, lse[..., 0])
+    return out, (q, k, v, out, lse)
 
 
 def _fa_bwd(causal, block_q, block_k, kv_groups, bwd_blocks, res, g):

@@ -122,7 +122,9 @@ def backward_path(request, monkeypatch):
 
 
 def _kernel_names(fn, *args):
-    return set(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)(*args))))
+    # the kernels': `flash_out` and `flash_lse` name values (_fa_fwd), not calls
+    return set(re.findall(r"name=(flash_(?:fwd|bwd)\w*)",
+                          str(jax.make_jaxpr(fn)(*args))))
 
 
 @pytest.mark.parametrize("case", sorted(GRADIENT_CASES))
