@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +53,11 @@ class GPTConfig:
     n_kv_heads: Optional[int] = None
     # rotary position embeddings instead of the learned wpe table (no
     # max_seq-bound position parameters; the LLaMA-style configuration
-    # together with bias-free blocks + GQA)
-    rope: bool = False
+    # together with bias-free blocks + GQA).  One value for every layer,
+    # or a tuple as long as the layers: a layer whose entry is False
+    # rotates nothing and has no other position signal (NoPE; such a model
+    # has no wpe table either)
+    rope: Any = False
     # dtype for the RoPE cos/sin rotation math.  None = activation dtype
     # (fast: no extra HBM pass).  With a bf16 activation dtype the 8-bit
     # mantissa makes the rotation error grow with absolute position —
@@ -67,7 +70,8 @@ class GPTConfig:
     # into ONE [D, 2*d_ff] matmul at apply time (a free reshape; d_ff
     # stays the minor axis for clean MXU tiling — measured ~35% faster
     # than a [D, d_ff, 2] layout whose minor dim is 2 on v5e) and tensor
-    # parallelism shards d_ff with gate/up pairs kept together)
+    # parallelism shards d_ff with gate/up pairs kept together) or
+    # "reglu" (the same layout, relu(gate) * up)
     mlp: str = "gelu"
     # what a published configuration states beside its widths: the eps
     # inside every RMS norm and the base of the rotary frequencies
@@ -81,11 +85,46 @@ class GPTConfig:
     # each round starting from the last one's normed output
     # (models/looped.py trains it; nothing here decodes it)
     n_rounds: int = 1
+    # the size of a head where a configuration states it apart from the
+    # hidden size (28 heads of 128 over a hidden size of 2560); None: the
+    # quotient d_model // n_heads, and only then must it divide
+    d_head: Optional[int] = None
+    # sliding-window attention: a query sees the ``window`` newest
+    # positions, itself included (i - j < window).  None: plain causal;
+    # one value for every layer, or a tuple as long as the layers whose
+    # None entries are the full layers
+    window: Any = None
+    # a routed feed-forward in every layer, without dropped tokens
+    # (parallel/moe.py dropless_moe_ffn): the router's width (experts
+    # routed over; 0: the dense FFN above), experts a token, an expert's
+    # width, and which experts this chip holds, (first id, count), None:
+    # all.  Experts are gated (``mlp`` "reglu"); the router reads what
+    # attention reads, the block's normed input
+    n_experts: int = 0
+    experts_per_token: int = 0
+    d_expert: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
-        if self.d_model % self.n_heads != 0:
+        if self.d_head is None and self.d_model % self.n_heads != 0:
             raise ValueError(f"d_model {self.d_model} not divisible by "
                              f"n_heads {self.n_heads}")
+        for name in ("rope", "window"):
+            value = getattr(self, name)
+            if isinstance(value, (tuple, list)) and (
+                    len(value) != self.n_layers):
+                raise ValueError(f"{name} has {len(value)} entries for "
+                                 f"{self.n_layers} layers")
+        if self.n_experts:
+            first, count = self.held
+            if not (0 < self.experts_per_token <= self.n_experts
+                    and self.d_expert > 0 and self.mlp == "reglu"
+                    and 0 <= first and count > 0
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    "a routed feed-forward needs experts_per_token in "
+                    "1..n_experts, d_expert, mlp='reglu' and experts_held "
+                    f"inside the {self.n_experts} routed over")
         if self.n_kv_heads is not None and self.n_kv_heads <= 0:
             raise ValueError(f"n_kv_heads must be positive, "
                              f"got {self.n_kv_heads}")
@@ -95,15 +134,27 @@ class GPTConfig:
         if self.rope and self.head_dim % 2 != 0:
             raise ValueError(f"RoPE needs an even head_dim, "
                              f"got {self.head_dim}")
-        if self.mlp not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp must be 'gelu' or 'swiglu', "
+        if self.mlp not in ("gelu", "swiglu", "reglu"):
+            raise ValueError(f"mlp must be 'gelu', 'swiglu' or 'reglu', "
                              f"got {self.mlp!r}")
         if self.n_rounds < 1:
             raise ValueError(f"n_rounds must be >= 1, got {self.n_rounds}")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    def layer_rope(self, i: int) -> bool:
+        return bool(self.rope[i] if isinstance(self.rope, (tuple, list))
+                    else self.rope)
+
+    def layer_window(self, i: int) -> Optional[int]:
+        return (self.window[i] if isinstance(self.window, (tuple, list))
+                else self.window)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def kv_heads(self) -> int:
@@ -123,7 +174,8 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
     D, H, Dh, F, V = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
                       cfg.vocab_size)
     Hkv = cfg.kv_heads
-    k = iter(jax.random.split(rng, 4 + 6 * cfg.n_layers))
+    k = iter(jax.random.split(
+        rng, 4 + (7 if cfg.n_experts else 6) * cfg.n_layers))
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
@@ -133,8 +185,9 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
                   "ln2_out": jnp.ones((D,), jnp.float32)}
                  if cfg.out_norms else {})
     layers: List[Dict] = []
+    G, Fe = cfg.held[1], cfg.d_expert
     for _ in range(cfg.n_layers):
-        layers.append({
+        layer = {
             **out_norms,
             "ln1": jnp.ones((D,), jnp.float32),
             "wq": dense(next(k), (D, H, Dh), D),
@@ -142,10 +195,17 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
             "wv": dense(next(k), (D, Hkv, Dh), D),
             "wo": dense(next(k), (H, Dh, D), D),
             "ln2": jnp.ones((D,), jnp.float32),
-            "wi": dense(next(k), (D, 2, F) if cfg.mlp == "swiglu"
-                        else (D, F), D),
-            "wm": dense(next(k), (F, D), F),
-        })
+        }
+        if cfg.n_experts:
+            # the router over all experts, gate/up and down of those held
+            layer.update(router=dense(next(k), (D, cfg.n_experts), D),
+                         wi=dense(next(k), (G, D, 2 * Fe), D),
+                         wm=dense(next(k), (G, Fe, D), Fe))
+        else:
+            layer.update(wi=dense(next(k), (D, F) if cfg.mlp == "gelu"
+                                  else (D, 2, F), D),
+                         wm=dense(next(k), (F, D), F))
+        layers.append(layer)
     out = {
         "wte": dense(next(k), (V, D), D),
         "layers": layers,
@@ -165,6 +225,13 @@ def param_specs(cfg: GPTConfig, tp: Optional[str] = "tp") -> Dict:
 
     out_norms = {"ln1_out": P(), "ln2_out": P()} if cfg.out_norms else {}
 
+    if cfg.n_experts:
+        # experts are shared out by id (experts_held), never over tp
+        ffn = {"router": P(), "wi": P(), "wm": P()}
+    else:
+        ffn = {"wi": P(None, t) if cfg.mlp == "gelu" else P(None, None, t),
+               "wm": P(t, None)}
+
     def layer_specs():
         return {
             **out_norms,
@@ -174,8 +241,7 @@ def param_specs(cfg: GPTConfig, tp: Optional[str] = "tp") -> Dict:
             "wv": P(None, t, None),
             "wo": P(t, None, None),
             "ln2": P(),
-            "wi": P(None, None, t) if cfg.mlp == "swiglu" else P(None, t),
-            "wm": P(t, None),
+            **ffn,
         }
     out = {
         "wte": P(),
@@ -192,6 +258,9 @@ def validate_tp(cfg: GPTConfig, ntp: int) -> None:
     """Every dimension :func:`param_specs` shards over tp must divide by
     the rank count — the one validator shared by every tensor-parallel
     entry point (training, generation, the serving engine)."""
+    if cfg.n_experts and ntp > 1:
+        raise ValueError("a routed feed-forward is shared out by expert id "
+                         "(experts_held), not over tensor-parallel ranks")
     for what, val in (("n_heads", cfg.n_heads), ("kv_heads", cfg.kv_heads),
                       ("d_ff", cfg.d_ff), ("vocab_size", cfg.vocab_size)):
         if val % ntp != 0:
@@ -251,21 +320,30 @@ def _rope_rotate(t, pos, cfg: GPTConfig):
                             t1 * sin + t2 * cos], axis=-1).astype(t.dtype)
 
 
-def _layer_qkv(layer, x, cfg: GPTConfig, pos=None):
+def _layer_qkv(layer, x, cfg: GPTConfig, pos=None, rope=None,
+               route=None):
     """ln1 + q/k/v projections — shared by the train and decode paths.
     Under GQA, k/v come out with ``kv_heads`` heads (the cache shape);
     use :func:`_expand_kv` before a full-width attend.  With RoPE, q/k
-    are rotated here by the global positions ``pos``."""
-    if cfg.rope and pos is None:
+    are rotated here by the global positions ``pos``; ``rope`` says
+    whether THIS layer rotates (default: the configuration's one value).
+
+    ``route(layer, h)``: what reads the block's normed input beside the
+    projections (an expert layer's router, placed before attention); its
+    result comes back as a fourth value."""
+    rope = cfg.layer_rope(0) if rope is None else rope
+    if rope and pos is None:
         raise ValueError("RoPE model needs positions in _layer_qkv")
     with jax.named_scope("attn"):
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cfg.dtype))
         kk = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cfg.dtype))
         v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cfg.dtype))
-        if cfg.rope:
+        if rope:
             q = _rope_rotate(q, pos, cfg)
             kk = _rope_rotate(kk, pos, cfg)
+    if route is not None:
+        return q, kk, v, route(layer, h)
     return q, kk, v
 
 
@@ -280,11 +358,12 @@ def _expand_kv(t, cfg: GPTConfig):
 
 def _dense_ffn(layer, h, cfg: GPTConfig, tp_axis: Optional[str] = None):
     """Post-norm activations -> FFN delta (no residual add)."""
-    if cfg.mlp == "swiglu":
+    if cfg.mlp in ("swiglu", "reglu"):
         wi = layer["wi"].astype(cfg.dtype)          # [D, 2, F_local]
         fl = wi.shape[2]
         u = h @ wi.reshape(wi.shape[0], 2 * fl)     # one packed matmul
-        u = jax.nn.silu(u[..., :fl]) * u[..., fl:]
+        gate = jax.nn.silu if cfg.mlp == "swiglu" else jax.nn.relu
+        u = gate(u[..., :fl]) * u[..., fl:]
     else:
         u = jax.nn.gelu(h @ layer["wi"].astype(cfg.dtype))
     m = u @ layer["wm"].astype(cfg.dtype)
@@ -294,10 +373,31 @@ def _dense_ffn(layer, h, cfg: GPTConfig, tp_axis: Optional[str] = None):
     return checkpoint_name(m, "ffn_proj")
 
 
+def _routed_ffn(layer, h, routing, cfg: GPTConfig):
+    """Post-norm activations -> the held experts' delta for the tokens
+    ``routing`` (ids, weights: :func:`_route`) sends them."""
+    from ..parallel.moe import expert_ffn
+    B, T, D = h.shape
+    with jax.named_scope("moe"):
+        m = expert_ffn(h.reshape(B * T, D), *routing, layer["wi"],
+                       layer["wm"], cfg.held)
+    return m.reshape(B, T, D)
+
+
+def _route(layer, h, cfg: GPTConfig):
+    """The expert layer's routing of the tokens ``h`` [B, T, D] (the
+    block's normed input): (ids, weights), each [B T, k]."""
+    from ..parallel.moe import route_topk
+    with jax.named_scope("moe"):
+        return route_topk(h.reshape(-1, h.shape[-1]), layer["router"],
+                          cfg.experts_per_token)
+
+
 def _layer_finish(layer, x, o, cfg: GPTConfig,
                   tp_axis: Optional[str] = None,
                   ffn: Optional[Any] = None,
-                  remat_ffn: bool = False):
+                  remat_ffn: bool = False,
+                  routing: Optional[Any] = None):
     """Attention output projection + residual + FFN — shared by the train
     and decode paths (any architecture change lands in both).
 
@@ -307,7 +407,10 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
 
     ``remat_ffn`` checkpoints the norm+FFN sub-block: its internal
     activations (the [B, T, 2F] up-projection above all) are recomputed
-    in the backward from ``x`` — the attention residuals stay saved."""
+    in the backward from ``x`` — the attention residuals stay saved.
+
+    ``routing``: an expert layer's (ids, weights), made from the block's
+    normed input; the feed-forward is then the held experts'."""
     with jax.named_scope("attn"):
         o = jnp.einsum("bthk,hkd->btd", o, layer["wo"].astype(cfg.dtype))
         if tp_axis:
@@ -316,10 +419,14 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
             o = rms_norm(o, layer["ln1_out"], cfg.norm_eps)
         x = x + o
 
-    def norm_ffn(layer, x):
+    def norm_ffn(layer, x, routing):
         h = rms_norm(x, layer["ln2"], cfg.norm_eps)
-        m = (ffn(layer, h) if ffn is not None
-             else _dense_ffn(layer, h, cfg, tp_axis))
+        if ffn is not None:
+            m = ffn(layer, h)
+        elif routing is not None:
+            m = _routed_ffn(layer, h, routing, cfg)
+        else:
+            m = _dense_ffn(layer, h, cfg, tp_axis)
         if cfg.out_norms:
             m = rms_norm(m, layer["ln2_out"], cfg.norm_eps)
         return m
@@ -327,16 +434,20 @@ def _layer_finish(layer, x, o, cfg: GPTConfig,
     if remat_ffn:
         norm_ffn = jax.checkpoint(norm_ffn)
     with jax.named_scope("ffn"):
-        return x + norm_ffn(layer, x)
+        return x + norm_ffn(layer, x, routing)
 
 
 def _attend(q, kk, v, attn: str, sp_axis: Optional[str],
-            kv_groups: int = 1):
+            kv_groups: int = 1, window: Optional[int] = None):
     """``kk``/``v`` arrive COMPACT (kv_heads) under GQA: the sp paths
     transport them compact and expand at local compute (kv_groups-times
-    less inter-chip KV traffic); local paths expand here."""
+    less inter-chip KV traffic); local paths expand here.  ``window``:
+    the local paths' sliding window (None: plain causal)."""
     if attn in ("ring", "ring_flash", "ulysses") and sp_axis is None:
         raise ValueError(f"attn={attn!r} needs a sequence-parallel axis")
+    if window is not None and attn not in ("flash", "dense"):
+        raise ValueError(f"attn={attn!r} has no sliding window: only the "
+                         f"local paths (flash, dense) take one")
     if attn == "ring":
         return ring_attention(q, kk, v, sp_axis, causal=True,
                               kv_groups=kv_groups)
@@ -349,12 +460,13 @@ def _attend(q, kk, v, attn: str, sp_axis: Optional[str],
                                  kv_groups=kv_groups)
     if attn == "flash":
         from ..ops.flash_attention import flash_attention
-        return flash_attention(q, kk, v, causal=True, kv_groups=kv_groups)
+        return flash_attention(q, kk, v, causal=True, kv_groups=kv_groups,
+                               window=window)
     if attn == "dense":
         from ..ops.flash_attention import _expand_kv_heads
         return reference_attention(q, _expand_kv_heads(kk, kv_groups),
                                    _expand_kv_heads(v, kv_groups),
-                                   causal=True)
+                                   causal=True, window=window)
     raise ValueError(f"unknown attention mode {attn!r}")
 
 
@@ -365,11 +477,18 @@ def apply_layer(layer, x, cfg: GPTConfig, *,
                 ffn: Optional[Any] = None,
                 pos=None,
                 remat_ffn: bool = False,
-                remat_around_attn: bool = False):
+                remat_around_attn: bool = False,
+                index: int = 0):
     """One transformer block on (local) activations ``x`` [B, T, D].
     ``pos`` [T]: GLOBAL token positions — required whenever the sequence
     is sharded (sp_axis) so RoPE rotates by global offsets; defaults to
-    arange only in the unsharded case.
+    arange only in the unsharded case.  ``index``: which layer of the
+    configuration this is, where layers differ (``cfg.rope``,
+    ``cfg.window`` as tuples).
+
+    With ``cfg.n_experts`` the feed-forward is the routed one: the routing
+    is computed here from the attention's input (the output of ``ln1``)
+    and handed to the feed-forward, which reads the output of ``ln2``.
 
     ``remat_around_attn`` implements selective remat structurally: the
     qkv projections and the (output-projection + FFN) tail each sit in
@@ -379,24 +498,48 @@ def apply_layer(layer, x, cfg: GPTConfig, *,
     re-runs the attention kernel, while everything cheap to recompute
     (norms, projections, the [B, T, 2F] FFN blow-up) is rematerialized.
     """
+    rope, window = cfg.layer_rope(index), cfg.layer_window(index)
     if pos is None:
-        if cfg.rope and sp_axis is not None:
+        if rope and sp_axis is not None:
             raise ValueError("RoPE under sequence parallelism needs "
                              "explicit global positions (pos)")
         pos = jnp.arange(x.shape[1])
+    routed = bool(cfg.n_experts) and ffn is None
 
-    qkv_fn = functools.partial(_layer_qkv, cfg=cfg, pos=pos)
+    qkv_fn = functools.partial(
+        _layer_qkv, cfg=cfg, pos=pos, rope=rope,
+        route=functools.partial(_route, cfg=cfg) if routed else None)
     if remat_around_attn:
         qkv_fn = jax.checkpoint(qkv_fn)
-    q, kk, v = qkv_fn(layer, x)
+    q, kk, v, *routing = qkv_fn(layer, x)
     with jax.named_scope("attn"):
-        o = _attend(q, kk, v, attn, sp_axis, kv_groups=cfg.kv_groups)
+        o = _attend(q, kk, v, attn, sp_axis, kv_groups=cfg.kv_groups,
+                    window=window)
 
     finish = functools.partial(_layer_finish, cfg=cfg, tp_axis=tp_axis,
                                ffn=ffn, remat_ffn=remat_ffn)
     if remat_around_attn:
         finish = jax.checkpoint(finish)
+    if routed:
+        return finish(layer, x, o, routing=routing[0])
     return finish(layer, x, o)
+
+
+def _local_attn(attn: str, T: int, sp_axis: Optional[str] = None) -> str:
+    """What ``attn="auto"`` means for sequences of ``T`` on this backend."""
+    if attn != "auto":
+        return attn
+
+    def _flash_ok():
+        from ..ops.flash_attention import fit_block
+        try:
+            return fit_block(T, 512) >= 128  # tiny blocks lose to dense
+        except ValueError:
+            return False
+    on_tpu = jax.default_backend() == "tpu"
+    if sp_axis:
+        return "ring_flash" if (on_tpu and _flash_ok()) else "ring"
+    return "flash" if (on_tpu and _flash_ok()) else "dense"
 
 
 # what remat="full" keeps of a layer beside its input: the flash kernel's
@@ -431,35 +574,35 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
     otherwise: a step pays for it with the layers' forward a second time.
     ``"ffn"`` and ``"attn"``: see :func:`apply_layer`."""
     T = tokens.shape[1]
-    if attn == "auto":
-        def _flash_ok():
-            from ..ops.flash_attention import fit_block
-            try:
-                return fit_block(T, 512) >= 128  # tiny blocks lose to dense
-            except ValueError:
-                return False
-        on_tpu = jax.default_backend() == "tpu"
-        if sp_axis:
-            attn = "ring_flash" if (on_tpu and _flash_ok()) else "ring"
-        else:
-            attn = "flash" if (on_tpu and _flash_ok()) else "dense"
+    attn = _local_attn(attn, T, sp_axis)
     offset = lax.axis_index(sp_axis) * T if sp_axis else 0
     pos = offset + jnp.arange(T)
 
     x = embed(params, tokens, pos[None], cfg)
 
-    layer_fn = functools.partial(apply_layer, cfg=cfg, tp_axis=tp_axis,
-                                 sp_axis=sp_axis, attn=attn, pos=pos,
-                                 remat_ffn=(remat == "ffn"),
-                                 remat_around_attn=(remat == "attn"))
-    if remat in (True, "full"):
-        layer_fn = jax.checkpoint(layer_fn, policy=_FULL_REMAT_KEEPS)
-    elif remat not in (False, None, "", "none", "ffn", "attn"):
+    if remat not in (True, "full", False, None, "", "none", "ffn", "attn"):
         raise ValueError(f"unknown remat mode {remat!r}")
 
+    by_kind = {}
+
+    def layer_fn(i):
+        """One function for all the layers of layer ``i``'s kind: where
+        every layer is of one kind, one function for all of them."""
+        kind = (cfg.layer_rope(i), cfg.layer_window(i))
+        if kind not in by_kind:
+            fn = functools.partial(apply_layer, cfg=cfg, tp_axis=tp_axis,
+                                   sp_axis=sp_axis, attn=attn, pos=pos,
+                                   remat_ffn=(remat == "ffn"),
+                                   remat_around_attn=(remat == "attn"),
+                                   index=i)
+            if remat in (True, "full"):
+                fn = jax.checkpoint(fn, policy=_FULL_REMAT_KEEPS)
+            by_kind[kind] = fn
+        return by_kind[kind]
+
     def run(x):
-        for layer in params["layers"]:
-            x = layer_fn(layer, x)
+        for i, layer in enumerate(params["layers"]):
+            x = layer_fn(i)(layer, x)
         return x
     return x, run
 
@@ -500,6 +643,38 @@ def _one_round_only(cfg: GPTConfig, what: str) -> None:
         raise ValueError(f"{what} runs the layers once; n_rounds="
                          f"{cfg.n_rounds} is trained by models/looped.py "
                          f"and has no decode path")
+
+
+def _plain_layers_only(cfg: GPTConfig, what: str) -> None:
+    """The decode paths, the cache and the pipeline walk layers of one
+    kind, full causal attention over a dense feed-forward: refuse by name
+    what they would compute wrongly."""
+    _one_round_only(cfg, what)
+    if (isinstance(cfg.rope, (tuple, list)) or cfg.window is not None
+            or cfg.n_experts):
+        raise ValueError(f"{what} walks layers of one kind, full causal "
+                         f"attention over a dense feed-forward; a sliding "
+                         f"window, layers of several kinds and a routed "
+                         f"feed-forward run through layer_stack only")
+
+
+def held_rows(params, tokens, cfg: GPTConfig, *, attn: str = "auto"):
+    """A probe of an expert model: for each layer, how many of the tokens'
+    assignments go to the experts held here, [n_layers] int32 — the rows
+    its grouped products multiply in a forward over ``tokens`` [B, T].  A
+    forward of its own, for reading beside a training job, not inside."""
+    if not cfg.n_experts:
+        raise ValueError("held_rows reads an expert model (cfg.n_experts)")
+    from ..parallel.moe import held_assignments
+    T = tokens.shape[1]
+    attn, pos = _local_attn(attn, T), jnp.arange(T)
+    x = embed(params, tokens, pos[None], cfg)
+    counts = []
+    for i, layer in enumerate(params["layers"]):
+        ids, _ = _route(layer, rms_norm(x, layer["ln1"], cfg.norm_eps), cfg)
+        counts.append(jnp.sum(held_assignments(ids, cfg.held)))
+        x = apply_layer(layer, x, cfg, attn=attn, pos=pos, index=i)
+    return jnp.stack(counts)
 
 
 def forward_local(params, tokens, cfg: GPTConfig, *,
@@ -600,7 +775,7 @@ def _decode_hidden(params, cfg: GPTConfig, cache, pos, token,
     Under ``tp_axis`` the cache and q/k/v hold the local head shard and
     the per-layer psums restore replicated activations — the same
     Megatron sharding as training."""
-    _one_round_only(cfg, "_decode_hidden")
+    _plain_layers_only(cfg, "_decode_hidden")
     x = embed(params, token[:, None], pos, cfg)               # [B, 1, D]
     pos1 = jnp.reshape(pos, (1,))
     new_cache = []

@@ -59,32 +59,41 @@ _LOG2E = 1.4426950408889634
 _INV_LOG2E = 1.0 / _LOG2E
 
 
-def _causal_tile_classes(iq, ik, block_q, block_k):
+def _causal_tile_classes(iq, ik, block_q, block_k, window=None):
     """Classify tile (iq, ik) against the causal diagonal — the single
     source of truth for all the kernels (fwd, bwd-dq, bwd-dkv).
     Returns (below, on_diag, visible): ``below`` = every key position in
     the tile visible to every query (no mask needed), ``on_diag`` =
     straddles the diagonal (mask required), ``visible`` = any pair
-    visible."""
+    visible.
+
+    ``window``: query i sees key j only where ``i - j < window`` as well.
+    A tile wholly left of that band is not visible; one that straddles the
+    band's left edge needs the mask like one on the diagonal."""
     q_lo = iq * block_q
     q_hi = q_lo + block_q - 1
     k_lo = ik * block_k
     k_hi = k_lo + block_k - 1
     visible = k_lo <= q_hi
     below = k_hi <= q_lo
-    on_diag = visible & (k_hi > q_lo)
-    return below, on_diag, visible
+    if window is None:
+        return below, visible & (k_hi > q_lo), visible
+    visible = visible & (q_lo - k_hi < window)
+    below = below & (q_hi - k_lo < window)
+    return below, visible & jnp.logical_not(below), visible
 
 
-def _causal_dispatch(body, causal, iq, ik, block_q, block_k):
+def _causal_dispatch(body, causal, iq, ik, block_q, block_k, window=None):
     """Run ``body(masked=...)`` once per visible tile: fully-visible
     tiles skip the mask iota/compare/select, only the tiles that straddle
-    the diagonal pay it.  Blocks strictly above the diagonal run nothing
-    — their grid steps are predicated off."""
+    the diagonal (or the left edge of ``window``'s band) pay it.  Blocks
+    strictly above the diagonal or left of the band run nothing — their
+    grid steps are predicated off."""
     if not causal:
         body(masked=False)
         return
-    below, on_diag, _ = _causal_tile_classes(iq, ik, block_q, block_k)
+    below, on_diag, _ = _causal_tile_classes(iq, ik, block_q, block_k,
+                                             window)
 
     @pl.when(below)
     def _():
@@ -95,31 +104,56 @@ def _causal_dispatch(body, causal, iq, ik, block_q, block_k):
         body(masked=True)
 
 
-def _k_block_index(causal, block_q, block_k):
+def _k_block_index(causal, block_q, block_k, window=None):
     """The k-side block a grid step (iq, ik) asks for.  Causal: never a
     block above the diagonal — a step that runs nothing names the last
     visible k block of its q row again, and a repeated index is not
-    fetched again (the dead step costs a grid step and no DMA)."""
+    fetched again (the dead step costs a grid step and no DMA).  With a
+    ``window`` never one left of the band either: those steps name the
+    row's first visible k block."""
     if not causal:
         return lambda iq, ik: ik
-    return lambda iq, ik: jnp.minimum(
-        ik, (iq * block_q + block_q - 1) // block_k)
+    if window is None:
+        return lambda iq, ik: jnp.minimum(
+            ik, (iq * block_q + block_q - 1) // block_k)
+    return lambda iq, ik: jnp.clip(
+        ik, jnp.maximum(iq * block_q - window + 1, 0) // block_k,
+        (iq * block_q + block_q - 1) // block_k)
 
 
-def _q_block_index(causal, block_q, block_k, n_q):
+def _q_block_index(causal, block_q, block_k, n_q, window=None):
     """The q-side block (q, dO, lse, delta) a grid step (iq, ik) asks
     for; causal: the first visible q block of the k column in place of
     one above the diagonal (clamped: with T_k > T_q a k column may see
-    no q row at all)."""
+    no q row at all).  With a ``window`` the column's last visible q block
+    in place of one past the band."""
     if not causal:
         return lambda iq, ik: iq
-    return lambda iq, ik: jnp.minimum(
-        jnp.maximum(iq, ik * block_k // block_q), n_q - 1)
+    if window is None:
+        return lambda iq, ik: jnp.minimum(
+            jnp.maximum(iq, ik * block_k // block_q), n_q - 1)
+    return lambda iq, ik: jnp.clip(
+        iq, jnp.minimum(ik * block_k // block_q, n_q - 1),
+        jnp.minimum((ik * block_k + block_k + window - 2) // block_q,
+                    n_q - 1))
+
+
+def _mask_tile(s, iq, ik, block_q, block_k, window):
+    """Scores of tile (iq, ik) with the pairs no query may see at NEG_INF:
+    keys after the query, and with a ``window`` keys ``window`` or more
+    positions before it."""
+    qpos = iq * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    kpos = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    if window is None:
+        return jnp.where(qpos >= kpos, s, NEG_INF)
+    return jnp.where((qpos >= kpos) & (qpos - kpos < window), s, NEG_INF)
 
 
 # ------------------------------------------------------------------ forward
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q,
-               block_k, n_k, with_lse):
+               block_k, n_k, with_lse, window=None):
     if with_lse:
         lse_ref, acc, m, l = rest
     else:
@@ -147,11 +181,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q,
                                 preferred_element_type=jnp.float32
                                 ) * (scale * _LOG2E)
         if masked:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            s = _mask_tile(s, iq, ik, block_q, block_k, window)
         m_prev = m[:, :1]
         s_max = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, s_max)
@@ -164,7 +194,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _causal_dispatch(_attend, causal, iq, ik, block_q, block_k)
+    _causal_dispatch(_attend, causal, iq, ik, block_q, block_k, window)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -207,9 +237,10 @@ def _block_sizes(T, Tk, block_q, block_k):
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
-                   interpret: bool, with_lse: bool):
+                   interpret: bool, with_lse: bool, window=None):
     """``with_lse`` is set only on the VJP path — the primal would just
-    discard the [B, H, T, 128] residual (HBM allocation + write)."""
+    discard the [B, H, T, 128] residual (HBM allocation + write).
+    ``window`` (static, causal only): see :func:`flash_attention`."""
     B, T, H, D = q.shape
     Tk = k.shape[1]
     block_q, block_k = _block_sizes(T, Tk, block_q, block_k)
@@ -224,10 +255,10 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     vt = jnp.transpose(v, (0, 2, 1, 3))
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, n_k=n_k,
-                               with_lse=with_lse)
+                               with_lse=with_lse, window=window)
     o_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, iq, ik: (b, h, iq, 0))
-    k_at = _k_block_index(causal, block_q, block_k)
+    k_at = _k_block_index(causal, block_q, block_k, window)
     k_spec = pl.BlockSpec((1, 1, block_k, D),
                           lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
     out_specs = [o_spec]
@@ -266,7 +297,7 @@ def _fa_delta_kernel(o_ref, do_ref, delta_ref):
 
 
 def _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, masked,
-                scale, block_q, block_k, iq, ik):
+                scale, block_q, block_k, iq, ik, window=None):
     """Recompute p and ds for one (q-block, k-block) pair, all f32.
     Base-2 like the forward: p = 2^(s*scale*log2e - lse*log2e).
     ``masked`` is True only for causal blocks straddling the diagonal —
@@ -280,11 +311,7 @@ def _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, masked,
                             preferred_element_type=jnp.float32
                             ) * (scale * _LOG2E)
     if masked:
-        qpos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = _mask_tile(s, iq, ik, block_q, block_k, window)
     lse = lse_ref[0, 0, :, :1] * _LOG2E                   # [bq, 1], base-2
     p = jnp.exp2(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -296,7 +323,7 @@ def _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, masked,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc, *, causal, scale, block_q, block_k,
-                      n_k):
+                      n_k, window=None):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -308,13 +335,13 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         _, ds, _, _ = _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                   delta_ref, masked=masked, scale=scale,
                                   block_q=block_q, block_k=block_k,
-                                  iq=iq, ik=ik)
+                                  iq=iq, ik=ik, window=window)
         k = k_ref[0, 0, :, :]
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _causal_dispatch(_accum, causal, iq, ik, block_q, block_k)
+    _causal_dispatch(_accum, causal, iq, ik, block_q, block_k, window)
 
     @pl.when(ik == n_k - 1)
     def _finish():
@@ -323,7 +350,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        *rest, causal, scale, block_q, block_k, n_q, n_k,
-                       with_dq):
+                       with_dq, window=None):
     """dk and dv of one k block, q innermost.  ``with_dq``: the fused
     backward — the tile's ``ds`` also adds its q rows' ``ds k`` into a
     [T, D] f32 scratch that holds the whole sequence's dq of this
@@ -350,7 +377,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p, ds, q, do = _block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, masked=masked, scale=scale,
                                    block_q=block_q, block_k=block_k,
-                                   iq=iq, ik=ik)
+                                   iq=iq, ik=ik, window=window)
         ds = ds.astype(q.dtype)
         # dv += p^T dO ; dk += ds^T q
         dv_acc[...] += jax.lax.dot_general(
@@ -365,7 +392,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds, k_ref[0, 0, :, :], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _causal_dispatch(_accum, causal, iq, ik, block_q, block_k)
+    _causal_dispatch(_accum, causal, iq, ik, block_q, block_k, window)
 
     @pl.when(iq == n_q - 1)
     def _finish():
@@ -395,7 +422,7 @@ def _fused_backward(T: int, D: int) -> bool:
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                    interpret, dlse=None):
+                    interpret, dlse=None, window=None):
     """``dlse`` (optional, [B, H, T] f32): cotangent of the lse output.
     It folds into the per-row term of ``ds`` — mathematically
     d lse/d s = p, so ds picks up ``+ p * dlse`` exactly where the delta
@@ -436,7 +463,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
             dlse.astype(jnp.float32)[..., None], delta.shape)
 
     # q innermost for dk/dv: k/v block indexed by grid axis 2
-    q_at = _q_block_index(causal, block_q, block_k, n_q)
+    q_at = _q_block_index(causal, block_q, block_k, n_q, window)
     kq_spec = pl.BlockSpec((1, 1, block_q, D),
                            lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
@@ -461,7 +488,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     outs = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k, n_q=n_q,
-                          n_k=n_k, with_dq=fused),
+                          n_k=n_k, with_dq=fused, window=window),
         grid=(B, H, n_k, n_q),
         in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, krow_spec, krow_spec],
         out_specs=out_specs,
@@ -476,7 +503,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     if fused:
         dq = outs[0]
     else:
-        k_at = _k_block_index(causal, block_q, block_k)
+        k_at = _k_block_index(causal, block_q, block_k, window)
         q_spec = pl.BlockSpec((1, 1, block_q, D),
                               lambda b, h, iq, ik: (b, h, iq, 0))
         k_spec = pl.BlockSpec((1, 1, block_k, D),
@@ -485,7 +512,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                                 lambda b, h, iq, ik: (b, h, iq, 0))
         dq = pl.pallas_call(
             functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
-                              block_q=block_q, block_k=block_k, n_k=n_k),
+                              block_q=block_q, block_k=block_k, n_k=n_k,
+                              window=window),
             grid=(B, H, n_q, n_k),
             in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
             out_specs=q_spec,
@@ -511,7 +539,7 @@ def _use_jnp_fallback(q) -> bool:
     return _auto_interpret() and bool(getattr(jax.typeof(q), "vma", ()))
 
 
-def _jnp_flash(q, k, v, causal):
+def _jnp_flash(q, k, v, causal, window=None):
     """Differentiable jnp twin of the kernel: (out, lse [B, H, T] f32)."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
@@ -519,6 +547,9 @@ def _jnp_flash(q, k, v, causal):
     if causal:
         Tq, Tk = s.shape[2], s.shape[3]
         mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            mask = mask & (jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]
+                           < window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     m = jax.lax.stop_gradient(jnp.max(s, axis=-1))
     p = jnp.exp(s - m[..., None])
@@ -543,21 +574,22 @@ def _compact_kv_grad(dt, kv_groups: int):
     return dt.reshape(B, T, H // kv_groups, kv_groups, D).sum(axis=3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_pallas(q, k, v, causal, block_q, block_k, kv_groups,
-                            bwd_blocks):
+                            bwd_blocks, window=None):
     out, _ = _flash_forward(q, _expand_kv_heads(k, kv_groups),
                             _expand_kv_heads(v, kv_groups), causal,
                             block_q, block_k, _auto_interpret(),
-                            with_lse=False)
+                            with_lse=False, window=window)
     return out
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, kv_groups, bwd_blocks):
+def _fa_fwd(q, k, v, causal, block_q, block_k, kv_groups, bwd_blocks,
+            window=None):
     out, lse = _flash_forward(q, _expand_kv_heads(k, kv_groups),
                               _expand_kv_heads(v, kv_groups), causal,
                               block_q, block_k, _auto_interpret(),
-                              with_lse=True)
+                              with_lse=True, window=window)
     # what the kernel made carries a name, so that a checkpoint policy
     # around the caller (gpt.layer_stack's under remat="full") can keep it
     # and spare the backward a second flash_fwd; the primal output and the
@@ -571,13 +603,14 @@ def _fa_fwd(q, k, v, causal, block_q, block_k, kv_groups, bwd_blocks):
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, kv_groups, bwd_blocks, res, g):
+def _fa_bwd(causal, block_q, block_k, kv_groups, bwd_blocks, window, res,
+            g):
     q, k, v, out, lse = res
     bq, bk = bwd_blocks or (block_q, block_k)
     dq, dk, dv = _flash_backward(q, _expand_kv_heads(k, kv_groups),
                                  _expand_kv_heads(v, kv_groups), out, lse,
                                  g, causal, bq, bk,
-                                 _auto_interpret())
+                                 _auto_interpret(), window=window)
     return (dq, _compact_kv_grad(dk, kv_groups),
             _compact_kv_grad(dv, kv_groups))
 
@@ -626,8 +659,13 @@ _BWD_BLOCKS_CAP = 1024   # backward VMEM budget ceiling (see above)
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, kv_groups: int = 1,
-                    bwd_blocks=None):
+                    bwd_blocks=None, window: Optional[int] = None):
     """Pallas flash attention, [B, T, H, D] → [B, T, H, D].
+
+    ``window`` (static, needs ``causal``): query i sees keys j with
+    ``0 <= i - j < window``.  Tiles wholly left of that band are neither
+    fetched nor computed, tiles on its left edge are masked; ``None`` is
+    plain causal attention, the same kernels as before the argument.
 
     ``kv_groups > 1``: GQA — ``k``/``v`` arrive compact ([B, T, H/g, D])
     and are expanded inside the VJP so the saved residuals stay compact.
@@ -647,11 +685,14 @@ def flash_attention(q, k, v, causal: bool = False,
     if bwd_blocks is None:
         bwd_blocks = (min(block_q, _BWD_BLOCKS_CAP),
                       min(block_k, _BWD_BLOCKS_CAP))
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and at least "
+                         f"one visible position")
     if _use_jnp_fallback(q):
         return _jnp_flash(q, _expand_kv_heads(k, kv_groups),
-                          _expand_kv_heads(v, kv_groups), causal)[0]
+                          _expand_kv_heads(v, kv_groups), causal, window)[0]
     return _flash_attention_pallas(q, k, v, causal, block_q, block_k,
-                                   kv_groups, bwd_blocks)
+                                   kv_groups, bwd_blocks, window)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

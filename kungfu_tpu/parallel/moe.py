@@ -17,10 +17,23 @@ Design (the canonical TPU MoE dataflow):
 Composes with data parallelism: batch axes (dp and ep both carry tokens
 outside the expert block) shard the tokens; only the expert weights are
 ep-sharded.  Gradient psums are inserted by shard_map's varying-axis AD.
+
+Beside it, the layer large sparse models train with
+(:func:`dropless_moe_ffn`): softmax routing over all experts, the top k
+of them a token with the weights normalised over the k, no capacity and
+no dropped token, gated experts (ReGLU), and a share of the experts held
+here: the layer is told which contiguous range of expert ids its weights
+are, routes over all of them, and computes the part of the result its own
+experts give.  The held assignments are sorted by expert into a row
+buffer and go through :func:`grouped_matmul`, whose work follows the rows
+held (a loop over the row blocks in use), not the buffer's static size.
+On one chip there is no exchange; what the experts held elsewhere would
+add is left out.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Sequence, Tuple
 
@@ -183,3 +196,430 @@ def make_moe_step(cfg: MoEConfig, optimizer, mesh: Mesh,
 
     kwargs = {"donate_argnums": (0, 1)} if donate else {}
     return jax.jit(step, **kwargs)
+
+
+# ------------------------------------------------- an expert layer's share
+def route_topk(h, router, k: int):
+    """Softmax routing in float32 over all of ``router``'s experts:
+    ``h`` [n, D], ``router`` [D, E] -> ``(ids [n, k] int32, weights [n, k]
+    f32)``, the k most probable experts of each token, most probable first
+    (of equal probabilities the lower id), and their probabilities divided
+    by their sum over the k."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = lax.top_k(probs, k)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return ids.astype(jnp.int32), weights
+
+
+def _visits(group_sizes, n_rows: int, block: int):
+    """Which (row block, group) pairs a grouped product has to visit:
+    ``(n_visits, group [V], block [V], starts [G], ends [G])``.  Group g
+    holds rows ``starts[g] <= r < ends[g]``, laid end to end; a visit is a
+    row block that holds rows of the group, so a group of n rows takes at
+    most ``n // block + 2`` of them and all groups together at most
+    ``V = n_rows // block + G - 1``.  ``n_visits`` is a traced number: what
+    the loops below run to."""
+    G = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // block
+    per_group = jnp.where(sizes > 0, (ends - 1) // block - first + 1, 0)
+    visit_ends = jnp.cumsum(per_group)
+    v = jnp.arange(n_rows // block + G - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(visit_ends, v, side="right"),
+                        G - 1).astype(jnp.int32)
+    blk = first[group] + v - (visit_ends - per_group)[group]
+    return visit_ends[-1], group, jnp.clip(blk, 0, n_rows // block - 1), \
+        starts, ends
+
+
+def _zeros_like_of(like, shape, dtype):
+    """Zeros that vary over the mesh axes ``like`` varies over: inside
+    ``shard_map`` a loop's carry has to be of one type going in and coming
+    out (shard_map#scan-vma).  (An unwritten buffer, ``lax.empty``, would
+    spare the fill, 35 ms a step in the benchmark's cell; the chip's
+    compiler gives each one memory of its own for the whole program, 20.45
+    GB for that step: PERF.md section 6, PR 35.)"""
+    z = jnp.zeros(shape, dtype)
+    vma = tuple(getattr(jax.typeof(like), "vma", ()) or ())
+    return lax.pcast(z, vma, to="varying") if vma else z
+
+
+def _gmm(rows, weights, group_sizes, block: int, transposed: bool,
+         whole: bool = False):
+    """``rows [M, K] x weights [G, K, N]`` by groups of rows (``transposed``:
+    ``weights [G, N, K]``, contracted over their last axis).  ``whole``:
+    every group is whole blocks, so a visit writes its block whole, with no
+    mask and without reading what was there."""
+    M = rows.shape[0]
+    N = weights.shape[1] if transposed else weights.shape[2]
+    n_visits, group, blk, starts, ends = _visits(group_sizes, M, block)
+    contract = (((1,), (1 if transposed else 0,)), ((), ()))
+
+    def visit(v, out):
+        g, at = group[v], blk[v] * block
+        x = lax.dynamic_slice_in_dim(rows, at, block)
+        w = lax.dynamic_index_in_dim(weights, g, keepdims=False)
+        y = lax.dot_general(x, w, contract,
+                            preferred_element_type=jnp.float32
+                            ).astype(out.dtype)
+        if not whole:
+            r = at + jnp.arange(block, dtype=jnp.int32)
+            mine = ((r >= starts[g]) & (r < ends[g]))[:, None]
+            y = jnp.where(mine, y, lax.dynamic_slice_in_dim(out, at, block))
+        return lax.dynamic_update_slice_in_dim(out, y, at, 0)
+
+    return lax.fori_loop(0, n_visits, visit,
+                         _zeros_like_of(rows, (M, N), rows.dtype))
+
+
+def _gmm_dw(rows, dout, group_sizes, n_groups: int, block: int,
+            whole: bool = False):
+    """Each group's ``rows_g^T dout_g``: [G, K, N] in float32."""
+    M, K = rows.shape
+    N = dout.shape[1]
+    n_visits, group, blk, starts, ends = _visits(group_sizes, M, block)
+
+    def visit(v, dw):
+        g, at = group[v], blk[v] * block
+        x = lax.dynamic_slice_in_dim(rows, at, block)
+        if not whole:
+            r = at + jnp.arange(block, dtype=jnp.int32)
+            x = jnp.where(((r >= starts[g]) & (r < ends[g]))[:, None], x, 0)
+        d = lax.dynamic_slice_in_dim(dout, at, block)
+        part = lax.dot_general(x, d, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+        old = lax.dynamic_index_in_dim(dw, g, keepdims=False)
+        return lax.dynamic_update_index_in_dim(dw, old + part, g, 0)
+
+    return lax.fori_loop(
+        0, n_visits, visit,
+        _zeros_like_of(rows, (n_groups, K, N), jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_matmul(rows, weights, group_sizes, block, whole):
+    with jax.named_scope("gmm"):
+        return _gmm(rows, weights, group_sizes, block, False, whole)
+
+
+def _grouped_matmul_fwd(rows, weights, group_sizes, block, whole):
+    return (_grouped_matmul(rows, weights, group_sizes, block, whole),
+            (rows, weights, group_sizes))
+
+
+def _grouped_matmul_bwd(block, whole, res, dout):
+    rows, weights, group_sizes = res
+    dout = dout.astype(rows.dtype)
+    with jax.named_scope("gmm"):
+        drows = _gmm(dout, weights, group_sizes, block, True, whole)
+        dw = _gmm_dw(rows, dout, group_sizes, weights.shape[0], block, whole)
+    return drows, dw.astype(weights.dtype), None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def grouped_matmul(rows, weights, group_sizes, block_rows: int = 512,
+                   whole_blocks: bool = False):
+    """``jax.lax.ragged_dot``'s product by its signature: ``rows`` [M, K]
+    lie sorted by group, the first ``group_sizes[0]`` rows are group 0's
+    and so on; row r of group g gives ``rows[r] @ weights[g]`` (``weights``
+    [G, K, N]) and a row past the groups gives nought.  -> [M, N].
+
+    Plain JAX whose work follows the rows held: a loop over the row blocks
+    in use (:func:`_visits`; a block that holds rows of two groups is
+    visited for each), one group's weights a visit, so it multiplies the
+    held rows and less than ``block_rows`` a group more, never all M.  jax
+    does not reverse a loop of traced length, so the backward is written
+    out (``custom_vjp``): the rows' cotangent is the same loop over the
+    weights transposed, a group's weight cotangent the sum over its visits
+    of ``rows^T dout``.  Operands are multiplied in their own dtype with
+    float32 accumulation.  ``M`` must be a multiple of ``block_rows``
+    (shorter buffers take one block).  The scope ``gmm`` stands around
+    both passes.
+
+    ``whole_blocks``: the caller's promise that every group is a whole
+    number of blocks.  A visit then writes its block without a mask and
+    without reading what was there, in the backward too."""
+    M = rows.shape[0]
+    block = min(block_rows, M)
+    if M % block:
+        raise ValueError(f"{M} rows are no multiple of block_rows={block}")
+    return _grouped_matmul(rows, weights, group_sizes, block, whole_blocks)
+
+
+def _blocks(n_rows, block: int):
+    """How many row blocks hold the first ``n_rows`` (a traced count)."""
+    return (n_rows + block - 1) // block
+
+
+@jax.custom_vjp
+def _pick(v, src, live, dest, here):
+    """``v[src]`` where ``live`` (nought elsewhere), for a map whose way
+    back is at hand (``dest``, taken where ``here``): its transpose is a
+    gather too, not a scatter."""
+    return jnp.where(live, v[src], 0)
+
+
+def _pick_fwd(v, src, live, dest, here):
+    return jnp.where(live, v[src], 0), (dest, here)
+
+
+def _pick_bwd(res, g):
+    dest, here = res
+    return jnp.where(here, g[dest], 0), None, None, None, None
+
+
+_pick.defvjp(_pick_fwd, _pick_bwd)
+
+
+def _take(x, tok, n_rows, block: int):
+    A = tok.shape[0]
+
+    def one(i, out):
+        idx = lax.dynamic_slice_in_dim(tok, i * block, block)
+        return lax.dynamic_update_slice_in_dim(out, x[idx], i * block, 0)
+
+    return lax.fori_loop(
+        0, _blocks(n_rows, block), one,
+        _zeros_like_of(x, (A, x.shape[1]), x.dtype))
+
+
+def _put(rows, scale, tok, n_rows, n_tokens: int, block: int):
+    def one(i, y):
+        at = i * block
+        idx = lax.dynamic_slice_in_dim(tok, at, block)
+        live = at + jnp.arange(block, dtype=jnp.int32) < n_rows
+        w = jnp.where(live, lax.dynamic_slice_in_dim(scale, at, block), 0.0)
+        part = lax.dynamic_slice_in_dim(rows, at, block).astype(jnp.float32)
+        # (told that a block's tokens rise and differ, which they do, the
+        # chip's scatter takes ten times as long: PERF.md section 6, PR 35)
+        return y.at[idx].add(part * w[:, None])
+
+    return lax.fori_loop(
+        0, _blocks(n_rows, block), one,
+        _zeros_like_of(rows, (n_tokens, rows.shape[1]), jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def take_rows(x, tok, n_rows, block):
+    """The sorted row buffer: row r is token ``tok[r]``'s, ``x[tok[r]]``,
+    for the ``n_rows`` rows in use (whole blocks of them: a loop over the
+    blocks in use, as in :func:`grouped_matmul`), nought after.  ``x``
+    [n, D], ``tok`` [M] -> [M, D].  A padding row reads token 0's row;
+    nothing reads what it becomes.  Its transpose is :func:`put_rows`."""
+    return _take(x, tok, n_rows, block)
+
+
+def _take_rows_fwd(x, tok, n_rows, block):
+    return _take(x, tok, n_rows, block), (tok, n_rows, x.shape[0])
+
+
+def _take_rows_bwd(block, res, g):
+    tok, n_rows, n_tokens = res
+    ones = jnp.ones(tok.shape, jnp.float32)
+    return (_put(g, ones, tok, n_rows, n_tokens, block).astype(g.dtype),
+            None, None)
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def put_rows(rows, scale, tok, n_rows, n_tokens, block):
+    """Back to the tokens: ``y[t] = sum of scale[r] rows[r]`` over the rows
+    in use with ``tok[r] == t`` (a token has a row for each held expert it
+    was sent to).  ``rows`` [M, D], ``scale`` [M] f32 -> [n_tokens, D] f32,
+    again a loop over the blocks in use.  A padding row has a ``scale`` of
+    nought and adds it to token 0."""
+    return _put(rows, scale, tok, n_rows, n_tokens, block)
+
+
+def _put_rows_fwd(rows, scale, tok, n_rows, n_tokens, block):
+    return (_put(rows, scale, tok, n_rows, n_tokens, block),
+            (rows, scale, tok, n_rows))
+
+
+def _put_rows_bwd(n_tokens, block, res, dy):
+    rows, scale, tok, n_rows = res
+
+    def one(i, carry):
+        drows, dscale = carry
+        at = i * block
+        idx = lax.dynamic_slice_in_dim(tok, at, block)
+        live = at + jnp.arange(block, dtype=jnp.int32) < n_rows
+        w = jnp.where(live, lax.dynamic_slice_in_dim(scale, at, block), 0.0)
+        d = dy[idx]                                         # [block, D] f32
+        r = lax.dynamic_slice_in_dim(rows, at, block).astype(jnp.float32)
+        return (lax.dynamic_update_slice_in_dim(
+                    drows, (d * w[:, None]).astype(rows.dtype), at, 0),
+                lax.dynamic_update_slice_in_dim(
+                    dscale, jnp.where(live, jnp.sum(d * r, -1), 0.0), at, 0))
+
+    drows, dscale = lax.fori_loop(
+        0, _blocks(n_rows, block), one,
+        (_zeros_like_of(rows, rows.shape, rows.dtype),
+         _zeros_like_of(rows, scale.shape, jnp.float32)))
+    return drows, dscale, None, None
+
+
+put_rows.defvjp(_put_rows_fwd, _put_rows_bwd)
+
+
+def _reglu(h, n_rows, block: int):
+    F = h.shape[1] // 2
+
+    def one(i, out):
+        g = lax.dynamic_slice_in_dim(h, i * block, block)
+        return lax.dynamic_update_slice_in_dim(
+            out, jax.nn.relu(g[:, :F]) * g[:, F:], i * block, 0)
+
+    return lax.fori_loop(
+        0, _blocks(n_rows, block), one,
+        _zeros_like_of(h, (h.shape[0], F), h.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def reglu_rows(h, n_rows, block):
+    """``relu(gate) * up`` of the rows in use: ``h`` [M, 2F] holds gate
+    then up -> [M, F], nought past the blocks in use."""
+    return _reglu(h, n_rows, block)
+
+
+def _reglu_rows_fwd(h, n_rows, block):
+    return _reglu(h, n_rows, block), (h, n_rows)
+
+
+def _reglu_rows_bwd(block, res, da):
+    h, n_rows = res
+    F = h.shape[1] // 2
+
+    def one(i, dh):
+        g = lax.dynamic_slice_in_dim(h, i * block, block)
+        d = lax.dynamic_slice_in_dim(da, i * block, block)
+        gate, up = g[:, :F], g[:, F:]
+        part = jnp.concatenate(
+            [jnp.where(gate > 0, d * up, 0), d * jax.nn.relu(gate)], axis=1)
+        return lax.dynamic_update_slice_in_dim(dh, part.astype(h.dtype),
+                                               i * block, 0)
+
+    return (lax.fori_loop(
+        0, _blocks(n_rows, block), one,
+        _zeros_like_of(h, h.shape, h.dtype)), None)
+
+
+reglu_rows.defvjp(_reglu_rows_fwd, _reglu_rows_bwd)
+
+
+def held_assignments(ids, held: Tuple[int, int]):
+    """How many of the assignments ``ids`` [n, k] go to each expert held
+    here (``held`` = first id, count): [count] int32."""
+    local = ids - held[0]
+    return jnp.sum((local[..., None] == jnp.arange(held[1])).reshape(
+        -1, held[1]), axis=0, dtype=jnp.int32)
+
+
+def row_buffer(ids, n: int, held: Tuple[int, int], block: int):
+    """Where the held assignments of ``ids`` [n, k] lie in the row buffer:
+    sorted by expert, each expert's group padded to whole blocks, so that
+    a block holds rows of one expert, in the order of their tokens.
+    ``M = (ceil(n k / block) + G) block`` rows have room for every
+    assignment and the padding.  Returns
+
+    - ``sizes`` [G]: each held expert's rows with its padding (multiples of
+      ``block``), and ``n_rows``, their sum: the rows in use;
+    - ``tok`` [M]: a live row's token; 0 for padding;
+    - ``which`` [M], ``live`` [M]: the assignment (index into the flattened
+      ``ids``) a live row stands for;
+    - ``dest`` [n k], ``here`` [n k]: the row of an assignment that is held
+      here."""
+    G, k = held[1], ids.shape[1]
+    A = n * k
+    M = (-(-A // block) + G) * block
+    local = (ids - held[0]).reshape(-1)                         # [A]
+    here = (local >= 0) & (local < G)
+    key = jnp.where(here, local, G)             # held elsewhere: sorted last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rank = jnp.argsort(order).astype(jnp.int32)
+    count = held_assignments(ids, held)
+    first = jnp.cumsum(count) - count           # in the sorted order
+    sizes = -(-count // block) * block
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes                       # in the buffer
+    b = jnp.arange(M, dtype=jnp.int32)
+    g = jnp.minimum(jnp.searchsorted(ends, b, side="right"),
+                    G - 1).astype(jnp.int32)
+    j = b - starts[g]
+    live = (b < ends[-1]) & (j < count[g])
+    which = order[jnp.clip(first[g] + j, 0, A - 1)]
+    tok = jnp.where(live, which // k, 0)
+    mine = jnp.minimum(key, G - 1)
+    dest = starts[mine] + rank - first[mine]
+    return sizes, ends[-1], tok, which, live, dest, here
+
+
+def expert_ffn(x, ids, weights, wi, wm, held: Tuple[int, int],
+               block_rows: int = 512):
+    """The held experts' part of a routed feed-forward without dropped
+    tokens.  ``x`` [n, D]; ``ids``, ``weights`` [n, k] from
+    :func:`route_topk`; ``wi`` [G, D, 2 F] (gate then up) and ``wm``
+    [G, F, D] the weights of experts ``held[0] <= id < held[0] + G``.
+    -> ``sum over a token's held experts e of w_e wm_e (relu(gate_e x) *
+    (up_e x))`` [n, D].  An assignment to an expert held elsewhere adds
+    nothing.
+
+    There is no capacity: the row buffer has room for every assignment
+    (n k rows) and a block of padding a held expert, and every pass over
+    it (the gather of the tokens' rows, the two grouped products, ReGLU
+    between them, the weighted sum back into the tokens) runs over the
+    blocks in use.  The held assignments lie sorted by expert, each
+    expert's group padded to a whole number of blocks, so a block holds
+    rows of one expert: the products multiply the held rows and less than
+    a block an expert more."""
+    n, D = x.shape
+    G = wi.shape[0]
+    if held[1] != G:
+        raise ValueError(f"told to hold {held[1]} experts, given {G}")
+    block = min(block_rows, ids.size)
+    with jax.named_scope("moe_route"):
+        sizes, n_rows, tok, which, live, dest, here = row_buffer(
+            ids, n, held, block)
+        scale = _pick(weights.reshape(-1), which, live, dest, here)
+        rows = take_rows(x, tok, n_rows, block)                 # [M, D]
+    h = grouped_matmul(rows, wi.astype(x.dtype), sizes, block, True)
+    with jax.named_scope("moe_act"):
+        a = reglu_rows(h, n_rows, block)
+    o = grouped_matmul(a, wm.astype(x.dtype), sizes, block, True)
+    with jax.named_scope("moe_route"):
+        return put_rows(o, scale, tok, n_rows, n, block).astype(x.dtype)
+
+
+def dropless_moe_ffn(params: dict, x, *, experts_per_token: int,
+                     held: Optional[Tuple[int, int]] = None,
+                     router_input=None, dtype: Any = None,
+                     block_rows: int = 512):
+    """An expert layer without dropped tokens that is told which experts
+    it holds.  ``params``: ``router`` [D, E] over ALL experts, ``wi``
+    [G, D, 2 F] and ``wm`` [G, F, D] of the G experts held here, ids
+    ``held[0] .. held[0] + G - 1`` (default: all E).  ``x`` [..., D] is what
+    the experts read; ``router_input`` what the router reads where that is
+    another tensor (a router placed before attention), default ``x``.
+    Returns the held experts' contribution, no residual, in ``x``'s dtype:
+    :func:`route_topk` then :func:`expert_ffn` under the scope ``moe``.
+    Experts run in ``dtype`` (default ``x``'s), the router in float32."""
+    G = params["wi"].shape[0]
+    held = held or (0, G)
+    lead, D = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, D)
+    rt = xt if router_input is None else router_input.reshape(-1, D)
+    with jax.named_scope("moe"):
+        ids, weights = route_topk(rt, params["router"], experts_per_token)
+        y = expert_ffn(xt.astype(dtype or x.dtype), ids, weights,
+                       params["wi"], params["wm"], held, block_rows)
+    return y.astype(x.dtype).reshape(*lead, D)

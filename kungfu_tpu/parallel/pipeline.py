@@ -184,7 +184,7 @@ def make_gpt_pp_train_step(cfg: G.GPTConfig,
     either way); for M < S it is (M-1) + S*v ticks, still below
     GPipe's overhead but short of the asymptotic formula.
     """
-    G._one_round_only(cfg, "make_gpt_pp_train_step")
+    G._plain_layers_only(cfg, "make_gpt_pp_train_step")
     tp = TP_AXIS if TP_AXIS in mesh.axis_names else None
     specs = pp_param_specs(cfg, tp=tp)
     data_spec = P(DP_AXIS, None)

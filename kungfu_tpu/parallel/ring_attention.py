@@ -193,15 +193,20 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     return to_seq(out)
 
 
-def reference_attention(q, k, v, causal: bool = False):
+def reference_attention(q, k, v, causal: bool = False, window=None):
     """Dense softmax attention — the correctness oracle and the local
-    kernel inside Ulysses.  [B, T, H, D] layout."""
+    kernel inside Ulysses.  [B, T, H, D] layout.  ``window`` (with
+    ``causal``): a query sees only the ``window`` newest positions, itself
+    included."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         Tq, Tk = s.shape[2], s.shape[3]
         mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            mask = mask & (jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]
+                           < window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

@@ -574,7 +574,7 @@ class DecodeEngine:
         if attend not in ("auto", "fused", "gather"):
             raise ValueError(f"attend must be auto|fused|gather, "
                              f"got {attend!r}")
-        G._one_round_only(cfg, "DecodeEngine")
+        G._plain_layers_only(cfg, "DecodeEngine")
         quant = kv_dtype == jnp.int8
         if kv_dtype is not None and not quant:
             raise ValueError("kv_dtype must be None (model dtype) or "

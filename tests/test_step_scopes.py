@@ -34,7 +34,8 @@ GPT_METRICS = ["forward_ms.gpt", "backward_ms.gpt", "recompute_ms.gpt",
                "optimizer_ms.gpt", "accumulate_ms.gpt", "ce_head_ms.gpt",
                "ce_head_other_ms.gpt"]
 FLASH_METRICS = ["flash_fwd_ms.gpt", "flash_bwd_ms.gpt",
-                 "flash_fwd_ms.ouro", "flash_bwd_ms.ouro"]
+                 "flash_fwd_ms.ouro", "flash_bwd_ms.ouro",
+                 "flash_fwd_ms.smallthinker", "flash_bwd_ms.smallthinker"]
 # the looped decoder: the same layers, four rounds under one set of weights
 OURO = dataclasses.replace(GPT, n_kv_heads=4, norm_eps=1e-6, rope_theta=1e6,
                            out_norms=True, n_rounds=4)
@@ -43,6 +44,18 @@ OURO_METRICS = ["forward_ms.ouro", "backward_ms.ouro", "recompute_ms.ouro",
                 "ce_head_other_ms.ouro", "exit_ms.ouro",
                 "loop_other_ms.ouro"]
 RESNET_METRICS = ["forward_ms.resnet", "backward_ms.resnet"]
+# the sparse decoder: a head size of its own, layers of two kinds, a routed
+# feed-forward without dropped tokens over the experts held here
+SPARSE = GPTConfig(vocab_size=256, d_model=40, n_heads=6, d_head=8,
+                   n_kv_heads=2, n_layers=2, d_ff=0, max_seq=128,
+                   rope=(False, True), window=(None, 64), mlp="reglu",
+                   n_experts=8, experts_per_token=3, d_expert=16,
+                   experts_held=(2, 4), norm_eps=1e-6, rope_theta=1.5e6)
+SPARSE_METRICS = ["forward_ms.smallthinker", "backward_ms.smallthinker",
+                  "recompute_ms.smallthinker", "optimizer_ms.smallthinker",
+                  "accumulate_ms.smallthinker", "ce_head_ms.smallthinker",
+                  "moe_ms.smallthinker", "moe_route_ms.smallthinker",
+                  "gmm_ms.smallthinker"]
 
 
 def scope_metrics() -> dict:
@@ -102,6 +115,23 @@ def make_ouro_step(mesh):
                   (tokens, jnp.roll(tokens, -1, axis=1)))
 
 
+def make_sparse_step(mesh):
+    def loss_fn(p, batch):
+        tokens, targets = batch
+        feats = forward_features(p, tokens, SPARSE, attn="flash",
+                                 remat="full")
+        return chunked_cross_entropy(
+            feats, p["lm_head"].astype(SPARSE.dtype), targets, 128).mean()
+
+    opt = kfopt.synchronous_sgd(optax.adamw(1e-6))
+    step = build_train_step(loss_fn, opt, mesh, donate=False, accum_steps=2,
+                            compute_dtype=jnp.bfloat16)
+    params = replicate(init_params(jax.random.PRNGKey(0), SPARSE), mesh)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 256)
+    return step, (params, init_opt_state(opt, params, mesh),
+                  (tokens, jnp.roll(tokens, -1, axis=1)))
+
+
 def make_resnet_step(mesh):
     model = ResNet(stage_sizes=[1, 1], num_classes=10, num_filters=8)
 
@@ -124,7 +154,7 @@ def make_resnet_step(mesh):
 
 
 MAKERS = {"gpt": make_gpt_step, "resnet": make_resnet_step,
-          "ouro": make_ouro_step}
+          "ouro": make_ouro_step, "smallthinker": make_sparse_step}
 
 
 @pytest.fixture(scope="module")
@@ -143,7 +173,7 @@ def names(steps):
 # the flash kernels run only on the chip inside shard_map (the CPU takes the
 # jnp path there): their names are checked on the lowered kernels below
 @pytest.mark.parametrize("metric", GPT_METRICS + RESNET_METRICS
-                         + OURO_METRICS)
+                         + OURO_METRICS + SPARSE_METRICS)
 def test_the_step_has_what_each_metric_reads(names, metric):
     family = metric.rsplit(".", 1)[1]
     assert found(scope_metrics()[metric], names[family])
@@ -151,10 +181,11 @@ def test_the_step_has_what_each_metric_reads(names, metric):
 
 def test_every_scope_metric_of_the_manifest_is_covered_here():
     assert set(scope_metrics()) == set(GPT_METRICS + FLASH_METRICS
-                                       + RESNET_METRICS + OURO_METRICS)
+                                       + RESNET_METRICS + OURO_METRICS
+                                       + SPARSE_METRICS)
 
 
-@pytest.mark.parametrize("which", ["gpt", "resnet", "ouro"])
+@pytest.mark.parametrize("which", ["gpt", "resnet", "ouro", "smallthinker"])
 def test_the_builders_scopes(names, which):
     has = lambda rx: any(re.search(rx, n) for n in names[which])
     for scope in ("grads", "optimizer", "sync"):
