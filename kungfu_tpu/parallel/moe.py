@@ -25,10 +25,11 @@ no dropped token, gated experts (ReGLU), and a share of the experts held
 here: the layer is told which contiguous range of expert ids its weights
 are, routes over all of them, and computes the part of the result its own
 experts give.  The held assignments are sorted by expert into a row
-buffer and go through :func:`grouped_matmul`, whose work follows the rows
-held (a loop over the row blocks in use), not the buffer's static size.
-On one chip there is no exchange; what the experts held elsewhere would
-add is left out.
+buffer of which only the integer arrays are made; a pass is one loop over
+its blocks in use (:func:`expert_ffn`), so the work follows the rows held,
+not the buffer's static size.  :func:`grouped_matmul` is the same product
+alone, behind ``jax.lax.ragged_dot``'s signature.  On one chip there is no
+exchange; what the experts held elsewhere would add is left out.
 """
 from __future__ import annotations
 
@@ -241,13 +242,27 @@ def _visits(group_sizes, n_rows: int, block: int):
 def _zeros_like_of(like, shape, dtype):
     """Zeros that vary over the mesh axes ``like`` varies over: inside
     ``shard_map`` a loop's carry has to be of one type going in and coming
-    out (shard_map#scan-vma).  (An unwritten buffer, ``lax.empty``, would
-    spare the fill, 35 ms a step in the benchmark's cell; the chip's
-    compiler gives each one memory of its own for the whole program, 20.45
-    GB for that step: PERF.md section 6, PR 35.)"""
+    out (shard_map#scan-vma).  What is filled is what a pass sums into:
+    the tokens' result, and in the backward the tokens' and the weights'
+    cotangents.  (An unwritten buffer, ``lax.empty``, gets memory of its
+    own for the whole program from the chip's compiler: PERF.md section
+    6, PR 35.)"""
     z = jnp.zeros(shape, dtype)
     vma = tuple(getattr(jax.typeof(like), "vma", ()) or ())
     return lax.pcast(z, vma, to="varying") if vma else z
+
+
+def _dot(a, b, i: int, j: int):
+    """``a`` and ``b`` contracted over their axes ``i`` and ``j``, the
+    operands in their own dtype, the sum in float32."""
+    return lax.dot_general(a, b, (((i,), (j,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _add_to_group(dw, g, part):
+    """``dw[g] += part``."""
+    old = lax.dynamic_index_in_dim(dw, g, keepdims=False)
+    return lax.dynamic_update_index_in_dim(dw, old + part, g, 0)
 
 
 def _gmm(rows, weights, group_sizes, block: int, transposed: bool,
@@ -259,15 +274,12 @@ def _gmm(rows, weights, group_sizes, block: int, transposed: bool,
     M = rows.shape[0]
     N = weights.shape[1] if transposed else weights.shape[2]
     n_visits, group, blk, starts, ends = _visits(group_sizes, M, block)
-    contract = (((1,), (1 if transposed else 0,)), ((), ()))
 
     def visit(v, out):
         g, at = group[v], blk[v] * block
         x = lax.dynamic_slice_in_dim(rows, at, block)
         w = lax.dynamic_index_in_dim(weights, g, keepdims=False)
-        y = lax.dot_general(x, w, contract,
-                            preferred_element_type=jnp.float32
-                            ).astype(out.dtype)
+        y = _dot(x, w, 1, 1 if transposed else 0).astype(out.dtype)
         if not whole:
             r = at + jnp.arange(block, dtype=jnp.int32)
             mine = ((r >= starts[g]) & (r < ends[g]))[:, None]
@@ -292,10 +304,7 @@ def _gmm_dw(rows, dout, group_sizes, n_groups: int, block: int,
             r = at + jnp.arange(block, dtype=jnp.int32)
             x = jnp.where(((r >= starts[g]) & (r < ends[g]))[:, None], x, 0)
         d = lax.dynamic_slice_in_dim(dout, at, block)
-        part = lax.dot_general(x, d, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-        old = lax.dynamic_index_in_dim(dw, g, keepdims=False)
-        return lax.dynamic_update_index_in_dim(dw, old + part, g, 0)
+        return _add_to_group(dw, g, _dot(x, d, 0, 0))
 
     return lax.fori_loop(
         0, n_visits, visit,
@@ -354,169 +363,6 @@ def grouped_matmul(rows, weights, group_sizes, block_rows: int = 512,
     return _grouped_matmul(rows, weights, group_sizes, block, whole_blocks)
 
 
-def _blocks(n_rows, block: int):
-    """How many row blocks hold the first ``n_rows`` (a traced count)."""
-    return (n_rows + block - 1) // block
-
-
-@jax.custom_vjp
-def _pick(v, src, live, dest, here):
-    """``v[src]`` where ``live`` (nought elsewhere), for a map whose way
-    back is at hand (``dest``, taken where ``here``): its transpose is a
-    gather too, not a scatter."""
-    return jnp.where(live, v[src], 0)
-
-
-def _pick_fwd(v, src, live, dest, here):
-    return jnp.where(live, v[src], 0), (dest, here)
-
-
-def _pick_bwd(res, g):
-    dest, here = res
-    return jnp.where(here, g[dest], 0), None, None, None, None
-
-
-_pick.defvjp(_pick_fwd, _pick_bwd)
-
-
-def _take(x, tok, n_rows, block: int):
-    A = tok.shape[0]
-
-    def one(i, out):
-        idx = lax.dynamic_slice_in_dim(tok, i * block, block)
-        return lax.dynamic_update_slice_in_dim(out, x[idx], i * block, 0)
-
-    return lax.fori_loop(
-        0, _blocks(n_rows, block), one,
-        _zeros_like_of(x, (A, x.shape[1]), x.dtype))
-
-
-def _put(rows, scale, tok, n_rows, n_tokens: int, block: int):
-    def one(i, y):
-        at = i * block
-        idx = lax.dynamic_slice_in_dim(tok, at, block)
-        live = at + jnp.arange(block, dtype=jnp.int32) < n_rows
-        w = jnp.where(live, lax.dynamic_slice_in_dim(scale, at, block), 0.0)
-        part = lax.dynamic_slice_in_dim(rows, at, block).astype(jnp.float32)
-        # (told that a block's tokens rise and differ, which they do, the
-        # chip's scatter takes ten times as long: PERF.md section 6, PR 35)
-        return y.at[idx].add(part * w[:, None])
-
-    return lax.fori_loop(
-        0, _blocks(n_rows, block), one,
-        _zeros_like_of(rows, (n_tokens, rows.shape[1]), jnp.float32))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(x, tok, n_rows, block):
-    """The sorted row buffer: row r is token ``tok[r]``'s, ``x[tok[r]]``,
-    for the ``n_rows`` rows in use (whole blocks of them: a loop over the
-    blocks in use, as in :func:`grouped_matmul`), nought after.  ``x``
-    [n, D], ``tok`` [M] -> [M, D].  A padding row reads token 0's row;
-    nothing reads what it becomes.  Its transpose is :func:`put_rows`."""
-    return _take(x, tok, n_rows, block)
-
-
-def _take_rows_fwd(x, tok, n_rows, block):
-    return _take(x, tok, n_rows, block), (tok, n_rows, x.shape[0])
-
-
-def _take_rows_bwd(block, res, g):
-    tok, n_rows, n_tokens = res
-    ones = jnp.ones(tok.shape, jnp.float32)
-    return (_put(g, ones, tok, n_rows, n_tokens, block).astype(g.dtype),
-            None, None)
-
-
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def put_rows(rows, scale, tok, n_rows, n_tokens, block):
-    """Back to the tokens: ``y[t] = sum of scale[r] rows[r]`` over the rows
-    in use with ``tok[r] == t`` (a token has a row for each held expert it
-    was sent to).  ``rows`` [M, D], ``scale`` [M] f32 -> [n_tokens, D] f32,
-    again a loop over the blocks in use.  A padding row has a ``scale`` of
-    nought and adds it to token 0."""
-    return _put(rows, scale, tok, n_rows, n_tokens, block)
-
-
-def _put_rows_fwd(rows, scale, tok, n_rows, n_tokens, block):
-    return (_put(rows, scale, tok, n_rows, n_tokens, block),
-            (rows, scale, tok, n_rows))
-
-
-def _put_rows_bwd(n_tokens, block, res, dy):
-    rows, scale, tok, n_rows = res
-
-    def one(i, carry):
-        drows, dscale = carry
-        at = i * block
-        idx = lax.dynamic_slice_in_dim(tok, at, block)
-        live = at + jnp.arange(block, dtype=jnp.int32) < n_rows
-        w = jnp.where(live, lax.dynamic_slice_in_dim(scale, at, block), 0.0)
-        d = dy[idx]                                         # [block, D] f32
-        r = lax.dynamic_slice_in_dim(rows, at, block).astype(jnp.float32)
-        return (lax.dynamic_update_slice_in_dim(
-                    drows, (d * w[:, None]).astype(rows.dtype), at, 0),
-                lax.dynamic_update_slice_in_dim(
-                    dscale, jnp.where(live, jnp.sum(d * r, -1), 0.0), at, 0))
-
-    drows, dscale = lax.fori_loop(
-        0, _blocks(n_rows, block), one,
-        (_zeros_like_of(rows, rows.shape, rows.dtype),
-         _zeros_like_of(rows, scale.shape, jnp.float32)))
-    return drows, dscale, None, None
-
-
-put_rows.defvjp(_put_rows_fwd, _put_rows_bwd)
-
-
-def _reglu(h, n_rows, block: int):
-    F = h.shape[1] // 2
-
-    def one(i, out):
-        g = lax.dynamic_slice_in_dim(h, i * block, block)
-        return lax.dynamic_update_slice_in_dim(
-            out, jax.nn.relu(g[:, :F]) * g[:, F:], i * block, 0)
-
-    return lax.fori_loop(
-        0, _blocks(n_rows, block), one,
-        _zeros_like_of(h, (h.shape[0], F), h.dtype))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def reglu_rows(h, n_rows, block):
-    """``relu(gate) * up`` of the rows in use: ``h`` [M, 2F] holds gate
-    then up -> [M, F], nought past the blocks in use."""
-    return _reglu(h, n_rows, block)
-
-
-def _reglu_rows_fwd(h, n_rows, block):
-    return _reglu(h, n_rows, block), (h, n_rows)
-
-
-def _reglu_rows_bwd(block, res, da):
-    h, n_rows = res
-    F = h.shape[1] // 2
-
-    def one(i, dh):
-        g = lax.dynamic_slice_in_dim(h, i * block, block)
-        d = lax.dynamic_slice_in_dim(da, i * block, block)
-        gate, up = g[:, :F], g[:, F:]
-        part = jnp.concatenate(
-            [jnp.where(gate > 0, d * up, 0), d * jax.nn.relu(gate)], axis=1)
-        return lax.dynamic_update_slice_in_dim(dh, part.astype(h.dtype),
-                                               i * block, 0)
-
-    return (lax.fori_loop(
-        0, _blocks(n_rows, block), one,
-        _zeros_like_of(h, h.shape, h.dtype)), None)
-
-
-reglu_rows.defvjp(_reglu_rows_fwd, _reglu_rows_bwd)
-
-
 def held_assignments(ids, held: Tuple[int, int]):
     """How many of the assignments ``ids`` [n, k] go to each expert held
     here (``held`` = first id, count): [count] int32."""
@@ -564,6 +410,92 @@ def row_buffer(ids, n: int, held: Tuple[int, int], block: int):
     return sizes, ends[-1], tok, which, live, dest, here
 
 
+def _block_forward(x, scale, wi, wm, group, tok, i, block: int):
+    """Block ``i`` of the row buffer through its expert: ``(g, idx, s, rows,
+    w1, w2, gate, up, a, o)``, the expert, the block's tokens and scales,
+    their rows of ``x``, the expert's two weights, gate and up, ReGLU and
+    the output [block, D] in float32."""
+    g = group[i]
+    with jax.named_scope("moe_route"):
+        idx = lax.dynamic_slice_in_dim(tok, i * block, block)
+        s = lax.dynamic_slice_in_dim(scale, i * block, block)
+        rows = x[idx]
+    with jax.named_scope("gmm"):
+        w1 = lax.dynamic_index_in_dim(wi, g, keepdims=False).astype(x.dtype)
+        w2 = lax.dynamic_index_in_dim(wm, g, keepdims=False).astype(x.dtype)
+        h = _dot(rows, w1, 1, 0).astype(x.dtype)
+    with jax.named_scope("moe_act"):
+        gate, up = h[:, :w2.shape[0]], h[:, w2.shape[0]:]
+        a = jax.nn.relu(gate) * up
+    with jax.named_scope("gmm"):
+        o = _dot(a, w2, 1, 0)
+    return g, idx, s, rows, w1, w2, gate, up, a, o
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _expert_rows(x, weights, wi, wm, buffer, block):
+    """:func:`expert_ffn` given where the rows lie: ``buffer`` is the
+    blocks in use, each block's expert, and :func:`row_buffer`'s ``tok``,
+    ``which``, ``live``, ``dest``, ``here``."""
+    return _expert_rows_fwd(x, weights, wi, wm, buffer, block)[0]
+
+
+def _expert_rows_fwd(x, weights, wi, wm, buffer, block):
+    n_blocks, group, tok, which, live, dest, here = buffer
+    with jax.named_scope("moe_route"):
+        scale = jnp.where(live, weights.reshape(-1)[which], 0)
+
+    def visit(i, y):
+        _, idx, s, *_, o = _block_forward(x, scale, wi, wm, group, tok, i,
+                                          block)
+        # (told that a block's tokens rise and differ, which they do, the
+        # chip's scatter takes ten times as long: PERF.md section 6, PR 35)
+        with jax.named_scope("moe_route"):
+            return y.at[idx].add(o * s[:, None])
+
+    y = lax.fori_loop(0, n_blocks, visit,
+                      _zeros_like_of(x, x.shape, jnp.float32))
+    # kept: the inputs and a scale a row, nothing [M, .] of the buffer
+    return y.astype(x.dtype), (x, scale, wi, wm, n_blocks, group, tok, dest,
+                               here)
+
+
+def _expert_rows_bwd(block, res, dy):
+    x, scale, wi, wm, n_blocks, group, tok, dest, here = res
+
+    def visit(i, carry):
+        dx, dscale, dwi, dwm = carry
+        g, idx, s, rows, w1, w2, gate, up, a, o = _block_forward(
+            x, scale, wi, wm, group, tok, i, block)
+        with jax.named_scope("moe_route"):
+            d = dy[idx].astype(jnp.float32)
+            dscale = lax.dynamic_update_slice_in_dim(
+                dscale, jnp.sum(d * o, -1), i * block, 0)
+            do = (d * s[:, None]).astype(x.dtype)
+        with jax.named_scope("gmm"):
+            da = _dot(do, w2, 1, 1).astype(x.dtype)
+            dwm = _add_to_group(dwm, g, _dot(a, do, 0, 0))
+        with jax.named_scope("moe_act"):
+            dh = jnp.concatenate([jnp.where(gate > 0, da * up, 0),
+                                  da * jax.nn.relu(gate)], axis=1)
+        with jax.named_scope("gmm"):
+            drows = _dot(dh, w1, 1, 1)
+            dwi = _add_to_group(dwi, g, _dot(rows, dh, 0, 0))
+        with jax.named_scope("moe_route"):
+            return dx.at[idx].add(drows), dscale, dwi, dwm
+
+    dx, dscale, dwi, dwm = lax.fori_loop(0, n_blocks, visit, tuple(
+        _zeros_like_of(x, like.shape, jnp.float32)
+        for like in (x, scale, wi, wm)))
+    with jax.named_scope("moe_route"):
+        dweights = jnp.where(here, dscale[dest], 0).reshape(len(x), -1)
+    return (dx.astype(x.dtype), dweights, dwi.astype(wi.dtype),
+            dwm.astype(wm.dtype), None)
+
+
+_expert_rows.defvjp(_expert_rows_fwd, _expert_rows_bwd)
+
+
 def expert_ffn(x, ids, weights, wi, wm, held: Tuple[int, int],
                block_rows: int = 512):
     """The held experts' part of a routed feed-forward without dropped
@@ -575,29 +507,29 @@ def expert_ffn(x, ids, weights, wi, wm, held: Tuple[int, int],
     nothing.
 
     There is no capacity: the row buffer has room for every assignment
-    (n k rows) and a block of padding a held expert, and every pass over
-    it (the gather of the tokens' rows, the two grouped products, ReGLU
-    between them, the weighted sum back into the tokens) runs over the
-    blocks in use.  The held assignments lie sorted by expert, each
-    expert's group padded to a whole number of blocks, so a block holds
-    rows of one expert: the products multiply the held rows and less than
-    a block an expert more."""
-    n, D = x.shape
+    (n k rows) and a block of padding a held expert, but only its integer
+    arrays and the rows' scales are ever made.  The held assignments lie
+    sorted by expert, each expert's group padded to a whole number of
+    blocks, so a block holds rows of one expert, and a pass is ONE loop
+    over the blocks in use: a visit gathers the block's tokens' rows,
+    multiplies them by the expert's two weights with ReGLU between
+    (operands in ``x``'s dtype, sums in float32) and adds the scaled result
+    into the tokens in float32.  The backward (one ``custom_vjp``) keeps
+    the inputs and no row of the buffer: a visit makes the block's rows
+    and products again, then their cotangents.  So the work follows the
+    rows held and less than a block an expert more, and under a
+    checkpoint nothing of the forward loop has to run again."""
     G = wi.shape[0]
     if held[1] != G:
         raise ValueError(f"told to hold {held[1]} experts, given {G}")
     block = min(block_rows, ids.size)
     with jax.named_scope("moe_route"):
-        sizes, n_rows, tok, which, live, dest, here = row_buffer(
-            ids, n, held, block)
-        scale = _pick(weights.reshape(-1), which, live, dest, here)
-        rows = take_rows(x, tok, n_rows, block)                 # [M, D]
-    h = grouped_matmul(rows, wi.astype(x.dtype), sizes, block, True)
-    with jax.named_scope("moe_act"):
-        a = reglu_rows(h, n_rows, block)
-    o = grouped_matmul(a, wm.astype(x.dtype), sizes, block, True)
-    with jax.named_scope("moe_route"):
-        return put_rows(o, scale, tok, n_rows, n, block).astype(x.dtype)
+        sizes, _, tok, which, live, dest, here = row_buffer(
+            ids, len(x), held, block)
+        n_blocks, group = _visits(sizes, tok.shape[0], block)[:2]
+    return _expert_rows(x, weights, wi, wm,
+                        (n_blocks, group, tok, which, live, dest, here),
+                        block)
 
 
 def dropless_moe_ffn(params: dict, x, *, experts_per_token: int,

@@ -10,7 +10,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from kungfu_tpu.comm.mesh import PEER_AXIS, flat_mesh
 from kungfu_tpu.models import gpt
 from kungfu_tpu.parallel import moe
 
@@ -191,6 +193,102 @@ def test_the_shares_add_up_to_the_whole_layer():
                                rtol=1e-4, atol=1e-4)
 
 
+def _dense_sum(x, ids, weights, wi, wm, held):
+    """The benchmark's plain reference: each held expert over every token,
+    weighted by what the routing sends it (nought for most)."""
+    from perf.reference import gpt as plain, smallthinker as ref
+    sent = jnp.einsum("nk,nke->ne", weights, jax.nn.one_hot(ids, 8))
+    return ref.held_experts(plain._mm("none"),
+                            {"first": held[0], "G": held[1]},
+                            {"wi": wi, "wm": wm}, x, sent)
+
+
+def _routed(name):
+    """``(ids [n, 3], held, block_rows)`` of the eight experts' layer."""
+    n, k, E = 40, 3, 8
+    logits = jax.random.normal(jax.random.PRNGKey(7), (n, E))
+    top = lambda l: jax.lax.top_k(l, k)[1].astype(jnp.int32)
+    if name == "a held expert without a row":
+        return top(logits.at[:, 3].set(-1e9)), (2, 4), 16
+    if name == "a group of exactly one block":
+        ids = top(logits.at[:16, 5].set(1e9).at[16:, 5].set(-1e9))
+        assert int((ids == 5).sum()) == 16
+        return ids, (2, 4), 16
+    if name == "every assignment held elsewhere":
+        return top(logits.at[:, 6:].set(-1e9)), (6, 2), 16
+    if name == "fewer assignments than a block":
+        return top(logits), (0, 8), 512
+    return top(logits), name, 16
+
+
+def _under(how, layer, c):
+    """``layer(x, weights, wi, wm)`` and the gradient of ``sum(c * layer)``
+    by each of the four, as a train step takes them."""
+    if how == "checkpoint":
+        layer = jax.checkpoint(layer, policy=gpt._FULL_REMAT_KEEPS)
+
+    def both(*a):
+        return layer(*a), jax.grad(lambda *a: jnp.sum(c * layer(*a)),
+                                   (0, 1, 2, 3))(*a)
+    if how == "shard_map":
+        # as training.build_train_step: the arguments stacked over the
+        # mesh's one axis and unstacked inside, so every one varies over
+        # it, and the gradient taken inside
+        stack = lambda t: jax.tree_util.tree_map(lambda v: v[None], t)
+        sharded = jax.shard_map(
+            lambda *a: stack(both(*(t[0] for t in a))),
+            mesh=flat_mesh(n=1), in_specs=P(PEER_AXIS), out_specs=P(PEER_AXIS))
+        return lambda *a: jax.tree_util.tree_map(
+            lambda v: v[0], sharded(*stack(a)))
+    return both
+
+
+@pytest.mark.parametrize("how", ["plain", "checkpoint", "shard_map"])
+@pytest.mark.parametrize("case", [
+    (0, 8), (2, 4), (6, 2), "a held expert without a row",
+    "a group of exactly one block", "every assignment held elsewhere",
+    "fewer assignments than a block"], ids=str)
+def test_the_one_loop_and_its_backward_are_the_dense_sum(case, how):
+    """Value and the gradient of every input, at the edges the loop over
+    the blocks in use has."""
+    ids, held, block = _routed(case)
+    params, x = _layer_params()
+    k = jax.random.split(jax.random.PRNGKey(9), 2)
+    x = x.reshape(-1, x.shape[-1])[:ids.shape[0]]
+    weights = jax.random.uniform(k[0], ids.shape, minval=0.1)
+    wi, wm = (params[w][held[0]:held[0] + held[1]] for w in ("wi", "wm"))
+    c = jax.random.normal(k[1], x.shape)
+    got = jax.jit(_under(how, lambda *a: moe.expert_ffn(
+        a[0], ids, *a[1:], held, block), c))(x, weights, wi, wm)
+    want = _under("plain", lambda *a: _dense_sum(a[0], ids, *a[1:], held),
+                  c)(x, weights, wi, wm)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+    if case == "every assignment held elsewhere":
+        assert not any(np.asarray(g).any()
+                       for g in jax.tree_util.tree_leaves(got))
+
+
+def test_the_backward_keeps_the_inputs_and_no_row_of_the_buffer():
+    """The mechanism: what the layer saves for its backward is its inputs,
+    the buffer's integer arrays and one scale a row, so nothing [M, .] is
+    filled, written and read back, and a checkpoint's backward has no
+    forward loop to run again."""
+    ids, held, block = _routed((2, 4))
+    params, x = _layer_params()
+    x = x.reshape(-1, x.shape[-1])[:ids.shape[0]]
+    wi, wm = (params[w][2:6] for w in ("wi", "wm"))
+    M = (-(-ids.size // block) + held[1]) * block
+    # (what `jax.vjp` hands back is a pytree of what it kept)
+    kept = jax.tree_util.tree_leaves(jax.vjp(
+        lambda *a: moe.expert_ffn(a[0], ids, *a[1:], held, block),
+        x, jnp.ones(ids.shape), wi, wm)[1])
+    floats = [a.shape for a in kept if jnp.issubdtype(a.dtype, jnp.floating)]
+    assert sorted(floats) == sorted([x.shape, wi.shape, wm.shape, (M,)])
+    assert M not in (x.shape[0], wi.shape[0], wm.shape[0])
+
+
 def test_a_router_placed_before_attention_reads_another_tensor():
     params, x = _layer_params()
     other = x[::-1]
@@ -296,13 +394,20 @@ def test_the_scopes_stand_in_the_lowered_step():
     step = jax.jit(jax.grad(lambda p: jnp.mean(gpt.forward_features(
         p, tokens, CFG, attn="dense", remat="full") ** 2)))
     text = step.lower(params).as_text(debug_info=True)
-    # the router stands beside attention's projections, under no `attn`
-    # (the forward, the forward again under full remat, and the backward
-    # that the grouped product writes out itself)
+    # the router stands beside attention's projections, under no `attn`;
+    # the experts' scopes stand inside the one loop a pass, the forward's
+    # and the backward's that the layer writes out itself
     for scope in ("jvp(ffn)/moe/moe_route", "/moe/moe_route/top_k",
-                  "jvp(ffn)/moe/gmm", "rematted_computation/ffn/moe/gmm",
-                  "checkpoint/ffn/moe/gmm"):
+                  "rematted_computation/ffn/moe/moe_route",
+                  "jvp(ffn)/moe/while/body/gmm",
+                  "jvp(ffn)/moe/while/body/moe_act",
+                  "jvp(ffn)/moe/while/body/moe_route/scatter-add",
+                  "checkpoint/ffn/moe/while/body/gmm",
+                  "checkpoint/ffn/moe/while/body/moe_act",
+                  "checkpoint/ffn/moe/while/body/moe_route/scatter-add"):
         assert scope in text, scope
+    # full remat routes again and has no forward loop to run again
+    assert "rematted_computation/ffn/moe/while" not in text
 
 
 def test_what_walks_one_kind_of_layer_refuses_this_model_by_name():
