@@ -47,6 +47,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.row_scatter import carry_shape, rows_of, scatter_add_rows
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -467,33 +469,33 @@ def _block_forward(x, scale, wi, wm, group, tok, i, block: int, act: str):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _expert_rows(x, weights, wi, wm, buffer, block, act):
     """:func:`expert_ffn` given where the rows lie: ``buffer`` is the
-    blocks in use, each block's expert, and :func:`row_buffer`'s ``tok``,
-    ``which``, ``live``, ``dest``, ``here``."""
+    blocks in use, each block's expert, :func:`row_buffer`'s ``tok``, each
+    block's live rows (a prefix of it), and its ``which``, ``live``,
+    ``dest``, ``here``."""
     return _expert_rows_fwd(x, weights, wi, wm, buffer, block, act)[0]
 
 
 def _expert_rows_fwd(x, weights, wi, wm, buffer, block, act):
-    n_blocks, group, tok, which, live, dest, here = buffer
+    n_blocks, group, tok, n_live, which, live, dest, here = buffer
     with jax.named_scope("moe_route"):
         scale = jnp.where(live, weights.reshape(-1)[which], 0)
 
     def visit(i, y):
         _, idx, s, *_, o = _block_forward(x, scale, wi, wm, group, tok, i,
                                           block, act)
-        # (told that a block's tokens rise and differ, which they do, the
-        # chip's scatter takes ten times as long: PERF.md section 6, PR 35)
         with jax.named_scope("moe_route"):
-            return y.at[idx].add(o * s[:, None])
+            return scatter_add_rows(y, idx, n_live[i], o * s[:, None])
 
-    y = lax.fori_loop(0, n_blocks, visit,
-                      _zeros_like_of(x, x.shape, jnp.float32))
-    # kept: the inputs and a scale a row, nothing [M, .] of the buffer
-    return y.astype(x.dtype), (x, scale, wi, wm, n_blocks, group, tok, dest,
-                               here)
+    y = lax.fori_loop(0, n_blocks, visit, _zeros_like_of(
+        x, carry_shape(*x.shape), jnp.float32))
+    # kept: the inputs, a scale a row and a live count a block, nothing
+    # [M, .] of the buffer
+    return rows_of(y, x.shape[1], x.dtype), (
+        x, scale, wi, wm, n_blocks, group, tok, n_live, dest, here)
 
 
 def _expert_rows_bwd(block, act, res, dy):
-    x, scale, wi, wm, n_blocks, group, tok, dest, here = res
+    x, scale, wi, wm, n_blocks, group, tok, n_live, dest, here = res
 
     def visit(i, carry):
         dx, dscale, dwi, dwm = carry
@@ -513,14 +515,16 @@ def _expert_rows_bwd(block, act, res, dy):
             drows = _dot(dh, w1, 1, 1)
             dwi = _add_to_group(dwi, g, _dot(rows, dh, 0, 0))
         with jax.named_scope("moe_route"):
-            return dx.at[idx].add(drows), dscale, dwi, dwm
+            return (scatter_add_rows(dx, idx, n_live[i], drows), dscale,
+                    dwi, dwm)
 
     dx, dscale, dwi, dwm = lax.fori_loop(0, n_blocks, visit, tuple(
-        _zeros_like_of(x, like.shape, jnp.float32)
-        for like in (x, scale, wi, wm)))
+        _zeros_like_of(x, shape, jnp.float32) for shape in (
+            carry_shape(*x.shape), scale.shape, wi.shape, wm.shape)))
     with jax.named_scope("moe_route"):
         dweights = jnp.where(here, dscale[dest], 0).reshape(len(x), -1)
-    return (dx.astype(x.dtype), dweights, dwi.astype(wi.dtype),
+    return (rows_of(dx, x.shape[1], x.dtype), dweights,
+            dwi.astype(wi.dtype),
             dwm.astype(wm.dtype), None)
 
 
@@ -558,9 +562,12 @@ def expert_ffn(x, ids, weights, wi, wm, held: Tuple[int, int],
     over the blocks in use: a visit gathers the block's tokens' rows,
     multiplies them by the expert's two weights with the gate between
     (operands in ``x``'s dtype, sums in float32) and adds the scaled result
-    into the tokens in float32.  The backward (one ``custom_vjp``) keeps
-    the inputs and no row of the buffer: a visit makes the block's rows
-    and products again, then their cotangents.  So the work follows the
+    into the tokens in float32, the block's live rows alone, by
+    ``ops.row_scatter.scatter_add_rows`` (a block's live rows are a prefix
+    of it and their tokens differ: a token has one row an expert).  The
+    backward (one ``custom_vjp``) keeps the inputs, each block's count of
+    live rows and no row of the buffer: a visit makes the block's rows and
+    products again, then their cotangents.  So the work follows the
     rows held and less than a block an expert more, and under a
     checkpoint nothing of the forward loop has to run again."""
     G = wi.shape[0]
@@ -571,9 +578,10 @@ def expert_ffn(x, ids, weights, wi, wm, held: Tuple[int, int],
         sizes, _, tok, which, live, dest, here = row_buffer(
             ids, len(x), held, block)
         n_blocks, group = _visits(sizes, tok.shape[0], block)[:2]
+        n_live = jnp.sum(live.reshape(-1, block), axis=1, dtype=jnp.int32)
     return _expert_rows(x, weights, wi, wm,
-                        (n_blocks, group, tok, which, live, dest, here),
-                        block, act)
+                        (n_blocks, group, tok, n_live, which, live, dest,
+                         here), block, act)
 
 
 def dropless_moe_ffn(params: dict, x, *, experts_per_token: int,

@@ -400,16 +400,18 @@ def test_the_scopes_stand_in_the_lowered_step():
     text = step.lower(params).as_text(debug_info=True)
     # the router stands beside attention's projections, under no `attn`;
     # the experts' scopes stand inside the one loop a pass, the forward's
-    # and the backward's that the layer writes out itself
+    # and the backward's that the layer writes out itself, and each pass
+    # adds into the tokens with the kernel, not with XLA's scatter
     for scope in ("jvp(ffn)/moe/moe_route", "/moe/moe_route/top_k",
                   "rematted_computation/ffn/moe/moe_route",
                   "jvp(ffn)/moe/while/body/gmm",
                   "jvp(ffn)/moe/while/body/moe_act",
-                  "jvp(ffn)/moe/while/body/moe_route/scatter-add",
+                  "jvp(ffn)/moe/while/body/moe_route/moe_scatter_add",
                   "checkpoint/ffn/moe/while/body/gmm",
                   "checkpoint/ffn/moe/while/body/moe_act",
-                  "checkpoint/ffn/moe/while/body/moe_route/scatter-add"):
+                  "checkpoint/ffn/moe/while/body/moe_route/moe_scatter_add"):
         assert scope in text, scope
+    assert "while/body/moe_route/scatter-add" not in text
     # full remat routes again and has no forward loop to run again
     assert "rematted_computation/ffn/moe/while" not in text
 
