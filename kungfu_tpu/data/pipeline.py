@@ -24,17 +24,31 @@ Two pieces:
 
 Works with :class:`kungfu_tpu.elastic.dataset.ElasticDataShard` — the
 shard decides WHICH samples; this pipeline hides WHEN they move.
+
+While a :class:`~kungfu_tpu.utils.compile_cache.CompileCounter` is
+current, each batch leaves two records there, joined by one sequence
+number: ``feed.stage`` (the worker's source ``next`` and placement) and
+``feed.handout`` (the consumer's wait inside ``next()``, ending at the
+hand-out, with the queue's depth it found).  They live in the counter,
+so they outlast the prefetcher.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
+import time
 from typing import Callable, Iterator, Optional
 
 import jax
 import numpy as np
 
+from ..utils.compile_cache import CompileCounter, current_counter
+
 _SENTINEL = object()
+# one numbering for every prefetcher of the process, so a sequence number
+# names one batch among all of a counter's records
+_SEQ = itertools.count()
 
 
 class Prefetcher:
@@ -62,10 +76,21 @@ class Prefetcher:
 
     def _run(self):
         try:
-            for batch in self._src:
+            source = iter(self._src)
+            while True:
+                began = time.perf_counter_ns()
+                try:
+                    batch = next(source)
+                except StopIteration:
+                    break
                 if self._stop.is_set():
                     return
-                staged = jax.tree_util.tree_map(self._place, batch)
+                seq = next(_SEQ)
+                staged = (seq, jax.tree_util.tree_map(self._place, batch))
+                counter = current_counter()
+                if counter is not None:
+                    counter.add(CompileCounter.STAGE, "", began,
+                                time.perf_counter_ns(), seq)
                 while not self._stop.is_set():
                     try:
                         self._q.put(staged, timeout=0.1)
@@ -93,13 +118,19 @@ class Prefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
+        began, depth = time.perf_counter_ns(), self._q.qsize()
         item = self._q.get()
         if item is _SENTINEL:
             self._done = True
             if self._err is not None:
                 raise self._err
             raise StopIteration
-        return item
+        seq, batch = item
+        counter = current_counter()
+        if counter is not None:
+            counter.add(CompileCounter.HANDOUT, "", began,
+                        time.perf_counter_ns(), seq, depth)
+        return batch
 
     def close(self):
         """Stop the worker (used on early exit; idempotent)."""

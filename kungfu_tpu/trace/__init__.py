@@ -106,8 +106,13 @@ class Recorder:
                rank: Optional[int] = None, step: Optional[int] = None,
                version: Optional[int] = None,
                ts: Optional[float] = None, dur: Optional[float] = None,
-               attrs: Optional[dict] = None) -> dict:
-        """Append one structured event (and stream it to the sink)."""
+               attrs: Optional[dict] = None,
+               wait: bool = True) -> Optional[dict]:
+        """Append one structured event (and stream it to the sink).
+
+        ``wait=False`` returns None, recording nothing, where another
+        frame holds the recorder's lock: for a caller that may interrupt
+        this very method in its own thread (a ``gc.callbacks`` entry)."""
         ev: Dict = {"ts": time.perf_counter() if ts is None else ts,
                     "name": name, "cat": category,
                     "pid": self.pid,
@@ -120,7 +125,9 @@ class Recorder:
             ev["dur"] = dur
         if attrs:
             ev["attrs"] = attrs
-        with self._lock:
+        if not self._lock.acquire(wait):
+            return None
+        try:
             self._ring.append(ev)
             if self._sink is not None:
                 # flush (not fsync) per line: the bytes reach the OS, so
@@ -129,6 +136,8 @@ class Recorder:
                 # correctness checks, not timelines) is the fsync'd tier
                 self._sink.write(json.dumps(ev) + "\n")
                 self._sink.flush()
+        finally:
+            self._lock.release()
         return ev
 
     def tail(self, n: Optional[int] = None) -> List[dict]:

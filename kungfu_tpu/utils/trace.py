@@ -111,13 +111,6 @@ def reset() -> None:
         _events.clear()
 
 
-def report() -> str:
-    lines = [f"{name}: {c} calls, {tot * 1e3:.2f} ms total, "
-             f"{tot / c * 1e3:.3f} ms/call"
-             for name, (c, tot) in sorted(scope_stats().items())]
-    return "\n".join(lines)
-
-
 # one device capture at a time: jax.profiler raises RuntimeError on a
 # second start_trace, and a failed start used to leak that exception to
 # whoever asked for a profile (the /profile endpoint must answer "busy",

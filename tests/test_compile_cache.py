@@ -5,12 +5,15 @@ without it an accelerator gets ``<checkout>/.jax_cache`` — a path made
 from the package's location, the same in every process — and the CPU
 gets no cache at all.
 """
+import gc
 import json
 import os
 import subprocess
 import sys
+import threading
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from kungfu_tpu.utils import compile_cache as cc
@@ -97,8 +100,8 @@ print(json.dumps({"path": path, "config": jax.config.jax_compilation_cache_dir,
 """
 
 
-def _child(env):
-    r = subprocess.run([sys.executable, "-c", _CHILD], env=env, cwd=REPO,
+def _child(env, script=_CHILD):
+    r = subprocess.run([sys.executable, "-c", script], env=env, cwd=REPO,
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr[-2000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
@@ -218,3 +221,167 @@ def test_no_knob_and_no_home_directory_default():
     assert not [k for k in knobs.KNOBS if "COMPILE_CACHE" in k]
     src = open(cc.__file__).read()
     assert "expanduser" not in src and "knobs" not in src
+
+
+@pytest.fixture
+def no_automatic_gc():
+    """Collections only where the test calls `gc.collect()`."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+def _collections(counter, gen="gen2"):
+    return [r for r in counter.host if r.kind == counter.GC and r.name == gen]
+
+
+YOUNG = {"generation": 0, "collected": 0, "uncollectable": 0}
+OLDER = dict(YOUNG, generation=1, collected=5)
+
+
+def test_a_collection_leaves_one_record(no_automatic_gc):
+    counter = CompileCounter()
+    before = len(_collections(counter))
+    cycles = [[] for _ in range(1000)]
+    for a, b in zip(cycles, cycles[1:]):
+        a.append(b)
+        b.append(a)
+    del cycles, a, b
+    gc.collect()
+    after = _collections(counter)
+    assert len(after) == before + 1
+    assert after[-1].start_ns <= after[-1].end_ns and after[-1].value >= 1000
+
+
+def test_a_newer_counter_takes_the_collections_over(no_automatic_gc):
+    first, second = CompileCounter(), CompileCounter()
+    assert gc.callbacks.count(cc._on_gc) == 1
+    had = len(_collections(first)), len(_collections(second))
+    gc.collect()
+    assert (len(_collections(first)), len(_collections(second))) == (
+        had[0], had[1] + 1)
+
+
+def test_a_quick_young_collection_allocates_and_records_nothing(
+        no_automatic_gc):
+    counter = CompileCounter()
+    kept = len(counter.host)
+    allocated = gc.get_count()[0]
+    cc._on_gc("start", YOUNG)
+    cc._on_gc("stop", YOUNG)
+    assert gc.get_count()[0] == allocated and len(counter.host) == kept
+    cc._on_gc("start", OLDER)
+    cc._on_gc("stop", OLDER)
+    assert len(counter.host) == kept + 1
+    assert counter.host[-1][:2] == (counter.GC, "gen1")
+    assert counter.host[-1].value == 5
+
+
+def test_jaxs_records_name_the_function():
+    counter = CompileCounter()
+
+    def halve_and_tanh(x):
+        return jnp.tanh(x / 2)
+
+    jax.jit(halve_and_tanh)(jnp.ones((3,))).block_until_ready()
+    named = {(r.kind, r.name) for r in counter.host}
+    assert {("jax.trace", "halve_and_tanh"),
+            ("jax.lower", "jit(halve_and_tanh)"),
+            ("jax.request", "jit(halve_and_tanh)")} <= named
+    # the same intervals as the stage records, on the same clock
+    assert [r.end_ns for r in counter.host if r.kind.startswith("jax.")] == [
+        at for _, _, at in counter.records]
+    assert counter.requests()[-1][::3] == ("jit(halve_and_tanh)", False)
+
+
+_CHILD_NAMED = r"""
+import json
+import jax, jax.numpy as jnp
+from kungfu_tpu.utils.compile_cache import (CompileCounter,
+                                            enable_compile_cache)
+enable_compile_cache()
+counter = CompileCounter()
+def scaled_tanh(x):
+    return jnp.tanh(x) * 3
+jax.jit(scaled_tanh)(jnp.ones((8, 8))).block_until_ready()
+print(json.dumps([[name, hit] for name, _, _, hit in counter.requests()]))
+"""
+
+
+def test_a_program_from_the_cache_is_told_from_one_compiled(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    cold, warm = _child(env, _CHILD_NAMED), _child(env, _CHILD_NAMED)
+    assert ["jit(scaled_tanh)", False] in cold
+    assert not any(hit for _, hit in cold)
+    assert ["jit(scaled_tanh)", True] in warm
+
+
+def test_records_are_mirrored_into_kftrace_while_armed(no_automatic_gc):
+    from kungfu_tpu import trace as kftrace
+    rec = kftrace.arm(capacity=1000)
+    try:
+        counter = CompileCounter()
+        counter.add(counter.HANDOUT, "", 1_000, 3_000, 7, 2)
+        gc.collect()
+        # a collection inside kftrace's own frames must not wait for its
+        # lock: its record waits for the next one instead
+        with rec._lock:
+            cc._on_gc("start", OLDER)
+            cc._on_gc("stop", OLDER)
+        assert [e["name"] for e in rec.tail(2)] == ["feed.handout",
+                                                    "gc gen2"]
+        counter.add(counter.STAGE, "", 4_000, 9_000, 8)
+        tail = rec.tail(4)
+    finally:
+        kftrace.disarm()
+    assert [(e["name"], e["cat"]) for e in tail] == [
+        ("feed.handout", "host.feed"), ("gc gen2", "host.gc"),
+        ("gc gen1", "host.gc"), ("feed.stage", "host.feed")]
+    assert tail[0]["ts"] == pytest.approx(1e-6)
+    assert tail[0]["dur"] == pytest.approx(2e-6)
+    assert tail[0]["attrs"] == {"seq": 7, "depth": 2}
+    assert tail[2]["attrs"] == {"collected": 5}
+
+
+def test_records_from_many_threads_are_all_kept(no_automatic_gc):
+    """Threads adding records and setting off collections at once: no
+    record is lost and no collection's start is taken for another's."""
+    counter = CompileCounter()
+    n_threads, each = 2 * (os.cpu_count() or 4), 10
+    before = len(_collections(counter))
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+
+    def work(t):
+        for i in range(each):
+            counter.add(counter.STAGE, "", i, i + 1, t * each + i)
+            gc.collect()
+
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+        assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(switch)
+    staged = sorted(r.seq for r in counter.host if r.kind == counter.STAGE)
+    assert staged == list(range(n_threads * each))
+    pauses = _collections(counter)[before:]
+    assert 0 < len(pauses) <= n_threads * each
+    for a, b in zip(pauses, pauses[1:]):
+        assert a.start_ns <= a.end_ns <= b.start_ns
+
+
+def test_records_are_laid_on_a_profilers_clock(no_automatic_gc):
+    counter = CompileCounter()
+    counter.add(counter.STAGE, "", 4_000, 4_500, 3)
+    counter.add(counter.GC, "gen2", 5_000, 7_000)
+    assert counter.on_profile_clock(offset_ns=100, profile_start_ns=1_000,
+                                    since_ns=4_500) == [("gc gen2", 4_100,
+                                                         2_000)]

@@ -148,3 +148,74 @@ def test_prefetch_feeds_train_step(devices):
             losses.append(float(np.asarray(loss)[0]))
     assert len(losses) == 5
     assert losses[-1] < losses[0]
+
+
+@pytest.fixture
+def counter():
+    """A fresh current counter, so the feed's records land in it."""
+    from kungfu_tpu.utils.compile_cache import CompileCounter
+    return CompileCounter()
+
+
+def _feed_records(counter):
+    from kungfu_tpu.utils.compile_cache import CompileCounter
+    stage = {r.seq: r for r in counter.host if r.kind == CompileCounter.STAGE}
+    handout = [r for r in counter.host if r.kind == CompileCounter.HANDOUT]
+    return stage, handout
+
+
+@pytest.mark.parametrize("slow", ["source", "consumer"])
+def test_each_batch_is_staged_and_handed_out_under_one_number(counter, slow):
+    """A slow source shows as the feed's staging and the consumer's wait;
+    a slow consumer as staging that is quick and a queue found full. The
+    sequence number joins each batch's two records."""
+    n, pause, depth = 5, 0.05, 2
+
+    def source():
+        for i in range(n):
+            if slow == "source":
+                time.sleep(pause)
+            yield np.full((2,), i)
+
+    with Prefetcher(source(), depth=depth) as pf:
+        for _ in pf:
+            if slow == "consumer":
+                time.sleep(pause)
+    stage, handout = _feed_records(counter)
+    assert len(handout) == n and set(stage) == {r.seq for r in handout}
+    assert [r.seq for r in handout] == sorted(r.seq for r in handout)
+    for r in handout:
+        assert stage[r.seq].end_ns <= r.end_ns      # staged before handed
+        assert r.start_ns <= r.end_ns and 0 <= r.value <= depth
+    waits = [(r.end_ns - r.start_ns) / 1e9 for r in handout]
+    staged = [(r.end_ns - r.start_ns) / 1e9 for r in stage.values()]
+    if slow == "source":
+        assert min(staged) >= 0.9 * pause
+        assert min(waits[1:]) >= 0.5 * pause        # waited for each batch
+    else:
+        assert max(staged) < pause and max(waits[1:]) < pause
+        assert handout[2].value == depth            # the queue was full
+
+
+def test_the_feeds_records_are_bounded_and_outlive_the_prefetcher(
+        monkeypatch):
+    from kungfu_tpu.utils.compile_cache import CompileCounter
+    monkeypatch.setattr(CompileCounter, "MAX_RECORDS", 4)
+    counter = CompileCounter()
+    pf = Prefetcher(iter([np.zeros(2)] * 6), depth=2)
+    assert len(list(pf)) == 6
+    pf.close()
+    del pf
+    # the newest four, the last batch's hand-out last
+    assert len(counter.host) == 4
+    assert counter.host[-1].kind == CompileCounter.HANDOUT
+    assert counter.host[-1].seq == max(r.seq for r in counter.host)
+
+
+def test_no_counter_no_records(monkeypatch):
+    from kungfu_tpu.utils import compile_cache
+    counter = compile_cache.CompileCounter()
+    monkeypatch.setattr(compile_cache, "_current", None)
+    with Prefetcher(iter([np.zeros(2)] * 3), depth=2) as pf:
+        assert len(list(pf)) == 3
+    assert _feed_records(counter) == ({}, [])

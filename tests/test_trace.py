@@ -30,7 +30,7 @@ def test_scopes_record_when_enabled():
     stats = trace.scope_stats()
     assert stats["work"][0] == 3
     assert stats["work"][1] >= 0
-    assert "work: 3 calls" in trace.report()
+    assert sorted(trace.scope_stats()) == ["work"]
 
 
 def test_session_collectives_traced(devices):
