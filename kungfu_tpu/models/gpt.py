@@ -523,11 +523,14 @@ def _short_conv(layer, x, cfg: GPTConfig):
     each channel its own taps, nought before the first position;
     ``(c z) W_out``.  The norm and the two projections stand under the
     scope ``conv_proj``, the gates and the taps (three shifted
-    multiply-adds the compiler fuses, in float32) under ``conv``."""
+    multiply-adds the compiler fuses, in float32) under ``conv``.  The two
+    products' outputs are named ``conv_in`` and ``conv_out``, for
+    ``remat="full"`` to keep (:data:`_FULL_REMAT_KEEPS`)."""
     T = x.shape[1]
     with jax.named_scope("conv_proj"):
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
-        bcv = jnp.einsum("btd,de->bte", h, layer["w_in"].astype(cfg.dtype))
+        bcv = checkpoint_name(jnp.einsum(
+            "btd,de->bte", h, layer["w_in"].astype(cfg.dtype)), "conv_in")
     with jax.named_scope("conv"):
         b, c, v = (t.astype(jnp.float32) for t in jnp.split(bcv, 3, -1))
         u, taps = b * v, layer["conv"].astype(jnp.float32)
@@ -536,7 +539,8 @@ def _short_conv(layer, x, cfg: GPTConfig):
             z = z + taps[j] * jnp.pad(u, ((0, 0), (j, 0), (0, 0)))[:, :T]
         y = (c * z).astype(cfg.dtype)
     with jax.named_scope("conv_proj"):
-        return jnp.einsum("btd,de->bte", y, layer["w_out"].astype(cfg.dtype))
+        return checkpoint_name(jnp.einsum(
+            "btd,de->bte", y, layer["w_out"].astype(cfg.dtype)), "conv_out")
 
 
 def _attend(q, kk, v, attn: str, sp_axis: Optional[str],
@@ -665,9 +669,10 @@ def _local_attn(attn: str, T: int, sp_axis: Optional[str] = None) -> str:
 
 
 # what remat="full" keeps of a layer beside its input: the flash kernel's
-# output and lse (ops/flash_attention._fa_fwd) and wm's output (_dense_ffn)
+# output and lse (ops/flash_attention._fa_fwd), wm's output (_dense_ffn) and
+# a conv layer's two products (_short_conv)
 _FULL_REMAT_KEEPS = jax.checkpoint_policies.save_only_these_names(
-    "ffn_proj", "flash_out", "flash_lse")
+    "ffn_proj", "flash_out", "flash_lse", "conv_in", "conv_out")
 
 
 def layer_stack(params, tokens, cfg: GPTConfig, *,
@@ -696,12 +701,16 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
     where ``attn`` is the local flash kernel (the backward then runs no
     second ``flash_fwd``; dense, ring and Ulysses attention keep nothing
     new), ``wm``'s output only where the backward reads it
-    (``cfg.out_norms``).  That is at most two ``[B, T, D]`` a layer visit
-    beside the block's input, each ``B*T / (72*D)`` of the bytes of the
-    layer's f32 weights with their Adam state (1.4% at D = 4096 and 4,096
-    tokens a microbatch); most where weights are shared across rounds
-    (``models/looped.py``).  A capacity knob for models that do not fit
-    otherwise: a step pays for it with the layers' forward a second time.
+    (``cfg.out_norms``).  That is at most two ``[B, T, D]`` an attention
+    layer visit beside the block's input, each ``B*T / (72*D)`` of the
+    bytes of the layer's f32 weights with their Adam state (1.4% at D =
+    4096 and 4,096 tokens a microbatch); most where weights are shared
+    across rounds (``models/looped.py``).  A ``conv`` layer keeps the
+    outputs of its two products, ``[B, T, 3D]`` and ``[B, T, D]``
+    (:func:`_short_conv`), so its backward makes again the norm, the gates
+    and the taps, and neither product.  A capacity knob for models that do
+    not fit otherwise: a step pays for it with the layers' forward a second
+    time.
     ``"ffn"`` and ``"attn"``: see :func:`apply_layer`."""
     T = tokens.shape[1]
     attn = _local_attn(attn, T, sp_axis)
