@@ -118,12 +118,21 @@ class GPTConfig:
     router: str = "softmax"
     router_reads: str = "ln1"
     # what mixes a layer's tokens: "attn", or "conv", a gated short
-    # convolution over ``CONV_TAPS`` positions (:func:`_short_conv`);
-    # one value for every layer, or a tuple as long as the layers
+    # convolution over ``CONV_TAPS`` positions (:func:`_short_conv`), or
+    # one of models/sambay.py's: "mamba" (a selective scan), "diff"
+    # (differential attention), "gmu" (a gated memory unit), "cross"
+    # (differential attention to a shared KV); one value for every layer,
+    # or a tuple as long as the layers
     operator: Any = "attn"
+    # every norm of the blocks and the final one: "rms", or "layer",
+    # LayerNorm with a bias (leaves ``ln1_b``, ``ln2_b``, ``lnf_b``)
+    norm: str = "rms"
     # an RMS norm over the head dimension on q and on k, before RoPE
     # (leaves ``q_norm``, ``k_norm``)
     qk_norm: bool = False
+    # the published depth of layer 0, where the layers are a stage of a
+    # deeper stack (differential attention's lam_init follows it)
+    first_layer: int = 0
 
     def __post_init__(self):
         if self.d_head is None and self.d_model % self.n_heads != 0:
@@ -135,10 +144,16 @@ class GPTConfig:
                     len(value) != self.n_layers):
                 raise ValueError(f"{name} has {len(value)} entries for "
                                  f"{self.n_layers} layers")
+        from . import sambay
         ops = {self.layer_operator(i) for i in range(self.n_layers)}
-        if not ops <= {"attn", "conv"}:
-            raise ValueError(f"operator must be 'attn' or 'conv', got "
-                             f"{sorted(ops)}")
+        if not ops <= {"attn", "conv", *sambay.OPERATORS}:
+            raise ValueError(f"operator must be 'attn', 'conv', 'mamba', "
+                             f"'diff', 'gmu' or 'cross', got {sorted(ops)}")
+        if ops & set(sambay.OPERATORS):
+            sambay.check(self)
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm must be 'rms' or 'layer', got "
+                             f"{self.norm!r}")
         if self.n_experts:
             first, count = self.held
             if not (0 < self.experts_per_token <= self.n_experts
@@ -218,8 +233,13 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
     D, H, Dh, F, V = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
                       cfg.vocab_size)
     Hkv = cfg.kv_heads
-    k = iter(jax.random.split(
-        rng, 4 + (7 if cfg.n_experts else 6) * cfg.n_layers))
+    from . import sambay
+    k = iter(jax.random.split(rng, 4 + sum(
+        14 if cfg.layer_operator(i) in sambay.OPERATORS
+        else 7 if cfg.n_experts else 6 for i in range(cfg.n_layers))))
+    biases = ({"ln1_b": jnp.zeros((D,), jnp.float32),
+               "ln2_b": jnp.zeros((D,), jnp.float32)}
+              if cfg.norm == "layer" else {})
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
@@ -232,8 +252,10 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
     G, Fe = cfg.held[1], cfg.d_expert
     for i in range(cfg.n_layers):
         layer = {**out_norms, "ln1": jnp.ones((D,), jnp.float32),
-                 "ln2": jnp.ones((D,), jnp.float32)}
-        if cfg.layer_operator(i) == "conv":
+                 "ln2": jnp.ones((D,), jnp.float32), **biases}
+        if cfg.layer_operator(i) in sambay.OPERATORS:
+            layer.update(sambay.init_layer(k, cfg, i))
+        elif cfg.layer_operator(i) == "conv":
             # [b, c, v] in one product, the taps a channel, the output
             layer.update(w_in=dense(next(k), (D, 3 * D), D),
                          conv=dense(next(k), (CONV_TAPS, D), CONV_TAPS),
@@ -264,6 +286,8 @@ def init_params(rng: jax.Array, cfg: GPTConfig) -> Dict:
     }
     if not cfg.rope:
         out["wpe"] = dense(next(k), (cfg.max_seq, D), D) * 0.1
+    if cfg.norm == "layer":
+        out["lnf_b"] = jnp.zeros((D,), jnp.float32)
     return out
 
 
@@ -347,6 +371,25 @@ def _rms_norm(x, scale, eps):
     return (xf * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def layer_norm(x, scale, bias, eps=1e-5):
+    """LayerNorm with a bias, in f32, made again in the backward as
+    :func:`rms_norm` is."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
+
+
+def _norm(x, weights, name: str, cfg: GPTConfig):
+    """``cfg.norm`` with the weights ``weights[name]``: RMS, or LayerNorm
+    with the bias ``weights[name + "_b"]``."""
+    if cfg.norm == "layer":
+        return layer_norm(x, weights[name], weights[name + "_b"],
+                          cfg.norm_eps)
+    return rms_norm(x, weights[name], cfg.norm_eps)
+
+
 def _rope_rotate(t, pos, cfg: GPTConfig):
     """Rotary position embedding on [B, T, heads, Dh] with GLOBAL
     positions ``pos`` — [T] (shared across the batch; under sequence
@@ -386,7 +429,7 @@ def _layer_qkv(layer, x, cfg: GPTConfig, pos=None, rope=None,
     if rope and pos is None:
         raise ValueError("RoPE model needs positions in _layer_qkv")
     with jax.named_scope("attn"):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        h = _norm(x, layer, "ln1", cfg)
         q = jnp.einsum("btd,dhk->bthk", h, layer["wq"].astype(cfg.dtype))
         kk = jnp.einsum("btd,dhk->bthk", h, layer["wk"].astype(cfg.dtype))
         v = jnp.einsum("btd,dhk->bthk", h, layer["wv"].astype(cfg.dtype))
@@ -494,7 +537,7 @@ def _ffn_residual(layer, x, cfg: GPTConfig, tp_axis=None, ffn=None,
     here = isinstance(routing, _RouteHere)
 
     def norm_ffn(layer, x, routing):
-        h = rms_norm(x, layer["ln2"], cfg.norm_eps)
+        h = _norm(x, layer, "ln2", cfg)
         if here:
             routing = _route(layer, h, cfg, routing.bias)
         if ffn is not None:
@@ -528,7 +571,7 @@ def _short_conv(layer, x, cfg: GPTConfig):
     ``remat="full"`` to keep (:data:`_FULL_REMAT_KEEPS`)."""
     T = x.shape[1]
     with jax.named_scope("conv_proj"):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        h = _norm(x, layer, "ln1", cfg)
         bcv = checkpoint_name(jnp.einsum(
             "btd,de->bte", h, layer["w_in"].astype(cfg.dtype)), "conv_in")
     with jax.named_scope("conv"):
@@ -669,10 +712,12 @@ def _local_attn(attn: str, T: int, sp_axis: Optional[str] = None) -> str:
 
 
 # what remat="full" keeps of a layer beside its input: the flash kernel's
-# output and lse (ops/flash_attention._fa_fwd), wm's output (_dense_ffn) and
-# a conv layer's two products (_short_conv)
+# output and lse (ops/flash_attention._fa_fwd), wm's output (_dense_ffn), a
+# conv layer's two products (_short_conv), and the scan kernel's output and
+# chunk states (ops/selective_scan._scan_fwd)
 _FULL_REMAT_KEEPS = jax.checkpoint_policies.save_only_these_names(
-    "ffn_proj", "flash_out", "flash_lse", "conv_in", "conv_out")
+    "ffn_proj", "flash_out", "flash_lse", "conv_in", "conv_out", "ssm_scan",
+    "ssm_states")
 
 
 def layer_stack(params, tokens, cfg: GPTConfig, *,
@@ -708,7 +753,11 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
     across rounds (``models/looped.py``).  A ``conv`` layer keeps the
     outputs of its two products, ``[B, T, 3D]`` and ``[B, T, D]``
     (:func:`_short_conv`), so its backward makes again the norm, the gates
-    and the taps, and neither product.  A capacity knob for models that do
+    and the taps, and neither product.  A mamba layer (models/sambay.py)
+    keeps what its scan kernel made, the output ``[B, T, E]`` in the
+    activations' dtype and the chunks' start states, so its backward runs
+    no second ``ssm_scan_fwd``; its products are made again.  A capacity
+    knob for models that do
     not fit otherwise: a step pays for it with the layers' forward a second
     time.
     ``"ffn"`` and ``"attn"``: see :func:`apply_layer`."""
@@ -722,40 +771,60 @@ def layer_stack(params, tokens, cfg: GPTConfig, *,
     if remat not in (True, "full", False, None, "", "none", "ffn", "attn"):
         raise ValueError(f"unknown remat mode {remat!r}")
 
+    from . import sambay
     by_kind = {}
+    carrying = sorted({cfg.layer_operator(i) for i in range(cfg.n_layers)}
+                      & set(sambay.OPERATORS))
+    if carrying and (tp_axis or sp_axis or router_bias is not None
+                     or remat in ("ffn", "attn")):
+        raise ValueError(f"the {', '.join(carrying)} layers run unsharded, "
+                         f"with remat 'full' or none")
 
     def layer_fn(i):
-        """One function for all the layers of layer ``i``'s kind: where
-        every layer is of one kind, one function for all of them."""
-        kind = (cfg.layer_operator(i), cfg.layer_rope(i),
-                cfg.layer_window(i), cfg.layer_routed(i))
+        """``fn(layer, x, carry, **kw) -> (out, carry)``, one function for
+        all the layers of layer ``i``'s kind: where every layer is of one
+        kind, one function for all of them."""
+        op = cfg.layer_operator(i)
+        kind = (sambay.kind(cfg, i) if op in sambay.OPERATORS else
+                (op, cfg.layer_rope(i), cfg.layer_window(i),
+                 cfg.layer_routed(i)))
         if kind not in by_kind:
-            fn = functools.partial(apply_layer, cfg=cfg, tp_axis=tp_axis,
-                                   sp_axis=sp_axis, attn=attn, pos=pos,
-                                   remat_ffn=(remat == "ffn"),
-                                   remat_around_attn=(remat == "attn"),
-                                   index=i)
+            if op in sambay.OPERATORS:
+                fn = functools.partial(sambay.apply_layer, cfg=cfg,
+                                       attn=attn, index=i)
+            else:
+                fn = functools.partial(
+                    apply_layer, cfg=cfg, tp_axis=tp_axis, sp_axis=sp_axis,
+                    attn=attn, pos=pos, remat_ffn=(remat == "ffn"),
+                    remat_around_attn=(remat == "attn"), index=i)
             if remat in (True, "full"):
                 fn = jax.checkpoint(fn, policy=_FULL_REMAT_KEEPS)
-            by_kind[kind] = fn
+            by_kind[kind] = fn if op in sambay.OPERATORS else _passing(fn)
         return by_kind[kind]
 
     def run(x):
+        """The layers in order; ``carry`` takes what a layer makes for a
+        later one (models/sambay.py: the memory, the shared KV)."""
+        carry, loads = {}, []
+        biases = iter(() if router_bias is None else router_bias)
         for i, layer in enumerate(params["layers"]):
-            x = layer_fn(i)(layer, x)
-        return x
-
-    def run_with_loads(x):
-        from ..parallel.moe import held_assignments
-        biases, loads = iter(router_bias), []
-        for i, layer in enumerate(params["layers"]):
-            if cfg.layer_routed(i):
-                x, ids = layer_fn(i)(layer, x, bias=next(biases))
+            if router_bias is not None and cfg.layer_routed(i):
+                from ..parallel.moe import held_assignments
+                (x, ids), carry = layer_fn(i)(layer, x, carry,
+                                              bias=next(biases))
                 loads.append(held_assignments(ids, (0, cfg.n_experts)))
             else:
-                x = layer_fn(i)(layer, x)
-        return x, jnp.stack(loads)
-    return x, (run if router_bias is None else run_with_loads)
+                x, carry = layer_fn(i)(layer, x, carry)
+        return x if router_bias is None else (x, jnp.stack(loads))
+    return x, run
+
+
+def _passing(fn):
+    """``fn(layer, x, **kw)`` of a layer that carries nothing to a later one
+    as ``(layer, x, carry, **kw) -> (out, carry)``."""
+    def carried(layer, x, carry, **kw):
+        return fn(layer, x, **kw), carry
+    return carried
 
 
 def forward_features(params, tokens, cfg: GPTConfig, *,
@@ -792,7 +861,7 @@ def forward_features(params, tokens, cfg: GPTConfig, *,
     else:
         x, loads = run(x)
     with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["lnf"], cfg.norm_eps)
+        x = _norm(x, params, "lnf", cfg)
     return x if router_bias is None else (x, loads)
 
 
@@ -809,8 +878,17 @@ def _attention_layers_only(cfg: GPTConfig, what: str) -> None:
     feed-forwards in one stack, and the sigmoid router, which only
     ``layer_stack`` runs (with the bias that a train step moves)."""
     _one_round_only(cfg, what)
-    if ({cfg.layer_operator(i) for i in range(cfg.n_layers)} != {"attn"}
-            or cfg.routed is not None or cfg.router != "softmax"):
+    from .sambay import OPERATORS
+    ops = {cfg.layer_operator(i) for i in range(cfg.n_layers)}
+    if ops & set(OPERATORS) or cfg.norm != "rms":
+        raise ValueError(f"{what} walks layers that keep no state but a KV "
+                         f"cache of their own, under RMS norms; mamba "
+                         f"layers (a scan's state), diff layers (two "
+                         f"softmax maps), gmu layers (a memory), cross "
+                         f"layers (a shared KV) and LayerNorm run through "
+                         f"layer_stack only")
+    if (ops != {"attn"} or cfg.routed is not None
+            or cfg.router != "softmax"):
         raise ValueError(f"{what} attends in every layer over one kind of "
                          f"feed-forward; conv layers, dense and routed "
                          f"feed-forwards in one stack and the sigmoid "
@@ -853,8 +931,7 @@ def held_rows(params, tokens, cfg: GPTConfig, *, attn: str = "auto",
             continue
         bias = next(biases, None)
         if cfg.router_reads == "ln1" and bias is None:
-            ids, _ = _route(layer, rms_norm(x, layer["ln1"], cfg.norm_eps),
-                            cfg)
+            ids, _ = _route(layer, _norm(x, layer, "ln1", cfg), cfg)
             x = apply_layer(layer, x, cfg, attn=attn, pos=pos, index=i)
         else:
             x, ids = apply_layer(layer, x, cfg, attn=attn, pos=pos, index=i,

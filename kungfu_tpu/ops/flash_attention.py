@@ -242,7 +242,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     discard the [B, H, T, 128] residual (HBM allocation + write).
     ``window`` (static, causal only): see :func:`flash_attention`."""
     B, T, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     block_q, block_k = _block_sizes(T, Tk, block_q, block_k)
     n_q, n_k = T // block_q, Tk // block_k
     scale = 1.0 / np.sqrt(D)
@@ -256,13 +256,17 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, n_k=n_k,
                                with_lse=with_lse, window=window)
-    o_spec = pl.BlockSpec((1, 1, block_q, D),
+    q_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    o_spec = pl.BlockSpec((1, 1, block_q, Dv),
                           lambda b, h, iq, ik: (b, h, iq, 0))
     k_at = _k_block_index(causal, block_q, block_k, window)
     k_spec = pl.BlockSpec((1, 1, block_k, D),
                           lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
+    v_spec = pl.BlockSpec((1, 1, block_k, Dv),
+                          lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
     out_specs = [o_spec]
-    out_shape = [_sds(qt.shape, qt.dtype, q)]
+    out_shape = [_sds((B, H, T, Dv), qt.dtype, q)]
     if with_lse:
         out_specs.append(pl.BlockSpec((1, 1, block_q, _LANES),
                                       lambda b, h, iq, ik: (b, h, iq, 0)))
@@ -270,11 +274,11 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     res = pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
-        in_specs=[o_spec, k_spec, k_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -429,7 +433,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     correction subtracts (FA2 with lse gradient, as needed by ring-flash
     merging)."""
     B, T, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     block_q, block_k = _block_sizes(T, Tk, block_q, block_k)
     n_q, n_k = T // block_q, Tk // block_k
     scale = 1.0 / np.sqrt(D)
@@ -446,7 +450,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     gt = jnp.transpose(g, (0, 2, 1, 3))
 
     # delta preprocess: one rowsum per q row (vs per block pair)
-    dspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq: (b, h, iq, 0))
+    dspec = pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq: (b, h, iq, 0))
     delta = pl.pallas_call(
         _fa_delta_kernel,
         grid=(B, H, n_q),
@@ -466,14 +470,18 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
     q_at = _q_block_index(causal, block_q, block_k, n_q, window)
     kq_spec = pl.BlockSpec((1, 1, block_q, D),
                            lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
+    kdo_spec = pl.BlockSpec((1, 1, block_q, Dv),
+                            lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
+                           lambda b, h, ik, iq: (b, h, ik, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, Dv),
                            lambda b, h, ik, iq: (b, h, ik, 0))
     krow_spec = pl.BlockSpec((1, 1, block_q, _LANES),
                              lambda b, h, ik, iq: (b, h, q_at(iq, ik), 0))
-    out_specs = [kk_spec, kk_spec]
+    out_specs = [kk_spec, kv_spec]
     out_shape = [_sds(kt.shape, kt.dtype, k), _sds(vt.shape, vt.dtype, v)]
     scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-               pltpu.VMEM((block_k, D), jnp.float32)]
+               pltpu.VMEM((block_k, Dv), jnp.float32)]
     fused = _fused_backward(T, D)
     if fused:
         # dq rides in the dk/dv kernel: one block for the whole sequence,
@@ -490,7 +498,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                           block_q=block_q, block_k=block_k, n_q=n_q,
                           n_k=n_k, with_dq=fused, window=window),
         grid=(B, H, n_k, n_q),
-        in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, krow_spec, krow_spec],
+        in_specs=[kq_spec, kk_spec, kv_spec, kdo_spec, krow_spec, krow_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
@@ -508,6 +516,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                               lambda b, h, iq, ik: (b, h, iq, 0))
         k_spec = pl.BlockSpec((1, 1, block_k, D),
                               lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
+        v_spec = pl.BlockSpec((1, 1, block_k, Dv),
+                              lambda b, h, iq, ik: (b, h, k_at(iq, ik), 0))
+        do_spec = pl.BlockSpec((1, 1, block_q, Dv),
+                               lambda b, h, iq, ik: (b, h, iq, 0))
         row_spec = pl.BlockSpec((1, 1, block_q, _LANES),
                                 lambda b, h, iq, ik: (b, h, iq, 0))
         dq = pl.pallas_call(
@@ -515,7 +527,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                               block_q=block_q, block_k=block_k, n_k=n_k,
                               window=window),
             grid=(B, H, n_q, n_k),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=_sds(qt.shape, qt.dtype, q),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
@@ -660,7 +672,11 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None, kv_groups: int = 1,
                     bwd_blocks=None, window: Optional[int] = None):
-    """Pallas flash attention, [B, T, H, D] → [B, T, H, D].
+    """Pallas flash attention, [B, T, H, D] → [B, T, H, Dv].
+
+    ``v`` may be wider than ``q`` and ``k`` (``Dv`` its own last axis,
+    a multiple of the lanes on the chip): scores and the scale are q's and
+    k's, the output and its accumulator are v's width.
 
     ``window`` (static, needs ``causal``): query i sees keys j with
     ``0 <= i - j < window``.  Tiles wholly left of that band are neither

@@ -264,8 +264,8 @@ def test_the_one_loop_and_its_backward_are_the_dense_sum(case, how, act):
     c = jax.random.normal(k[1], x.shape)
     got = jax.jit(_under(how, lambda *a: moe.expert_ffn(
         a[0], ids, *a[1:], held, block, act=act), c))(x, weights, wi, wm)
-    want = _under("plain", lambda *a: _dense_sum(a[0], ids, *a[1:], held,
-                                                 act), c)(x, weights, wi, wm)
+    want = jax.jit(_under("plain", lambda *a: _dense_sum(
+        a[0], ids, *a[1:], held, act), c))(x, weights, wi, wm)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
@@ -340,8 +340,10 @@ def test_the_kernels_path_is_the_dense_path(remat):
     params, tokens = _model()
     loss = lambda attn: lambda p: jnp.mean(gpt.forward_features(
         p, tokens, CFG, attn=attn, remat=remat) ** 2)
-    got = jax.value_and_grad(loss("flash"))(params)
-    want = jax.value_and_grad(loss("dense"))(params)
+    # each side one compiled program: op by op they take several times as
+    # long
+    got = jax.jit(jax.value_and_grad(loss("flash")))(params)
+    want = jax.jit(jax.value_and_grad(loss("dense")))(params)
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
@@ -370,8 +372,9 @@ def test_a_nope_layer_beside_a_rope_layer():
 def test_a_window_shorter_than_the_sequence_changes_the_result():
     params, tokens = _model()
     full = dataclasses.replace(CFG, window=None)
-    a = gpt.forward_features(params, tokens, CFG, attn="dense")
-    b = gpt.forward_features(params, tokens, full, attn="dense")
+    run = lambda cfg: jax.jit(lambda p, t: gpt.forward_features(
+        p, t, cfg, attn="dense"))(params, tokens)
+    a, b = run(CFG), run(full)
     np.testing.assert_allclose(a[:, :16], b[:, :16], rtol=1e-5, atol=1e-5)
     assert float(jnp.max(jnp.abs(a[:, 32:] - b[:, 32:]))) > 1e-3
 

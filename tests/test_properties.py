@@ -94,14 +94,20 @@ def test_chunked_ce_equals_dense(b, t, d, vocab, chunk):
     w = jnp.asarray((rng.randn(4 * d, vocab) * 0.3).astype(np.float32))
     y = jnp.asarray(rng.randint(0, vocab, (b, t)), jnp.int32)
 
-    got = chunked_cross_entropy(x, w, y, chunk)
-    logits = jnp.einsum("btd,dv->btv", x, w)
-    want = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+    dense = lambda a: optax.softmax_cross_entropy_with_integer_labels(
+        jnp.einsum("btd,dv->btv", a, w), y)
+    chunked = lambda a: chunked_cross_entropy(a, w, y, chunk)
+
+    # one compiled program an example: op by op, each new shape compiles
+    # every primitive apart, eight times as long
+    @jax.jit
+    def both(x):
+        return (chunked(x), dense(x),
+                jax.grad(lambda a: chunked(a).sum())(x),
+                jax.grad(lambda a: dense(a).sum())(x))
+
+    got, want, gx_c, gx_d = both(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
-
-    gx_c = jax.grad(lambda a: chunked_cross_entropy(a, w, y, chunk).sum())(x)
-    gx_d = jax.grad(lambda a: optax.softmax_cross_entropy_with_integer_labels(
-        jnp.einsum("btd,dv->btv", a, w), y).sum())(x)
     np.testing.assert_allclose(np.asarray(gx_c), np.asarray(gx_d),
                                rtol=1e-4, atol=1e-5)

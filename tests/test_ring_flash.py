@@ -98,8 +98,11 @@ def test_ring_flash_gradients_match_ring(devices):
         return lambda q, k, v: jnp.sum(sm(q, k, v) ** 2)
 
     rf = functools.partial(ring_flash_attention, block_q=4, block_k=4)
-    gf = jax.grad(make_loss(rf), argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(make_loss(ring_attention), argnums=(0, 1, 2))(q, k, v)
+    # each side one compiled program: op by op they take several times as
+    # long
+    gf = jax.jit(jax.grad(make_loss(rf), argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(make_loss(ring_attention), argnums=(0, 1, 2)))(
+        q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -134,8 +137,8 @@ def test_ring_flash_gqa_compact_gradients_match_dense(devices, causal):
         o = reference_attention(q, expand(kc), expand(vc), causal=causal)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    got = jax.grad(loss_ring, argnums=(0, 1, 2))(q, kc, vc)
-    want = jax.grad(loss_dense, argnums=(0, 1, 2))(q, kc, vc)
+    got = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, kc, vc)
+    want = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, kc, vc)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)

@@ -6,6 +6,7 @@ no output. All on the CPU: a scope is metadata of the program, the same
 whatever compiles it."""
 import contextlib
 import dataclasses
+import functools
 import glob
 import json
 import os
@@ -36,7 +37,8 @@ GPT_METRICS = ["forward_ms.gpt", "backward_ms.gpt", "recompute_ms.gpt",
 FLASH_METRICS = ["flash_fwd_ms.gpt", "flash_bwd_ms.gpt",
                  "flash_fwd_ms.ouro", "flash_bwd_ms.ouro",
                  "flash_fwd_ms.smallthinker", "flash_bwd_ms.smallthinker",
-                 "flash_fwd_ms.lfm2", "flash_bwd_ms.lfm2"]
+                 "flash_fwd_ms.lfm2", "flash_bwd_ms.lfm2",
+                 "flash_fwd_ms.phi4flash", "flash_bwd_ms.phi4flash"]
 # the looped decoder: the same layers, four rounds under one set of weights
 OURO = dataclasses.replace(GPT, n_kv_heads=4, norm_eps=1e-6, rope_theta=1e6,
                            out_norms=True, n_rounds=4)
@@ -68,6 +70,23 @@ HYBRID = GPTConfig(vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2,
 HYBRID_METRICS = ["forward_ms.lfm2", "backward_ms.lfm2", "recompute_ms.lfm2",
                   "optimizer_ms.lfm2", "ce_head_ms.lfm2", "moe_ms.lfm2",
                   "moe_route_ms.lfm2", "gmm_ms.lfm2", "conv_ms.lfm2"]
+
+# the decoder-hybrid-decoder: mamba, windowed differential attention, the
+# mamba that makes the memory, the full attention whose KV is shared, a
+# gated memory unit and a cross-attention layer
+SAMBAY = GPTConfig(vocab_size=256, d_model=64, n_heads=8, n_kv_heads=4,
+                   d_head=16, n_layers=6, d_ff=96, max_seq=128,
+                   rope=(False,) * 6, mlp="swiglu",
+                   window=(None, 32, None, None, None, None),
+                   operator=("mamba", "diff", "mamba", "diff", "gmu",
+                             "cross"), norm="layer")
+SAMBAY_METRICS = ["forward_ms.phi4flash", "backward_ms.phi4flash",
+                  "recompute_ms.phi4flash", "ce_head_ms.phi4flash",
+                  "ssm_ms.phi4flash", "gmu_ms.phi4flash",
+                  "diff_mix_ms.phi4flash"]
+# read by a kernel's name, which only the chip's step holds (the CPU takes
+# the scan's jnp path inside shard_map): checked on the lowered kernels
+SCAN_METRICS = ["ssm_scan_ms.phi4flash"]
 
 
 def scope_metrics() -> dict:
@@ -190,9 +209,31 @@ def make_resnet_step(mesh):
                   (images, jnp.arange(4, dtype=jnp.int32)))
 
 
+def make_sambay_step(mesh):
+    def loss_fn(p, batch):
+        tokens, targets = batch
+        feats = forward_features(p, tokens, SAMBAY, attn="flash",
+                                 remat="full")
+        return chunked_cross_entropy(
+            feats, p["wte"].astype(SAMBAY.dtype).T, targets, 128).mean()
+
+    opt = kfopt.synchronous_sgd(optax.adamw(1e-5))
+    step = build_train_step(loss_fn, opt, mesh, donate=False,
+                            compute_dtype=jnp.bfloat16)
+    # made in one program: op by op the mixers' many shapes take twice as
+    # long
+    params = jax.jit(functools.partial(init_params, cfg=SAMBAY))(
+        jax.random.PRNGKey(0))
+    del params["lm_head"]
+    params = replicate(params, mesh)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0, 256)
+    return step, (params, init_opt_state(opt, params, mesh),
+                  (tokens, jnp.roll(tokens, -1, axis=1)))
+
+
 MAKERS = {"gpt": make_gpt_step, "resnet": make_resnet_step,
           "ouro": make_ouro_step, "smallthinker": make_sparse_step,
-          "lfm2": make_hybrid_step}
+          "lfm2": make_hybrid_step, "phi4flash": make_sambay_step}
 
 
 @pytest.fixture(scope="module")
@@ -211,7 +252,8 @@ def names(steps):
 # the flash kernels run only on the chip inside shard_map (the CPU takes the
 # jnp path there): their names are checked on the lowered kernels below
 @pytest.mark.parametrize("metric", GPT_METRICS + RESNET_METRICS
-                         + OURO_METRICS + SPARSE_METRICS + HYBRID_METRICS)
+                         + OURO_METRICS + SPARSE_METRICS + HYBRID_METRICS
+                         + SAMBAY_METRICS)
 def test_the_step_has_what_each_metric_reads(names, metric):
     family = metric.rsplit(".", 1)[1]
     assert found(scope_metrics()[metric], names[family])
@@ -220,11 +262,12 @@ def test_the_step_has_what_each_metric_reads(names, metric):
 def test_every_scope_metric_of_the_manifest_is_covered_here():
     assert set(scope_metrics()) == set(GPT_METRICS + FLASH_METRICS
                                        + RESNET_METRICS + OURO_METRICS
-                                       + SPARSE_METRICS + HYBRID_METRICS)
+                                       + SPARSE_METRICS + HYBRID_METRICS
+                                       + SAMBAY_METRICS + SCAN_METRICS)
 
 
 @pytest.mark.parametrize("which", ["gpt", "resnet", "ouro", "smallthinker",
-                                   "lfm2"])
+                                   "lfm2", "phi4flash"])
 def test_the_builders_scopes(names, which):
     has = lambda rx: any(re.search(rx, n) for n in names[which])
     for scope in ("grads", "optimizer", "sync"):
@@ -262,6 +305,34 @@ def test_the_conv_scope_holds_the_gates_and_taps_not_the_projections(names):
     # the router reads ln2's output: it stands under ffn, not attn
     assert has(r"jvp\(ffn\)/moe/moe_route")
     assert not has(r"attn/.*moe_route")
+
+
+def test_the_sambay_scopes_hold_their_parts_and_no_projection(names):
+    has = lambda rx: any(re.search(rx, n) for n in names["phi4flash"])
+    # the mixers' scopes in the forward, the backward and full remat's
+    # second forward
+    for scope in ("ssm", "ssm_proj", "gmu", "diff_mix", "attn"):
+        assert has(rf"/jvp\({scope}\)/"), scope
+        assert has(rf"transpose\(.*/{scope}/"), scope
+    assert has(r"rematted_computation/ssm/")
+    # W_in and W_out stand under ssm_proj, which ssm_ms does not read
+    proj = [n for n in names["phi4flash"] if "ssm_proj" in n]
+    assert any(n.endswith("dot_general") for n in proj)
+    assert not found(scope_metrics()["ssm_ms.phi4flash"], proj)
+
+
+def test_the_scan_kernels_carry_their_names():
+    from kungfu_tpu.ops.selective_scan import _scan
+    u = jax.ShapeDtypeStruct((1, 16, 8), jnp.float32)
+    A = jax.ShapeDtypeStruct((8, 4), jnp.float32)
+    B = jax.ShapeDtypeStruct((1, 16, 4), jnp.float32)
+    grad = jax.jit(jax.grad(lambda u, d, A, B, C: _scan(
+        u, d, A, B, C, jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+    jaxpr = str(jax.make_jaxpr(grad)(u, u, A, B, B))
+    assert "name=ssm_scan_fwd" in jaxpr and "name=ssm_scan_bwd" in jaxpr
+    label = ("jit(body)/grads/jvp(checkpoint)/ssm/{}/pallas_call")
+    labels = [label.format(k) for k in ("ssm_scan_fwd", "ssm_scan_bwd")]
+    assert found(scope_metrics()["ssm_scan_ms.phi4flash"], labels) == labels
 
 
 def test_ouro_scopes_stand_inside_the_round_loop(names):
